@@ -11,31 +11,35 @@ Two kinds of rows:
   collectives;
 * measured mesh rows (informational, ``_us`` fields): the wall-time of
   the output-partial psum the lowered head-parallel serve executes,
-  plus one full ``head_parallel_decode_attention`` step, on a forced
-  2-device host mesh.  The bench re-execs those cells in a child
-  process so the parent's jax (already initialised with one device)
-  stays untouched.
+  plus one full ``head_parallel_decode_attention`` step, on a
+  2-device mesh — in-process when two devices exist, else (on the
+  CPU only) in a child forced onto two host devices.
 """
 
 import json
 import os
 import subprocess
 import sys
-import textwrap
 
 from repro.core import accelerator as acc
 from repro.core import allocation as galloc
 from repro.core import scheduler as sch
 from repro.core import workload as wl
 
-_CHILD = textwrap.dedent("""
-    import json, time
-    import jax, jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+
+def _measure_rows() -> list:
+    """Wall-time the output-partial psum and one head-parallel decode
+    step on a 2-device mesh of this process's devices."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
+
     from repro.launch.mesh_lowering import mesh_for_cores
+    from repro.serve.distributed_decode import \
+        head_parallel_decode_attention
     from repro.sharding import set_rules_for_mesh
-    from repro.serve.distributed_decode import head_parallel_decode_attention
 
     def measure_us(fn, args, repeats=5):
         jfn = jax.jit(fn)
@@ -50,9 +54,9 @@ _CHILD = textwrap.dedent("""
     mesh = mesh_for_cores(2)
     rows = []
     for M, E in ((64, 256), (128, 512)):
-        fn = shard_map(lambda x: jax.lax.psum(x, "model"), mesh=mesh,
-                       in_specs=P("model", None, None),
-                       out_specs=P(None, None, None), check_rep=False)
+        fn = jax.shard_map(lambda x: jax.lax.psum(x, "model"), mesh=mesh,
+                           in_specs=P("model", None, None),
+                           out_specs=P(None, None, None), check_vma=False)
         x = jax.random.normal(jax.random.PRNGKey(0), (2, M, E),
                               jnp.float32)
         rows.append({"name": f"mesh_psum_M{M}_E{E}",
@@ -69,8 +73,7 @@ _CHILD = textwrap.dedent("""
                         (q, k, v, lengths, wo))
     rows.append({"name": "mesh_head_parallel_step",
                  "step_us": round(us, 1)})
-    print(json.dumps(rows))
-""")
+    return rows
 
 
 def _dse_rows() -> list:
@@ -111,14 +114,27 @@ def _dse_rows() -> list:
 
 
 def _mesh_rows() -> list:
-    env = dict(os.environ,
+    """The measured rows: in this process when it already has two
+    devices; on a TPU host with one chip, refused (a child process could
+    not reach the chip this process holds); on the CPU, in a child
+    forced onto two host devices."""
+    import jax
+    if jax.device_count() >= 2:
+        return _measure_rows()
+    if jax.default_backend() == "tpu":
+        return [{"name": "mesh_measured_skipped",
+                 "reason": "one TPU device: the 2-device mesh rows need "
+                           "two chips in this process"}]
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=2",
                PYTHONPATH=os.pathsep.join(
-                   [os.path.join(os.path.dirname(__file__), "..", "src")]
+                   [os.path.join(root, "src")]
                    + ([os.environ["PYTHONPATH"]]
                       if "PYTHONPATH" in os.environ else [])))
-    out = subprocess.run([sys.executable, "-c", _CHILD], env=env,
-                         capture_output=True, text=True, timeout=600)
+    out = subprocess.run(
+        [sys.executable, "-m", "benchmarks.mesh_bench", "--measure"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=600)
     if out.returncode != 0:
         return [{"name": "mesh_measured_skipped",
                  "reason": out.stderr[-500:]}]
@@ -130,5 +146,8 @@ def run() -> list:
 
 
 if __name__ == "__main__":
-    for row in run():
-        print(row)
+    if "--measure" in sys.argv:
+        print(json.dumps(_measure_rows()))
+    else:
+        for row in run():
+            print(row)

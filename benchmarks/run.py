@@ -53,6 +53,8 @@ def main(argv=None) -> None:
                         help="directory for the BENCH_<area>.json "
                              "artifacts (default: cwd)")
     args = parser.parse_args(argv)
+    from repro.launch.compilation import enable_compile_cache
+    enable_compile_cache()
     selected = args.only or list(MODULES)
     outdir = pathlib.Path(args.outdir)
     outdir.mkdir(parents=True, exist_ok=True)

@@ -41,6 +41,12 @@ KVCACHE = "__kv_cache__"  # persistent KV-cache operand (decode phase):
 PHASES = ("prefill", "decode")
 
 
+class NotExpressible(ValueError):
+    """The configuration or shape has no DSE workload form (a non-GQA
+    block, or heads that do not group): callers fall back to a
+    config-driven path instead of a plan."""
+
+
 @dataclasses.dataclass(frozen=True)
 class Layer:
     """Base layer: produces one output tensor of shape (rows, cols)."""
@@ -409,8 +415,8 @@ def _add_gqa_attention(w: Workload, M: int, src: str, d_model: int,
     group, so consecutive ``n_heads // n_kv_heads`` heads consume the
     same K^T / V feature tensors.  Returns the output layer name."""
     if n_heads % n_kv_heads:
-        raise ValueError(f"n_heads={n_heads} not divisible by "
-                         f"n_kv_heads={n_kv_heads}")
+        raise NotExpressible(f"n_heads={n_heads} not divisible by "
+                             f"n_kv_heads={n_kv_heads}")
     p = prefix
     group = n_heads // n_kv_heads
     for g in range(n_kv_heads):
@@ -467,8 +473,8 @@ def _add_kv_cached_attention(w: Workload, M: int, src: str, d_model: int,
     2 * n_ctx * d_head words per KV group to ``w.kv_cache_words``.
     """
     if n_heads % n_kv_heads:
-        raise ValueError(f"n_heads={n_heads} not divisible by "
-                         f"n_kv_heads={n_kv_heads}")
+        raise NotExpressible(f"n_heads={n_heads} not divisible by "
+                             f"n_kv_heads={n_kv_heads}")
     p = prefix
     group = n_heads // n_kv_heads
     for g in range(n_kv_heads):
@@ -700,12 +706,12 @@ def _config_dims(cfg, layer_index: int = 0) -> dict:
     kind = cfg.block_kind(layer_index) if hasattr(cfg, "block_kind") \
         else "attn"
     if kind != "attn":
-        raise ValueError(
+        raise NotExpressible(
             f"{cfg.name}: layer {layer_index} is a {kind!r} block; only "
             "attention blocks are expressible as DSE workloads")
     attention = getattr(cfg, "attention", "gqa")
     if attention not in ("gqa",):
-        raise ValueError(
+        raise NotExpressible(
             f"{cfg.name}: attention flavour {attention!r} is not "
             "expressible yet (GQA/MHA only)")
     d_ff = cfg.d_ff

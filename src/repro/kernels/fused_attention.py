@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 NEG_INF = -1e30
 LANES = 128
@@ -89,7 +88,9 @@ def _emit_softmax_out(o_ref, lse_ref, acc_ref, m_ref, l_ref):
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
     if lse_ref is not None:
-        lse_ref[0] = (m_ref[...] + jnp.log(l_safe))[:, 0]
+        # lane-broadcast (bq, LANES) rows: a (1, bq) block over a
+        # (B*Hq, Sq) array is not a legal TPU block shape
+        lse_ref[0] = m_ref[...] + jnp.log(l_safe)
 
 
 # ---------------------------------------------------------------------------
@@ -162,23 +163,23 @@ def _fwd(q, k, v, *, causal, scale, q_offset, block_q, block_k, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dv), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, bq), lambda h, i, j: (h, i)),
+            pl.BlockSpec((1, bq, LANES), lambda h, i, j: (h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * hq, sq_p, dv), q.dtype),
-            jax.ShapeDtypeStruct((b * hq, sq_p), jnp.float32),
+            jax.ShapeDtypeStruct((b * hq, sq_p, LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)
     o = o[:, :sq].reshape(b, hq, sq, dv)
-    lse = lse[:, :sq].reshape(b, hq, sq)
+    lse = lse[:, :sq, 0].reshape(b, hq, sq)
     return o, lse
 
 
@@ -312,7 +313,7 @@ def fused_attention_masked(q, k, v, lengths, *, causal: bool = True,
                           hq=hq, sq=sq),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, dv), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lens, qr, kr, vr)
@@ -410,7 +411,7 @@ def fused_attention_paged(q, k_pool, v_pool, lengths, block_tables, *,
                           hq=hq, sq=sq),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, dv), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lens, tbl, qr, kr, vr)
@@ -447,11 +448,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         if kv_len % bk:
             cols = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
             s = jnp.where(cols < kv_len, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, None])                  # (bq, bk)
+        p = jnp.exp(s - lse_ref[0][:, :1])                    # (bq, bk)
         dp = jax.lax.dot_general(
             do_ref[0].astype(jnp.float32), v.astype(jnp.float32),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0][:, :1]) * scale
         dq_acc[...] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -491,7 +492,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if kv_len % bk:
             cols = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
             s = jnp.where(cols < kv_len, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])
+        p = jnp.exp(s - lse_ref[0, 0][:, :1])
         do = do_ref[0, 0].astype(jnp.float32)
         # dv += P^T dO
         dv_acc[...] += jax.lax.dot_general(
@@ -500,7 +501,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dp = jax.lax.dot_general(
             do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None]) * scale      # (bq, bk)
+        ds = p * (dp - delta_ref[0, 0][:, :1]) * scale        # (bq, bk)
         dk_acc[...] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -529,10 +530,12 @@ def _bwd(res, g, *, causal, scale, q_offset, block_q, block_k, interpret):
     kr = _pad_seq(k.reshape(b * hkv, skv, d), skv_p)
     vr = _pad_seq(v.reshape(b * hkv, skv, dv), skv_p)
     dor = _pad_seq(do.reshape(b * hq, sq, dv), sq_p)
-    # pad lse with +inf-ish so padded rows give p = exp(-inf) = 0
-    lser = _pad_seq(lse.reshape(b * hq, sq), sq_p,
-                    value=jnp.float32(1e30))
-    deltar = _pad_seq(delta.reshape(b * hq, sq), sq_p)
+    # pad lse with +inf-ish so padded rows give p = exp(-inf) = 0; the
+    # per-row residuals travel lane-broadcast, (.., Sq, LANES), like the
+    # forward's lse output
+    lser = _lanes(_pad_seq(lse.reshape(b * hq, sq), sq_p,
+                           value=jnp.float32(1e30)))
+    deltar = _lanes(_pad_seq(delta.reshape(b * hq, sq), sq_p))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, scale=scale,
@@ -543,21 +546,21 @@ def _bwd(res, g, *, causal, scale, q_offset, block_q, block_k, interpret):
             pl.BlockSpec((1, bk, d), lambda h, i, j, g=group: (h // g, j, 0)),
             pl.BlockSpec((1, bk, dv), lambda h, i, j, g=group: (h // g, j, 0)),
             pl.BlockSpec((1, bq, dv), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, bq), lambda h, i, j: (h, i)),
-            pl.BlockSpec((1, bq), lambda h, i, j: (h, i)),
+            pl.BlockSpec((1, bq, LANES), lambda h, i, j: (h, i, 0)),
+            pl.BlockSpec((1, bq, LANES), lambda h, i, j: (h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr, dor, lser, deltar)
 
     q4 = _pad_seq(q.reshape(b, hq, sq, d), sq_p, axis=2)
     do4 = _pad_seq(do.reshape(b, hq, sq, dv), sq_p, axis=2)
-    lse4 = _pad_seq(lse, sq_p, axis=2, value=jnp.float32(1e30))
-    delta4 = _pad_seq(delta, sq_p, axis=2)
+    lse4 = _lanes(_pad_seq(lse, sq_p, axis=2, value=jnp.float32(1e30)))
+    delta4 = _lanes(_pad_seq(delta, sq_p, axis=2))
     k4 = _pad_seq(k, skv_p, axis=2)
     v4 = _pad_seq(v, skv_p, axis=2)
 
@@ -574,12 +577,12 @@ def _bwd(res, g, *, causal, scale, q_offset, block_q, block_k, interpret):
             pl.BlockSpec((1, 1, bq, dv),
                          lambda b_, h, j, l, g=group, n=nq:
                          (b_, h * g + l // n, l % n, 0)),
-            pl.BlockSpec((1, 1, bq),
+            pl.BlockSpec((1, 1, bq, LANES),
                          lambda b_, h, j, l, g=group, n=nq:
-                         (b_, h * g + l // n, l % n)),
-            pl.BlockSpec((1, 1, bq),
+                         (b_, h * g + l // n, l % n, 0)),
+            pl.BlockSpec((1, 1, bq, LANES),
                          lambda b_, h, j, l, g=group, n=nq:
-                         (b_, h * g + l // n, l % n)),
+                         (b_, h * g + l // n, l % n, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda b_, h, j, l: (b_, h, j, 0)),
@@ -593,7 +596,7 @@ def _bwd(res, g, *, causal, scale, q_offset, block_q, block_k, interpret):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, dv), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -670,6 +673,13 @@ def _round_up(n: int, m: int = LANES) -> int:
 
 def _pad_to(n: int, block: int) -> int:
     return ((n + block - 1) // block) * block
+
+
+def _lanes(x):
+    """Broadcast per-row values (..., S) to (..., S, LANES): the TPU
+    block layout for a per-row residual (last block dim a full lane
+    width)."""
+    return jnp.broadcast_to(x[..., None], x.shape + (LANES,))
 
 
 def _pad_seq(x, target: int, axis: int = 1, value=None):

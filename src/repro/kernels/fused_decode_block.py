@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels import fused_attention as fa
 
 NEG_INF = fa.NEG_INF
@@ -196,7 +195,7 @@ def fused_decode_block_paged(x, wq, k_pool, v_pool, wo, residual,
                           rope_theta=rope_theta),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, bq, e), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(lens, tbl, xr, wqr, kr, vr, wo, rr)
@@ -260,7 +259,7 @@ def fused_decode_block(x, wq, k, v, wo, residual, lengths, *,
                           rope_theta=rope_theta),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, bq, e), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(lens, xr, wqr, kr, vr, wo, rr)

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.kernels import fused_attention as fa
 from repro.kernels import ref
 
@@ -118,11 +116,11 @@ def _qproj_fwd(x, wq, k, v, *, causal, scale, q_offset, rope_theta,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dv), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, bq), lambda h, i, j: (h, i)),
+            pl.BlockSpec((1, bq, LANES), lambda h, i, j: (h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * hq, sq_p, dv), x.dtype),
-            jax.ShapeDtypeStruct((b * hq, sq_p), jnp.float32),
+            jax.ShapeDtypeStruct((b * hq, sq_p, LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
@@ -130,12 +128,12 @@ def _qproj_fwd(x, wq, k, v, *, causal, scale, q_offset, rope_theta,
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xr, wqr, kr, vr)
     o = o[:, :sq].reshape(b, hq, sq, dv)
-    lse = lse[:, :sq].reshape(b, hq, sq)
+    lse = lse[:, :sq, 0].reshape(b, hq, sq)
     return o, lse
 
 
@@ -246,7 +244,7 @@ def fused_qproj_attention_masked(x, wq, k, v, lengths, *,
                           rope_theta=rope_theta),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, dv), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lens, xr, wqr, kr, vr)
@@ -325,7 +323,7 @@ def fused_qproj_attention_paged(x, wq, k_pool, v_pool, lengths,
                           rope_theta=rope_theta),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, dv), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lens, tbl, xr, wqr, kr, vr)

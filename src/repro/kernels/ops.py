@@ -53,6 +53,7 @@ import jax.numpy as jnp
 
 from repro.core import codesign
 from repro.core.fusion import select_schedule
+from repro.core.workload import NotExpressible
 from repro.kernels import ref as _ref
 from repro.kernels import xla_fallback as _xla
 from repro.kernels.fused_attention import fused_attention as _pallas_attn
@@ -104,15 +105,15 @@ def _auto_dispatch(entry: str, sq: int, skv: int, d: int, hq: int,
                    interpret: bool) -> Optional[_plan_rt.PlanDispatch]:
     """Resolve ``impl="auto"`` through the plan cache.  Returns None
     (caller falls back to the backend default) when the shapes are not
-    expressible as a DSE workload."""
+    expressible as a DSE workload; any other failure raises."""
     try:
         plan = _plan_cache.kernel_plan(seq_q=sq, seq_kv=skv, d_head=d,
                                        n_heads=hq, n_kv_heads=hkv)
-        return _plan_rt.dispatch(plan, backend=jax.default_backend(),
-                                 interpret=interpret, entry=entry,
-                                 lengths_masked=lengths_masked)
-    except Exception:
+    except NotExpressible:
         return None
+    return _plan_rt.dispatch(plan, backend=jax.default_backend(),
+                             interpret=interpret, entry=entry,
+                             lengths_masked=lengths_masked)
 
 
 #: (kernel, reason) pairs already warned about — per-reason, so e.g. a
@@ -126,30 +127,6 @@ def reset_lengths_downgrade_warning() -> None:
     (test isolation: the registry must not leak an 'already warned'
     state between tests)."""
     _warned_downgrade_reasons.clear()
-
-
-class KernelLaunchError(RuntimeError):
-    """A kernel launch failed.  Raised at dispatch time — in practice by
-    an installed fault injector (serve/faults.py); the serving
-    supervisor recovers by rung-down on the lowering ladder."""
-
-
-#: process-wide fault-injection hook consulted on every dispatch
-#: resolution; None outside chaos tests (see serve/faults.py).
-_fault_injector = None
-
-
-def set_fault_injector(inj) -> None:
-    """Install (or clear, with ``None``) a fault injector whose
-    ``on_kernel(entry, impl)`` runs after each entry point resolves its
-    impl — the kernel-launch-failure hook point of serve/faults.py."""
-    global _fault_injector
-    _fault_injector = inj
-
-
-def _maybe_inject(entry: str, impl: str) -> None:
-    if _fault_injector is not None:
-        _fault_injector.on_kernel(entry, impl)
 
 
 def _downgrade(plan, reason: str, *, kernel: str) -> str:
@@ -274,7 +251,6 @@ def _resolve(entry: str, impl: str, plan, sq: int, skv: int, d: int,
         else:
             impl = default_impl()
     block_q, block_k = _blocks(sq, skv, d, block_q, block_k)
-    _maybe_inject(entry, impl)
     return impl, block_q, block_k, interpret, plan
 
 

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, y_ref, hout_ref,
                 h_scr, *, chunk: int):
@@ -122,7 +120,7 @@ def ssd_scan(x, dt, a, b, c, d=None, *, chunk: int = 128,
             jax.ShapeDtypeStruct((B * H, P, S), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, S), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xr, dtr, alog, br, cr)

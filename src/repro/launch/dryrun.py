@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove the distribution config is coherent.
 
 For every assigned (architecture x input-shape) cell, on the single-pod
@@ -25,6 +22,7 @@ Usage:
 import argparse
 import functools
 import json
+import os
 import re
 import sys
 import time
@@ -36,7 +34,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
 from repro.launch.mesh import make_production_mesh
 from repro.models import transformer as tf
-from repro.models.common import split_params
 from repro.optim import adamw_init
 from repro.serve import engine
 from repro.sharding import (logical_to_mesh_axes, param_shardings,
@@ -135,19 +132,6 @@ def decode_state_shardings(state_sds, mesh):
     return jax.tree_util.tree_map_with_path(by_path, state_sds)
 
 
-def abstract_params(cfg, seed: int = 0):
-    """(values SDS tree, logical-axes tree) with zero allocation."""
-    captured = {}
-
-    def f(key):
-        vals, axes = split_params(tf.init_model(key, cfg))
-        captured["axes"] = axes
-        return vals
-
-    sds = jax.eval_shape(f, jax.random.PRNGKey(seed))
-    return sds, captured["axes"]
-
-
 # ---------------------------------------------------------------------------
 # per-cell lowering
 # ---------------------------------------------------------------------------
@@ -158,7 +142,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
     cfg = configs.get_config(arch)
     sh = configs.SHAPES[shape_name]
     specs = configs.input_specs(arch, shape_name, cfg)
-    params_sds, axes = abstract_params(cfg)
+    params_sds, axes = tf.abstract_params(cfg)
 
     with set_rules_for_mesh(mesh):
         p_shard = param_shardings(axes, mesh, like=params_sds)
@@ -314,7 +298,7 @@ def lower_cell_cfg(cfg, arch, shape_name, mesh, *,
     """lower_cell with an explicit (possibly depth-reduced) config."""
     sh = configs.SHAPES[shape_name]
     specs = configs.input_specs(arch, shape_name, cfg)
-    params_sds, axes = abstract_params(cfg)
+    params_sds, axes = tf.abstract_params(cfg)
 
     with set_rules_for_mesh(mesh, rules):
         p_shard = param_shardings(axes, mesh, like=params_sds)
@@ -429,6 +413,9 @@ def roofline_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main(argv=None):
+    # 512 host devices stand in for the production meshes; read when the
+    # backend first initialises, which nothing before this point does
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -15,14 +15,8 @@ import jax
 
 
 def _mk(shape, axes):
-    try:
-        axis_type = jax.sharding.AxisType.Auto
-    except AttributeError:
-        # jax < 0.6: no explicit-sharding axis types — every mesh axis
-        # is Auto already, and make_mesh has no axis_types kwarg
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,8 +26,11 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests/examples)."""
+    """A (data, model) mesh over the first ``data * model`` devices;
+    raises when fewer exist (a silently smaller mesh would run a
+    different program than the one asked for)."""
     n = len(jax.devices())
-    data = min(data, n)
-    model = min(model, max(n // data, 1))
+    if data * model > n:
+        raise ValueError(f"mesh ({data} x {model}) needs {data * model} "
+                         f"devices, but {n} exist")
     return _mk((data, model), ("data", "model"))

@@ -22,6 +22,7 @@ import numpy as np
 from repro import configs
 from repro.checkpoint import CheckpointManager
 from repro.data import SyntheticTokenDataset, make_batch_iterator
+from repro.launch.compilation import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optim.adamw import cosine_schedule
 from repro.runtime import StepTimer
@@ -114,6 +115,7 @@ def main(argv=None):
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = configs.get_config(args.arch, smoke=args.smoke)
     mesh = make_host_mesh(data=len(jax.devices())) if args.mesh else None
     t0 = time.time()

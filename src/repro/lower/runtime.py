@@ -224,6 +224,14 @@ class ServingPlan:
     paged: bool = False             # KV stored as page pool + tables
     page_size: Optional[int] = None
     resolutions: list = dataclasses.field(default_factory=list)
+    #: every ExecutionPlan this handle resolved, by identity
+    plans: dict = dataclasses.field(default_factory=dict)
+
+    @property
+    def downgrades(self) -> list:
+        """The downgrade ledger across every plan this handle resolved
+        (empty when the planned paths are the paths that ran)."""
+        return [d for p in self.plans.values() for d in p.downgrades]
 
     @property
     def head_dim(self) -> int:
@@ -256,6 +264,7 @@ class ServingPlan:
                      qk_norm=getattr(self.cfg, "qk_norm", False),
                      lengths_masked=True, paged=self.paged)
         self.resolutions.append((phase, n, plan.bucket, d.path, d.impl))
+        self.plans[id(plan)] = plan
         return d
 
     def prefill_dispatch(self, seq_len: int) -> PlanDispatch:
@@ -304,11 +313,13 @@ class ServingPlan:
         vector — the deepest row governs the whole-batch step); under
         a trace (abstract value) fall back to the buffer capacity —
         the conservative deepest-context plan."""
+        from jax.errors import (ConcretizationTypeError,
+                                TracerIntegerConversionError)
         try:
             if getattr(cache_len, "ndim", 0) == 1:
                 return max(int(v) for v in cache_len)
             return int(cache_len)
-        except Exception:
+        except (ConcretizationTypeError, TracerIntegerConversionError):
             return self.max_len
 
 

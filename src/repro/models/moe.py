@@ -70,7 +70,6 @@ def _expert_compute_shard_map(cfg: ModelConfig, buf, params, dt):
     cross-chip bytes are the token slots themselves — the lower bound
     for top-k routing.
     """
-    import jax.experimental.shard_map as _sm
     from jax.sharding import PartitionSpec as P
     mesh = _ep_mesh()
     n_ep = dict(zip(mesh.axis_names, mesh.devices.shape))["model"]
@@ -103,14 +102,14 @@ def _expert_compute_shard_map(cfg: ModelConfig, buf, params, dt):
         gspec = (*batch_tuple, "model")   # groups over ALL axes
     else:
         gspec = bspec                     # fallback: model-replicated
-    fn = _sm.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(gspec, None, None, None),
                   P("model", None, None),
                   P("model", None, None),
                   P("model", None, None)),
         out_specs=P(gspec, None, None, None),
-        check_rep=False)
+        check_vma=False)
     return fn(buf, wg, wu, wd)
 
 

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import shard_map as _sm
 from jax.sharding import PartitionSpec as P
 
 from repro.models import common as cm
@@ -95,7 +94,7 @@ def moe_forward_local(params, cfg: ModelConfig, x):
         zl = jax.lax.pmean(zl, all_axes)
         return y, lb, zl
 
-    fn = _sm.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(all_axes, None),       # tokens over every axis
                   P(None, None),           # router replicated
@@ -103,7 +102,7 @@ def moe_forward_local(params, cfg: ModelConfig, x):
                   P("model", None, None),
                   P("model", None, None)),
         out_specs=(P(all_axes, None), P(), P()),
-        check_rep=False)
+        check_vma=False)
     y, lb, zl = fn(x.reshape(tokens, d),
                    params["router"].astype(jnp.float32),
                    params["w_gate"].astype(dt),

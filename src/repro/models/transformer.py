@@ -292,6 +292,42 @@ def init_model_cache(cfg: ModelConfig, batch: int, max_len: int,
     return {"prefix": prefix, "scan": [stack_cache(p) for p in range(period)]}
 
 
+def cache_axes(cfg: ModelConfig):
+    """Logical axes of :func:`init_model_cache`'s tree (GQA caches):
+    batch rows, KV heads, sequence, head width — the scanned body's
+    leaves carry the leading n_periods axis."""
+    for i in range(cfg.n_layers):
+        if cfg.block_kind(i) != "attn" or cfg.attention != "gqa":
+            raise NotImplementedError(
+                "cache axes cover GQA attention caches only")
+    leaf = ("batch", "kv_heads", "seq", "head_dim")
+    one = {"attn": {"k": leaf, "v": leaf}}
+    scanned = {"attn": {"k": (None,) + leaf, "v": (None,) + leaf}}
+    return {"prefix": [one] * cfg.first_dense_layers,
+            "scan": [scanned] * cfg.layer_period}
+
+
 def init_params_and_axes(key, cfg: ModelConfig):
     """Convenience: init + split into (values, logical axes)."""
     return split_params(init_model(key, cfg))
+
+
+def init_params(key, cfg: ModelConfig):
+    """Parameter values of :func:`init_model`, built by one jitted
+    program: the compiler writes each layer straight into its stack, so
+    no layer is held twice (the eager path keeps every layer and the
+    stack at once)."""
+    return jax.jit(lambda k: split_params(init_model(k, cfg))[0])(key)
+
+
+def abstract_params(cfg: ModelConfig, seed: int = 0):
+    """(values SDS tree, logical-axes tree) with zero allocation."""
+    captured = {}
+
+    def f(key):
+        vals, axes = split_params(init_model(key, cfg))
+        captured["axes"] = axes
+        return vals
+
+    sds = jax.eval_shape(f, jax.random.PRNGKey(seed))
+    return sds, captured["axes"]

@@ -23,7 +23,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding import rules as shrules
@@ -111,14 +110,14 @@ def distributed_decode_attention(
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     bspec = batch_axes if len(batch_axes) > 1 else \
         (batch_axes[0] if batch_axes else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(bspec, None, None, None),
                   P(bspec, None, axis, None),
                   P(bspec, None, axis, None),
                   P(bspec)),
         out_specs=P(bspec, None, None, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k, v, lengths)
 
 
@@ -179,7 +178,7 @@ def head_parallel_decode_attention(
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     bspec = batch_axes if len(batch_axes) > 1 else \
         (batch_axes[0] if batch_axes else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(bspec, axis, None, None),
                   P(bspec, axis, None, None),
@@ -187,5 +186,5 @@ def head_parallel_decode_attention(
                   P(bspec),
                   P(axis, None, None)),
         out_specs=P(bspec, None, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k, v, lengths, wo).astype(q.dtype)

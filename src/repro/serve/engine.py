@@ -344,6 +344,8 @@ class ContinuousBatchingEngine:
         #: serve-layer fault injector (serve/faults.py); None outside
         #: chaos tests.
         self.fault_injector = None
+        #: one jitted launch per (kind, legalised dispatch) — see _launch
+        self._launches: dict = {}
         #: host copy of the last decode launch's final-position logits
         #: (B, vocab) — the supervisor's NaN-detection window.
         self.last_logits: Optional[np.ndarray] = None
@@ -368,7 +370,7 @@ class ContinuousBatchingEngine:
         when complete; the decode loop never pauses."""
         if self.live[slot] or slot in self._pending:
             raise ValueError(f"slot {slot} is not free")
-        toks = jnp.asarray(prompt, jnp.int32)[None, :]
+        toks = np.asarray(prompt, np.int32)[None, :]
         if toks.shape[1] > self.max_len:
             raise ValueError(f"prompt ({toks.shape[1]} tokens) exceeds "
                              f"cache max_len {self.max_len}")
@@ -391,10 +393,10 @@ class ContinuousBatchingEngine:
             if self.plan is not None:
                 dispatch = self._demoted(self.plan.chunk_dispatch(
                     p["pos"] + piece.shape[1], piece.shape[1]))
-            logits, p["cache"] = tf.forward(
-                self.params, self.cfg, tokens=piece, cache=p["cache"],
-                cache_len=p["pos"], interpret=self.interpret,
-                plan=dispatch)
+            self._check_kernel(dispatch)
+            logits, p["cache"] = self._launch("prefill", dispatch)(
+                self.params, jnp.asarray(piece), p["cache"],
+                jnp.int32(p["pos"]))
             p["pos"] += piece.shape[1]
             if p["pos"] >= total:
                 res = PrefillResult(
@@ -415,6 +417,48 @@ class ContinuousBatchingEngine:
     def _before_decode(self) -> None:
         """Hook run right before each decode launch (the paged engine
         grows page lists for rows crossing a page boundary here)."""
+
+    def _launch(self, kind: str, dispatch):
+        """The jitted launch for one legalised dispatch: ``"decode"``
+        (one whole-batch token step) or ``"prefill"`` (one chunk into a
+        B=1 side cache).  One compiled program per (kind, path, impl,
+        block sizes, paged); ``cache_len``, the chunk offset, the
+        active mask and the cache are traced arguments, so a steady
+        step compiles nothing.  Kernel-side work that ``kernels.ops``
+        does per call (tiling, downgrade records) now runs once, when
+        the program is traced."""
+        key = (kind, None if dispatch is None else (
+            dispatch.path, dispatch.impl, dispatch.block_q,
+            dispatch.block_k, dispatch.interpret, dispatch.paged))
+        fn = self._launches.get(key)
+        if fn is not None:
+            return fn
+        cfg, interpret = self.cfg, self.interpret
+        if kind == "decode":
+            def fn(params, state, active):
+                return decode_step(
+                    params, cfg, state, dispatch=dispatch, active=active,
+                    interpret=interpret,
+                    block_tables=getattr(state, "block_tables", None))
+        else:
+            def fn(params, tokens, cache, pos):
+                logits, cache = tf.forward(
+                    params, cfg, tokens=tokens, cache=cache,
+                    cache_len=pos, interpret=interpret, plan=dispatch)
+                return logits[:, -1:], cache
+        fn = self._launches[key] = jax.jit(fn)
+        return fn
+
+    def _check_kernel(self, dispatch) -> None:
+        """Fault hook, consulted on the host before every launch: a
+        kernel fault armed for the impl this dispatch runs raises here
+        (chaos testing only — the kernels themselves carry no hook)."""
+        if self.fault_injector is None:
+            return
+        if dispatch is None:
+            self.fault_injector.on_kernel("attention", self.cfg.attn_impl)
+        else:
+            self.fault_injector.on_kernel(dispatch.path, dispatch.impl)
 
     def _demoted(self, dispatch):
         """Apply the standing ``demotions`` count to a resolved
@@ -466,10 +510,9 @@ class ContinuousBatchingEngine:
                 [c for c, alive in zip(self.row_ctx, self.live)
                  if alive]))
         self.last_dispatch = dispatch
-        new_state, logits = decode_step(
-            self.params, self.cfg, self.state, dispatch=dispatch,
-            active=jnp.asarray(self.live), interpret=self.interpret,
-            block_tables=getattr(self.state, "block_tables", None))
+        self._check_kernel(dispatch)
+        new_state, logits = self._launch("decode", dispatch)(
+            self.params, self.state, jnp.asarray(self.live))
         self.state = new_state
         self.last_logits = np.asarray(logits)
         self._inject_nan()

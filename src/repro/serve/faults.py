@@ -10,11 +10,11 @@ and is consulted from three hook points:
 * ``PageAllocator.alloc``/``ensure`` (``on_alloc``) — raises
   :class:`~repro.serve.engine.OutOfPages` on the armed step, modelling
   pool exhaustion at admission, resume, or the in-step page grow;
-* ``kernels.ops`` dispatch resolution (``on_kernel``, installed via
-  ``ops.set_fault_injector``) — raises
-  :class:`~repro.kernels.ops.KernelLaunchError` when the resolved impl
-  matches the armed spec, modelling a sick kernel the supervisor must
-  rung-down around;
+* the engine's pre-launch check (``on_kernel``, run on the host before
+  every prefill-chunk and decode launch) — raises
+  :class:`KernelLaunchError` when the dispatch's impl matches the
+  armed spec, modelling a sick kernel the supervisor must rung-down
+  around;
 * the engine's decode step (``nan_slot``) — poisons one live slot's
   logits/last-token, modelling numerics corruption the supervisor must
   quarantine; plus ``preempt_storm`` — forced preemptions of healthy
@@ -35,7 +35,14 @@ from typing import Optional
 
 from repro.serve.engine import OutOfPages
 
-__all__ = ["FaultSpec", "FaultInjector", "Incident", "IncidentLedger"]
+__all__ = ["FaultSpec", "FaultInjector", "Incident", "IncidentLedger",
+           "KernelLaunchError"]
+
+
+class KernelLaunchError(RuntimeError):
+    """A kernel launch failed — raised by an installed injector from the
+    engine's pre-launch hook; the serving supervisor recovers by
+    rung-down on the lowering ladder."""
 
 #: fault kinds a spec may carry
 KINDS = ("oom", "kernel", "nan", "preempt")
@@ -50,7 +57,7 @@ class FaultSpec:
     """One scheduled fault.
 
     ``kind``:  'oom' (raise OutOfPages from the allocator), 'kernel'
-               (raise KernelLaunchError at dispatch), 'nan' (poison
+               (raise KernelLaunchError before a launch), 'nan' (poison
                ``slot``'s logits after the decode launch), 'preempt'
                (force-preempt ``count`` healthy slots).
     ``step``:  the scheduler step it arms on.
@@ -124,17 +131,16 @@ class FaultInjector:
                 f"injected page exhaustion at step {self._step} "
                 f"(alloc({key!r}, {n}))")
 
-    def on_kernel(self, entry: str, impl: str) -> None:
-        """kernels.ops dispatch hook: raise when the resolved impl
+    def on_kernel(self, path: str, impl: str) -> None:
+        """Engine pre-launch hook: raise when the dispatch's impl
         matches the armed spec."""
         spec = self._take("kernel", lambda s: s.impl == impl)
         if spec is not None:
-            from repro.kernels.ops import KernelLaunchError
             self.fired.append((self._step, "kernel",
-                               f"{entry}/{impl}"))
+                               f"{path}/{impl}"))
             raise KernelLaunchError(
                 f"injected kernel launch failure at step "
-                f"{self._step} ({entry}, impl={impl!r})")
+                f"{self._step} ({path}, impl={impl!r})")
 
     def nan_slot(self) -> Optional[int]:
         """Engine decode hook: the slot whose logits to poison this

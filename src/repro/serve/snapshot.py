@@ -144,7 +144,7 @@ def restore_engine(mgr, engine, batcher,
     for pm in extras["pending"]:
         cache = jax.tree.unflatten(side_def, take(pm["n_leaves"]))
         engine._pending[pm["slot"]] = {
-            "tokens": jnp.asarray([pm["tokens"]], jnp.int32),
+            "tokens": np.asarray([pm["tokens"]], np.int32),
             "pos": pm["pos"], "cache": cache}
 
     # batcher queue (paused KV snapshots share the cache structure)

@@ -8,7 +8,7 @@ every transition is guarded, every recovery is an explicit policy, and
 everything that goes wrong lands on a structured
 :class:`~repro.serve.faults.IncidentLedger`:
 
-* **Kernel failures** (:class:`~repro.kernels.ops.KernelLaunchError`)
+* **Kernel failures** (:class:`~repro.serve.faults.KernelLaunchError`)
   recover by *rung-down*: the engine's standing ``demotions`` count is
   raised and the step retried one rung lower on the lowering ladder
   (``decode_megakernel → qproj_attention → fused_attention →
@@ -50,11 +50,9 @@ from typing import Optional
 
 import numpy as np
 
-from repro.kernels import ops
-from repro.kernels.ops import KernelLaunchError
 from repro.serve.audit import audit_engine
 from repro.serve.engine import OutOfPages
-from repro.serve.faults import IncidentLedger
+from repro.serve.faults import IncidentLedger, KernelLaunchError
 from repro.serve.snapshot import restore_engine, snapshot_engine
 
 __all__ = ["PagePressurePolicy", "ServingSupervisor"]
@@ -119,7 +117,7 @@ class ServingSupervisor:
 
     Parameters beyond the obvious: ``injector`` (a
     :class:`~repro.serve.faults.FaultInjector`, installed on the
-    engine, its allocator and the kernels-dispatch hook for the run),
+    engine and its allocator for the run),
     ``deadline_steps`` (fail a request leased longer than this many
     scheduler steps; None = no deadline), ``retry_budget`` (quarantine
     re-admissions per request), ``max_step_retries`` (launch retries
@@ -165,13 +163,11 @@ class ServingSupervisor:
             self.engine.fault_injector = self.injector
             if self.paged:
                 self.engine.allocator.fault_injector = self.injector
-            ops.set_fault_injector(self.injector)
 
     def _detach(self):
         self.engine.fault_injector = None
         if self.paged:
             self.engine.allocator.fault_injector = None
-        ops.set_fault_injector(None)
 
     def state_dict(self) -> dict:
         return {"t": self.t,

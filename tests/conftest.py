@@ -29,7 +29,5 @@ def _reset_lengths_downgrade_warning():
         yield
         return
     ops.reset_lengths_downgrade_warning()
-    ops.set_fault_injector(None)
     yield
     ops.reset_lengths_downgrade_warning()
-    ops.set_fault_injector(None)

@@ -503,7 +503,6 @@ def validate_mesh(repeats: int = 5) -> list:
     balanced collective regardless of DSE-side skew, so pretending to
     "measure" it would be dishonest.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import accelerator as acc
@@ -531,9 +530,9 @@ def validate_mesh(repeats: int = 5) -> list:
         def partial_sum(x):
             return jax.lax.psum(x, "model")
 
-        fn = shard_map(partial_sum, mesh=mesh,
-                       in_specs=P("model", None, None),
-                       out_specs=P(None, None, None), check_rep=False)
+        fn = jax.shard_map(partial_sum, mesh=mesh,
+                           in_specs=P("model", None, None),
+                           out_specs=P(None, None, None), check_vma=False)
         x = jax.random.normal(jax.random.PRNGKey(0), (2, M, E),
                               jnp.float32)
         us = _measure_us(fn, (x,), repeats)
@@ -590,13 +589,18 @@ def _print_mesh_table(rows) -> None:
 
 
 def _mesh_main(repeats: int) -> None:
-    """Run (or re-exec onto a forced 2-device host and run) the mesh
-    comm-validation cells."""
+    """Run the mesh comm-validation cells in this process when it has
+    two devices; on the CPU, re-exec onto a forced 2-device host.  A
+    one-chip TPU host is refused: the re-exec'd child could not reach
+    the chip this process already holds."""
     import os
     import subprocess
     import sys
     if jax.device_count() < 2:
-        env = dict(os.environ)
+        if jax.default_backend() == "tpu":
+            sys.exit("--mesh needs two devices in this process; this TPU "
+                     "host has one")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                             " --xla_force_host_platform_device_count=2"
                             ).strip()
