@@ -31,7 +31,6 @@ MODULES = {
     "blocks_bench": "benchmarks.blocks_bench",
     "phase_sweep": "benchmarks.phase_sweep",
     "lowering_bench": "benchmarks.lowering_bench",
-    "serving_bench": "benchmarks.serving_bench",
     "mesh_bench": "benchmarks.mesh_bench",
     "kernel_bench": "benchmarks.kernel_bench",
     "roofline": "benchmarks.roofline",
@@ -40,7 +39,7 @@ MODULES = {
 # module name -> JSON artifact area (default: the module name itself)
 AREAS = {"kernel_bench": "kernels", "engine_bench": "engine",
          "blocks_bench": "blocks", "lowering_bench": "lowering",
-         "serving_bench": "serving", "mesh_bench": "mesh"}
+         "mesh_bench": "mesh"}
 
 
 def main(argv=None) -> None:

@@ -177,6 +177,7 @@ def _fwd(q, k, v, *, causal, scale, q_offset, block_q, block_k, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="fused_attention_fwd",
     )(qr, kr, vr)
     o = o[:, :sq].reshape(b, hq, sq, dv)
     lse = lse[:, :sq, 0].reshape(b, hq, sq)
@@ -316,6 +317,7 @@ def fused_attention_masked(q, k, v, lengths, *, causal: bool = True,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="fused_attention_masked",
     )(lens, qr, kr, vr)
     return o[:, :sq].reshape(b, hq, sq, dv)
 
@@ -414,6 +416,7 @@ def fused_attention_paged(q, k_pool, v_pool, lengths, block_tables, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="fused_attention_paged",
     )(lens, tbl, qr, kr, vr)
     return o[:, :sq].reshape(b, hq, sq, dv)
 
@@ -555,6 +558,7 @@ def _bwd(res, g, *, causal, scale, q_offset, block_q, block_k, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="fused_attention_bwd_dq",
     )(qr, kr, vr, dor, lser, deltar)
 
     q4 = _pad_seq(q.reshape(b, hq, sq, d), sq_p, axis=2)
@@ -600,6 +604,7 @@ def _bwd(res, g, *, causal, scale, q_offset, block_q, block_k, interpret):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="fused_attention_bwd_dkv",
     )(q4, k4, v4, do4, lse4, delta4)
 
     dq = dq[:, :sq].reshape(b, hq, sq, d)

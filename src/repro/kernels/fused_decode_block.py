@@ -198,6 +198,7 @@ def fused_decode_block_paged(x, wq, k_pool, v_pool, wo, residual,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="fused_decode_block_paged",
     )(lens, tbl, xr, wqr, kr, vr, wo, rr)
     return out[:, :1]
 
@@ -262,5 +263,6 @@ def fused_decode_block(x, wq, k, v, wo, residual, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="fused_decode_block",
     )(lens, xr, wqr, kr, vr, wo, rr)
     return out[:, :1]

@@ -131,6 +131,7 @@ def _qproj_fwd(x, wq, k, v, *, causal, scale, q_offset, rope_theta,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="fused_qproj_attention_fwd",
     )(xr, wqr, kr, vr)
     o = o[:, :sq].reshape(b, hq, sq, dv)
     lse = lse[:, :sq, 0].reshape(b, hq, sq)
@@ -247,6 +248,7 @@ def fused_qproj_attention_masked(x, wq, k, v, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="fused_qproj_attention_masked",
     )(lens, xr, wqr, kr, vr)
     return o[:, :sq].reshape(b, hq, sq, dv)
 
@@ -326,6 +328,7 @@ def fused_qproj_attention_paged(x, wq, k_pool, v_pool, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="fused_qproj_attention_paged",
     )(lens, tbl, xr, wqr, kr, vr)
     return o[:, :sq].reshape(b, hq, sq, dv)
 

@@ -123,6 +123,7 @@ def ssd_scan(x, dt, a, b, c, d=None, *, chunk: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_scan",
     )(xr, dtr, alog, br, cr)
 
     y = jnp.moveaxis(y.reshape(B, H, L, P), 1, 2)     # (B, L, H, P)

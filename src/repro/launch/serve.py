@@ -5,7 +5,9 @@ Requests of different prompt lengths are prefilled on the side
 mid-stream; every decode step is one whole-batch launch whose per-row
 ``cache_len`` feeds the masked kernels.  ``--paged`` serves from a KV
 page pool instead of dense per-row caches; ``--layers`` cuts a
-published config to its first layers (widths unchanged).
+published config to its first layers (widths unchanged).  The last line
+printed is the engine's and scheduler's counters and each span's total
+self time (``serve/tracing.py``).
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --smoke \
         --requests 6 --max-new 16
@@ -29,7 +31,7 @@ from repro import configs
 from repro.models import transformer as tf
 from repro.serve import (ContinuousBatchingEngine,
                          PagedContinuousBatchingEngine, Request,
-                         RequestBatcher, make_serving_plan)
+                         RequestBatcher, make_serving_plan, tracing)
 
 
 def make_engine(params, cfg, *, batch: int, max_len: int,
@@ -133,6 +135,7 @@ def main(argv=None):
     for r in finished[:3]:
         print(f"  req {r.uid}: prompt {len(r.prompt)} toks -> "
               f"{r.generated[:8]}...")
+    print(tracing.summary())
 
 
 if __name__ == "__main__":

@@ -111,11 +111,13 @@ def _layer_forward(lp, cfg: ModelConfig, i_kind: tuple, x, positions,
         # the attention block owns its residual add (residual=x): the
         # decode megakernel folds it into the Pallas launch, every
         # other path adds it inside attention_forward
-        x, new_attn_cache = attn.attention_forward(
-            lp["attn"], cfg, h, positions,
-            cache=None if layer_cache is None else layer_cache.get("attn"),
-            cache_len=cache_len, interpret=interpret, plan=plan,
-            residual=x, block_tables=block_tables)
+        with jax.named_scope("attention"):
+            x, new_attn_cache = attn.attention_forward(
+                lp["attn"], cfg, h, positions,
+                cache=None if layer_cache is None
+                else layer_cache.get("attn"),
+                cache_len=cache_len, interpret=interpret, plan=plan,
+                residual=x, block_tables=block_tables)
         new_cache = None if layer_cache is None else {"attn": new_attn_cache}
     else:
         h, new_mamba_cache = mb.mamba_forward(
@@ -127,10 +129,11 @@ def _layer_forward(lp, cfg: ModelConfig, i_kind: tuple, x, positions,
         x = x + h
     if "mlp" in lp or "moe" in lp:
         h = rms_norm(x, lp["ffn_norm"])
-        if ffn_kind == "moe" and "moe" in lp:
-            h, aux = moe_mod.moe_forward(lp["moe"], cfg, h)
-        else:
-            h = cm.mlp_forward(lp["mlp"], h, cfg.mlp)
+        with jax.named_scope("mlp"):
+            if ffn_kind == "moe" and "moe" in lp:
+                h, aux = moe_mod.moe_forward(lp["moe"], cfg, h)
+            else:
+                h = cm.mlp_forward(lp["mlp"], h, cfg.mlp)
         x = x + h
     x = constrain(x, "batch", "seq_stream", "embed_act")
     return x, new_cache, aux
@@ -249,14 +252,15 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None, *,
             new_scan_caches = None
 
     x = rms_norm(x, params["final_norm"])
-    if "lm_head" in params:
-        logits = jnp.einsum("bsd,dv->bsv", x,
-                            params["lm_head"].astype(cfg.cdtype))
-    elif "embed" in params:
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["embed"].astype(cfg.cdtype))
-    else:
-        logits = x
+    with jax.named_scope("logits"):
+        if "lm_head" in params:
+            logits = jnp.einsum("bsd,dv->bsv", x,
+                                params["lm_head"].astype(cfg.cdtype))
+        elif "embed" in params:
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                params["embed"].astype(cfg.cdtype))
+        else:
+            logits = x
     logits = constrain(logits, "batch", "seq", "vocab")
 
     out = [logits]

@@ -49,6 +49,8 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro.serve import tracing
+
 
 @dataclasses.dataclass
 class Request:
@@ -230,30 +232,45 @@ class RequestBatcher:
                 return engine.can_admit_tokens(len(req.prompt))
         steps = 0
         while (self.active or engine._pending) and steps < max_steps:
-            # lease-and-reserve one request at a time: the engine's
-            # begin_prefill/resume takes its pages before the next
-            # head is checked against the remaining free list
-            while True:
-                slot = self._admit_one(can_admit)
-                if slot is None:
-                    break
-                req = self.slots[slot]
-                if req.paused is not None:
+            with tracing.span("sched.step",
+                              step=tracing.counter("sched.steps"),
+                              queued=len(self.queue),
+                              live=sum(engine.live)):
+                self._serve_step(engine, can_admit, paged)
+            tracing.count("sched.steps")
+            steps += 1
+        return self.finished
+
+    def _serve_step(self, engine, can_admit, paged: bool) -> None:
+        """One iteration of :meth:`serve`: admit, relieve page
+        pressure, one engine step, feed tokens back, evict."""
+        # lease-and-reserve one request at a time: the engine's
+        # begin_prefill/resume takes its pages before the next head is
+        # checked against the remaining free list
+        while True:
+            slot = self._admit_one(can_admit)
+            if slot is None:
+                break
+            req = self.slots[slot]
+            resumed = req.paused is not None
+            # the one span that ties a request's uid to its slot
+            with tracing.span("sched.admit", uid=req.uid, slot=slot,
+                              resumed=resumed):
+                if resumed:
                     engine.resume(req.paused, slot)
                     req.paused = None
                 else:
                     engine.begin_prefill(slot, req.prompt)
-            if paged:
-                self._relieve_page_pressure(engine)
-            tokens, inserted = engine.step()
-            # a request's first token is sampled by its prefill
-            for slot, first in inserted:
-                for f in self.step_slots([slot], [first]):
-                    engine.evict(f)
-            if tokens is not None:
-                ready = [i for i in range(self.batch_size)
-                         if engine.live[i] and self.slots[i] is not None]
-                for f in self.step_slots(ready, tokens[ready]):
-                    engine.evict(f)
-            steps += 1
-        return self.finished
+            tracing.count("sched.admitted")
+        if paged:
+            self._relieve_page_pressure(engine)
+        tokens, inserted = engine.step()
+        # a request's first token is sampled by its prefill
+        for slot, first in inserted:
+            for f in self.step_slots([slot], [first]):
+                engine.evict(f)
+        if tokens is not None:
+            ready = [i for i in range(self.batch_size)
+                     if engine.live[i] and self.slots[i] is not None]
+            for f in self.step_slots(ready, tokens[ready]):
+                engine.evict(f)

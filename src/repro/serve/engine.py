@@ -71,6 +71,7 @@ import numpy as np
 
 from repro.models import transformer as tf
 from repro.models.common import ModelConfig
+from repro.serve import tracing
 
 
 @jax.tree_util.register_dataclass
@@ -298,6 +299,10 @@ def evict(state: DecodeState, slot: int) -> DecodeState:
         last_token=state.last_token.at[slot].set(0))
 
 
+def _path(dispatch) -> Optional[str]:
+    return None if dispatch is None else dispatch.path
+
+
 class ContinuousBatchingEngine:
     """The ``init_decode_state → prefill → insert → generate``
     lifecycle as one object: a fixed-geometry decode batch whose rows
@@ -374,9 +379,18 @@ class ContinuousBatchingEngine:
         if toks.shape[1] > self.max_len:
             raise ValueError(f"prompt ({toks.shape[1]} tokens) exceeds "
                              f"cache max_len {self.max_len}")
-        side = init_decode_state(self.cfg, 1, self.max_len, self.dtype)
-        self._pending[slot] = {"tokens": toks, "pos": 0,
-                               "cache": side.cache}
+        with tracing.span("engine.begin_prefill", slot=slot) as sp:
+            self._reserve(slot, toks.shape[1])
+            side = init_decode_state(self.cfg, 1, self.max_len, self.dtype)
+            nbytes = sum(a.nbytes for a in jax.tree.leaves(side.cache))
+            sp["side_cache_bytes"] = nbytes
+            self._pending[slot] = {"tokens": toks, "pos": 0,
+                                   "cache": side.cache}
+        tracing.count("engine.side_cache_bytes", nbytes)
+
+    def _reserve(self, slot: int, n_tokens: int) -> None:
+        """Hook run as a lease begins, before its side cache exists
+        (the paged engine reserves the prompt's pages here)."""
 
     def _advance_prefills(self) -> list:
         """Run one prefill chunk per pending request; insert the ones
@@ -393,30 +407,41 @@ class ContinuousBatchingEngine:
             if self.plan is not None:
                 dispatch = self._demoted(self.plan.chunk_dispatch(
                     p["pos"] + piece.shape[1], piece.shape[1]))
-            self._check_kernel(dispatch)
-            logits, p["cache"] = self._launch("prefill", dispatch)(
-                self.params, jnp.asarray(piece), p["cache"],
-                jnp.int32(p["pos"]))
+            with tracing.span("engine.prefill", slot=slot,
+                              rows=piece.shape[1], offset=p["pos"],
+                              path=_path(dispatch)) as sp:
+                self._check_kernel(dispatch)
+                traces = tracing.counter("engine.launch_traces")
+                logits, p["cache"] = self._launch("prefill", dispatch)(
+                    self.params, jnp.asarray(piece), p["cache"],
+                    jnp.int32(p["pos"]))
+                sp["traced"] = \
+                    tracing.counter("engine.launch_traces") > traces
+            tracing.count("engine.prefill_chunks")
             p["pos"] += piece.shape[1]
             if p["pos"] >= total:
-                res = PrefillResult(
-                    cache=p["cache"],
-                    length=jnp.asarray(total, jnp.int32),
-                    next_token=greedy_sample(logits)[0])
-                self._insert(res, slot)
-                self.row_ctx[slot] = total
-                self.live[slot] = True
-                del self._pending[slot]
-                inserted.append((slot, int(res.next_token)))
+                with tracing.span("engine.insert", slot=slot):
+                    res = PrefillResult(
+                        cache=p["cache"],
+                        length=jnp.asarray(total, jnp.int32),
+                        next_token=greedy_sample(logits)[0])
+                    self._insert(res, slot)
+                    self.row_ctx[slot] = total
+                    self.live[slot] = True
+                    del self._pending[slot]
+                    inserted.append((slot, int(res.next_token)))
+                tracing.count("engine.inserts")
         self._insert_backlog = []
         return inserted
 
     def _insert(self, res: PrefillResult, slot: int) -> None:
         self.state = insert(self.state, res, slot)
 
-    def _before_decode(self) -> None:
+    def _before_decode(self) -> int:
         """Hook run right before each decode launch (the paged engine
-        grows page lists for rows crossing a page boundary here)."""
+        grows page lists for rows crossing a page boundary here).
+        Returns the rows whose block table grew."""
+        return 0
 
     def _launch(self, kind: str, dispatch):
         """The jitted launch for one legalised dispatch: ``"decode"``
@@ -434,17 +459,23 @@ class ContinuousBatchingEngine:
         if fn is not None:
             return fn
         cfg, interpret = self.cfg, self.interpret
+        # the bodies run only while JAX traces them: the counter counts
+        # (re)traces, never steady launches
         if kind == "decode":
             def fn(params, state, active):
-                return decode_step(
-                    params, cfg, state, dispatch=dispatch, active=active,
-                    interpret=interpret,
-                    block_tables=getattr(state, "block_tables", None))
+                tracing.count("engine.launch_traces")
+                with jax.named_scope("decode_step"):
+                    return decode_step(
+                        params, cfg, state, dispatch=dispatch,
+                        active=active, interpret=interpret,
+                        block_tables=getattr(state, "block_tables", None))
         else:
             def fn(params, tokens, cache, pos):
-                logits, cache = tf.forward(
-                    params, cfg, tokens=tokens, cache=cache,
-                    cache_len=pos, interpret=interpret, plan=dispatch)
+                tracing.count("engine.launch_traces")
+                with jax.named_scope("prefill_chunk"):
+                    logits, cache = tf.forward(
+                        params, cfg, tokens=tokens, cache=cache,
+                        cache_len=pos, interpret=interpret, plan=dispatch)
                 return logits[:, -1:], cache
         fn = self._launches[key] = jax.jit(fn)
         return fn
@@ -503,23 +534,36 @@ class ContinuousBatchingEngine:
         if not any(self.live):
             self.last_logits = None
             return None
-        self._before_decode()
-        dispatch = None
-        if self.plan is not None:
-            dispatch = self._demoted(self.plan.step_dispatch(
-                [c for c, alive in zip(self.row_ctx, self.live)
-                 if alive]))
-        self.last_dispatch = dispatch
-        self._check_kernel(dispatch)
-        new_state, logits = self._launch("decode", dispatch)(
-            self.params, self.state, jnp.asarray(self.live))
-        self.state = new_state
-        self.last_logits = np.asarray(logits)
-        self._inject_nan()
-        for i in range(self.batch_size):
-            if self.live[i]:
-                self.row_ctx[i] += 1
-        return np.asarray(self.state.last_token)
+        with tracing.span("engine.decode", rows=sum(self.live)) as sp:
+            with tracing.span("engine.decode.prepare") as prep:
+                prep["rows_grown"] = self._before_decode()
+            tracing.count("engine.table_rows_grown", prep["rows_grown"])
+            with tracing.span("engine.decode.launch") as launch:
+                dispatch = None
+                if self.plan is not None:
+                    dispatch = self._demoted(self.plan.step_dispatch(
+                        [c for c, alive in zip(self.row_ctx, self.live)
+                         if alive]))
+                sp["path"] = _path(dispatch)
+                self.last_dispatch = dispatch
+                self._check_kernel(dispatch)
+                traces = tracing.counter("engine.launch_traces")
+                new_state, logits = self._launch("decode", dispatch)(
+                    self.params, self.state, jnp.asarray(self.live))
+                launch["traced"] = \
+                    tracing.counter("engine.launch_traces") > traces
+            tracing.count("engine.decode_launches")
+            self.state = new_state
+            with tracing.span("engine.decode.readback") as rb:
+                self.last_logits = np.asarray(logits)
+                self._inject_nan()
+                tokens = np.asarray(self.state.last_token)
+                rb["bytes"] = self.last_logits.nbytes + tokens.nbytes
+            tracing.count("engine.readback_bytes", rb["bytes"])
+            for i in range(self.batch_size):
+                if self.live[i]:
+                    self.row_ctx[i] += 1
+        return tokens
 
     def step(self):
         """One scheduler step: advance every pending prefill by one
@@ -569,11 +613,13 @@ class ContinuousBatchingEngine:
         # period-stacked scan caches — the layout ``insert`` scatters
         kv = {"prefix": jax.tree.map(take(0), self.state.cache["prefix"]),
               "scan": jax.tree.map(take(1), self.state.cache["scan"])}
-        pre = PreemptedRequest(
-            kv=jax.device_get(kv), n_pages=0,
-            length=self.row_ctx[slot],
-            last_token=int(np.asarray(self.state.last_token)[slot]))
-        self.evict(slot)
+        with tracing.span("engine.preempt", slot=slot):
+            pre = PreemptedRequest(
+                kv=jax.device_get(kv), n_pages=0,
+                length=self.row_ctx[slot],
+                last_token=int(np.asarray(self.state.last_token)[slot]))
+            self._clear_row(slot)
+        tracing.count("engine.preemptions")
         return pre
 
     def resume(self, pre: "PreemptedRequest", slot: int) -> None:
@@ -581,18 +627,27 @@ class ContinuousBatchingEngine:
         request continues bit-identically, no prefill recompute."""
         if self.live[slot] or slot in self._pending:
             raise ValueError(f"slot {slot} is not free")
-        res = PrefillResult(
-            cache=jax.tree.map(jnp.asarray, pre.kv),
-            length=jnp.asarray(pre.length, jnp.int32),
-            next_token=jnp.asarray(pre.last_token, jnp.int32))
-        self._insert(res, slot)
-        self.row_ctx[slot] = pre.length
-        self.live[slot] = True
+        with tracing.span("engine.resume", slot=slot):
+            res = PrefillResult(
+                cache=jax.tree.map(jnp.asarray, pre.kv),
+                length=jnp.asarray(pre.length, jnp.int32),
+                next_token=jnp.asarray(pre.last_token, jnp.int32))
+            self._insert(res, slot)
+            self.row_ctx[slot] = pre.length
+            self.live[slot] = True
+        tracing.count("engine.resumes")
 
     def evict(self, slot: int) -> None:
         """Reclaim ``slot`` (request finished or cancelled): frees the
         row for the next ``begin_prefill`` without touching any other
         row's cache."""
+        with tracing.span("engine.evict", slot=slot):
+            self._clear_row(slot)
+        tracing.count("engine.evictions")
+
+    def _clear_row(self, slot: int) -> None:
+        """Zero row ``slot``'s position and token and its host mirrors
+        (what evict and preempt share)."""
         self.state = evict(self.state, slot)
         self.row_ctx[slot] = 0
         self.live[slot] = False
@@ -933,19 +988,14 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
 
     # -- lifecycle overrides -----------------------------------------------
 
-    def begin_prefill(self, slot: int, prompt) -> None:
-        """Lease ``slot`` AND reserve the prompt's pages (plus the
-        first decoded token's — the quantity ``can_admit_tokens``
-        checks).  The prefill itself runs on a dense side cache over
-        the following steps; the reservation guarantees the pool can
-        take the result no matter how the live rows grow meanwhile."""
-        super().begin_prefill(slot, prompt)
-        try:
-            self.allocator.alloc(
-                slot, self.allocator.pages_for(len(prompt) + 1))
-        except OutOfPages:
-            del self._pending[slot]
-            raise
+    def _reserve(self, slot: int, n_tokens: int) -> None:
+        """A lease reserves the prompt's pages (plus the first decoded
+        token's — the quantity ``can_admit_tokens`` checks) before its
+        side cache exists.  The prefill itself runs on a dense side
+        cache over the following steps; the reservation guarantees the
+        pool can take the result no matter how the live rows grow
+        meanwhile.  Raises :class:`OutOfPages` with nothing leased."""
+        self.allocator.alloc(slot, self.allocator.pages_for(n_tokens + 1))
 
     def _insert(self, res: PrefillResult, slot: int) -> None:
         self.state = insert_paged(self.state, res, slot,
@@ -981,8 +1031,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                                              block_tables=tbl)
             for i, start, new in updates:
                 self._table_pages[i] = start + len(new)
+        return len(updates)
 
-    def evict(self, slot: int) -> None:
+    def _clear_row(self, slot: int) -> None:
         self.allocator.release(slot)
         self.state = evict_paged(self.state, slot)
         self.row_ctx[slot] = 0
@@ -995,27 +1046,27 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         through :meth:`resume` without any recompute."""
         if not self.live[slot]:
             raise ValueError(f"slot {slot} is not live")
-        ids = list(self.allocator.pages[slot])
-        pre = PreemptedRequest(
-            kv=jax.device_get(gather_slot_pages(self.state, ids)),
-            n_pages=len(ids),
-            length=self.row_ctx[slot],
-            last_token=int(self.state.last_token[slot]))
-        self.allocator.release(slot)
-        self.state = evict_paged(self.state, slot)
-        self.row_ctx[slot] = 0
-        self.live[slot] = False
-        self._table_pages[slot] = 0
+        with tracing.span("engine.preempt", slot=slot):
+            ids = list(self.allocator.pages[slot])
+            pre = PreemptedRequest(
+                kv=jax.device_get(gather_slot_pages(self.state, ids)),
+                n_pages=len(ids),
+                length=self.row_ctx[slot],
+                last_token=int(self.state.last_token[slot]))
+            self._clear_row(slot)
+        tracing.count("engine.preemptions")
         return pre
 
     def resume(self, pre: PreemptedRequest, slot: int) -> None:
         """Re-admit a preempted snapshot into free slot ``slot``."""
         if self.live[slot] or slot in self._pending:
             raise ValueError(f"slot {slot} is not free")
-        ids = self.allocator.alloc(slot, pre.n_pages)
-        self.state = resume_paged(self.state, pre, slot, ids)
-        self.row_ctx[slot] = pre.length
-        self.live[slot] = True
-        self._table_pages[slot] = len(ids)
-        self._lease_clock += 1
-        self.lease_order[slot] = self._lease_clock
+        with tracing.span("engine.resume", slot=slot):
+            ids = self.allocator.alloc(slot, pre.n_pages)
+            self.state = resume_paged(self.state, pre, slot, ids)
+            self.row_ctx[slot] = pre.length
+            self.live[slot] = True
+            self._table_pages[slot] = len(ids)
+            self._lease_clock += 1
+            self.lease_order[slot] = self._lease_clock
+        tracing.count("engine.resumes")

@@ -222,7 +222,7 @@ class ServingSupervisor:
                 self.lease_step.setdefault(req.uid, self.t)
             except OutOfPages as e:
                 # the lease never took (alloc is all-or-nothing, and
-                # begin_prefill rolls its pending entry back): un-admit
+                # begin_prefill reserves before it leases): un-admit
                 # and defer the head to a later, calmer step
                 self.batcher.slots[slot] = None
                 self.batcher.slot_lens[slot] = 0

@@ -94,7 +94,7 @@ def test_bench_diff_reports_polarity_aware_deltas():
     assert bench_diff.field_polarity("step_ms") == -1
     assert bench_diff.field_polarity("tokens_s") == 1
     # the committed baseline snapshot stays diffable against itself
-    snap = REPO / "benchmarks" / "baselines" / "BENCH_serving.json"
+    snap = REPO / "benchmarks" / "baselines" / "BENCH_lowering.json"
     same = json.loads(snap.read_text())
     self_diff = bench_diff.diff_artifacts(same, same)
     assert all(not r["deltas"] for r in self_diff["rows"])

@@ -173,3 +173,19 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     fn, args = CASES[case](s)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+#: the paged decode kernels at starcoder2-7b widths, by the name their
+#: pallas_call gives them
+NAMED = {"fused_decode_block_paged": _decode_block_paged,
+         "fused_attention_paged": _paged}
+
+
+@pytest.mark.parametrize("name", sorted(NAMED))
+def test_kernel_name_reaches_the_compiled_hlo(name, one_chip):
+    def s(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args = NAMED[name](s)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and name in text

@@ -4,8 +4,8 @@ matched by ``name`` and every shared numeric field is reported as an
 absolute and relative delta, so a bench regression shows up as one
 readable line per metric instead of a JSON eyeball-diff.
 
-    python tools/bench_diff.py benchmarks/baselines/BENCH_serving.json \\
-        BENCH_serving.json
+    python tools/bench_diff.py benchmarks/baselines/BENCH_lowering.json \\
+        BENCH_lowering.json
 
 Rows present on only one side are listed as added/removed.  With
 ``--fail-over PCT`` the exit code is non-zero when any field named by
