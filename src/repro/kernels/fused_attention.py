@@ -672,6 +672,23 @@ def _rope_tile(q, pos0, theta: float):
                             x2 * cos + x1 * sin], axis=-1)
 
 
+def _rope_tile_at(q, pos, theta: float):
+    """:func:`_rope_tile` with every row at the one rotary position
+    ``pos``: the rows of a GQA group's Q tile are the query heads of a
+    single token."""
+    bq, d = q.shape
+    half = d // 2
+    idx = jax.lax.broadcasted_iota(jnp.int32, (bq, half), 1)
+    freqs = jnp.exp(idx.astype(jnp.float32)
+                    * (-math.log(theta) / half))
+    ang = jnp.full((bq, half), pos, jnp.int32).astype(jnp.float32) * freqs
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1 = q[:, :half].astype(jnp.float32)
+    x2 = q[:, half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin,
+                            x2 * cos + x1 * sin], axis=-1)
+
+
 def _round_up(n: int, m: int = LANES) -> int:
     return max(m, ((n + m - 1) // m) * m)
 

@@ -212,7 +212,7 @@ def _paged_unsupported(x, lengths, block_tables, causal: bool, q_offset,
                        sq: int, page: int) -> Optional[str]:
     """Reason string when the paged Pallas kernels cannot serve this
     call, else None.  Paged kernels inherit every masked-kernel
-    constraint (they share the kernel body) plus the block-table
+    constraint (they mask the same way) plus the block-table
     contract: a 2-D integral (B, max_pages) table and a sublane-aligned
     page size."""
     if lengths is None:
@@ -450,7 +450,8 @@ def decode_block(x, wq, k, v, wo, residual, lengths, *,
     math from the streaming-XLA / reference pieces (identical numerics,
     more HBM round-trips).  ``block_tables``: (B, max_pages) page ids —
     k, v become pools (num_pages, Hkv, page, D[v]) and the one-launch
-    kernel fetches KV page-by-page through the table."""
+    kernel gathers KV through the table, ``block_k // page`` pages a
+    grid step."""
     b, sq, e = x.shape
     assert sq == 1, "decode_block is the M=1 decode schedule"
     hq, d = wq.shape[1], wq.shape[-1]
@@ -470,7 +471,7 @@ def decode_block(x, wq, k, v, wo, residual, lengths, *,
             else:
                 return _pallas_decode_block_paged(
                     x, wq, k, v, wo, residual, lengths, block_tables,
-                    scale=scale, rope_theta=rope_theta,
+                    scale=scale, rope_theta=rope_theta, block_k=block_k,
                     interpret=interpret)
         if impl == "reference":
             return _ref.paged_decode_block_reference(

@@ -443,6 +443,13 @@ class ContinuousBatchingEngine:
         Returns the rows whose block table grew."""
         return 0
 
+    def _megakernel_grid(self, dispatch) -> tuple[int, int]:
+        """(KV blocks scored, grid steps iterated) by this decode step's
+        paged megakernel launches, over every attention layer, counted
+        on the host; (0, 0) off that path.  The dense engine never runs
+        the paged kernel."""
+        return 0, 0
+
     def _launch(self, kind: str, dispatch):
         """The jitted launch for one legalised dispatch: ``"decode"``
         (one whole-batch token step) or ``"prefill"`` (one chunk into a
@@ -553,6 +560,10 @@ class ContinuousBatchingEngine:
                 launch["traced"] = \
                     tracing.counter("engine.launch_traces") > traces
             tracing.count("engine.decode_launches")
+            blocks, steps = self._megakernel_grid(dispatch)
+            if steps:
+                tracing.count("engine.decode_kv_blocks", blocks)
+                tracing.count("engine.decode_grid_steps", steps)
             self.state = new_state
             with tracing.span("engine.decode.readback") as rb:
                 self.last_logits = np.asarray(logits)
@@ -1032,6 +1043,27 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             for i, start, new in updates:
                 self._table_pages[i] = start + len(new)
         return len(updates)
+
+    def _megakernel_grid(self, dispatch) -> tuple[int, int]:
+        """From ``row_ctx`` and the static shapes, no device sync: each
+        live row scores ``ceil(ctx / (ppb * page))`` blocks per KV head,
+        where ``ctx`` counts the token this step appends; the grid is
+        (KV heads, batch rows, blocks).  Their ratio is the share of the
+        grid that does work."""
+        if dispatch is None or not dispatch.fuse_wo \
+                or dispatch.impl != "pallas":
+            return 0, 0
+        from repro.kernels.fused_decode_block import paged_blocks
+        cfg = self.cfg
+        ppb, n_blocks = paged_blocks(dispatch.block_k, self.page_size,
+                                     self.state.block_tables.shape[1])
+        bk = ppb * self.page_size
+        heads_layers = cfg.n_kv_heads * sum(
+            cfg.block_kind(i) == "attn" for i in range(cfg.n_layers))
+        live = sum(-(-(c + 1) // bk)
+                   for c, alive in zip(self.row_ctx, self.live) if alive)
+        return (live * heads_layers,
+                self.batch_size * n_blocks * heads_layers)
 
     def _clear_row(self, slot: int) -> None:
         self.allocator.release(slot)

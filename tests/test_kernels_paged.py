@@ -130,6 +130,60 @@ def test_paged_qproj_and_decode_block_match_oracles():
                                rtol=2e-5, atol=2e-5)
 
 
+MEGAKERNEL_SWEEP = [
+    # dtype, hq, hkv, page, max_pages, block_k, lengths
+    # group 1, 3 pages a block over 7 (not a multiple): 0, 1, page - 1,
+    # page, a block edge - 1 / at / + 1, the full table
+    (jnp.float32, 2, 2, 8, 7, 24, [0, 1, 7, 8, 23, 24, 25, 56]),
+    (jnp.bfloat16, 2, 2, 8, 7, 24, [0, 1, 7, 8, 23, 24, 25, 56]),
+    # group 4, 2 pages a block over 5; two length-0 rows in a row break
+    # the prefetch chain
+    (jnp.float32, 8, 2, 16, 5, 32, [31, 0, 0, 33, 80, 16, 1, 32]),
+    (jnp.bfloat16, 8, 2, 16, 5, 32, [31, 0, 0, 33, 80, 16, 1, 32]),
+    # group 9 (starcoder2's 36/4) padded to 16 sublanes; a KV block
+    # longer than the table is clamped to it
+    (jnp.float32, 9, 1, 8, 6, 32, [48, 31, 33, 0, 9, 15]),
+    (jnp.bfloat16, 18, 2, 16, 4, 1024, [64, 0, 17, 16, 63]),
+]
+
+
+@pytest.mark.parametrize("dtype,hq,hkv,page,max_pages,block_k,lengths",
+                         MEGAKERNEL_SWEEP)
+def test_paged_decode_block_retiled_matches_oracle(dtype, hq, hkv, page,
+                                                   max_pages, block_k,
+                                                   lengths):
+    """The paged megakernel's (KV head, row, block of pages) grid ==
+    the gather-dense oracle: scattered, non-monotone page ids, page 0
+    in every slot past a row's length, rows of length 0 emitting the
+    residual."""
+    b, d, e = len(lengths), 32, 64
+    n_pages = b * max_pages + 1
+    kp, vp, tbl = _pools(b, hkv, n_pages, page, d, max_pages, seed=hq)
+    live = np.arange(max_pages)[None, :] < -(
+        -np.asarray(lengths)[:, None] // page)
+    tbl = jnp.asarray(np.where(live, np.asarray(tbl), 0), jnp.int32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(hq * 100 + max_pages), 4)
+    x = jax.random.normal(ks[0], (b, 1, e), jnp.float32)
+    wq = jax.random.normal(ks[1], (e, hq, d), jnp.float32) * 0.2
+    wo = jax.random.normal(ks[2], (hq, d, e), jnp.float32) * 0.2
+    res = jax.random.normal(ks[3], (b, 1, e), jnp.float32)
+    args = [a.astype(dtype) for a in (x, wq, kp, vp, wo, res)]
+    y = fused_decode_block_paged(*args[:5], args[5], lens, tbl,
+                                 rope_theta=1e4, block_k=block_k,
+                                 interpret=True)
+    assert y.dtype == dtype and y.shape == (b, 1, e)
+    y_ref = ref.paged_decode_block_reference(
+        *[a.astype(jnp.float32) for a in args[:6]], lens, tbl,
+        rope_theta=1e4)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(y_ref), rtol=tol, atol=tol)
+    dead = np.asarray(lengths) == 0
+    np.testing.assert_array_equal(np.asarray(y)[dead],
+                                  np.asarray(args[5])[dead])
+
+
 def test_paged_dispatch_zero_downgrades_and_per_reason_warn_once():
     """ops.attention with block_tables stays on the Pallas path (no
     downgrade warning); an *unsupported* paged call warns exactly once

@@ -145,6 +145,22 @@ def _decode_block_paged(s):
         (x, wq, pool, pool, wo, x, s((B,), I32), _tables(s))
 
 
+def _decode_block_paged_at(b, max_len, bk):
+    """The paged megakernel at a benchmark cell's batch and depth, with
+    the plan's KV block for its deepest bucket: a VMEM overrun of the
+    per-KV-head Wq and Wo blocks fails here, not on the chip."""
+    def build(s):
+        x, wq, wo = s((b, 1, E)), s((E, HQ, D)), s((HQ, D, E))
+        pool = s((b * max_len // PAGE + 1, HKV, PAGE, D))
+        return (lambda x, wq, k, v, wo, r, n, t:
+                fdb.fused_decode_block_paged(x, wq, k, v, wo, r, n, t,
+                                             rope_theta=THETA,
+                                             block_k=bk)), \
+            (x, wq, pool, pool, wo, x, s((b,), I32),
+             s((b, max_len // PAGE), I32))
+    return build
+
+
 CASES = {
     # training / cacheless forward: the lse residual's block layout
     "fused_attention_fwd": _unmasked_fwd,
@@ -162,6 +178,11 @@ CASES = {
     "fused_decode_block_512": _decode_block(512),
     "fused_decode_block_1024": _decode_block(1024),
     "fused_decode_block_paged": _decode_block_paged,
+    # the benchmark's starcoder2-7b decode cells: batch-decode, completion
+    "fused_decode_block_paged_b32_2048": _decode_block_paged_at(32, 2048,
+                                                                1024),
+    "fused_decode_block_paged_b16_4096": _decode_block_paged_at(16, 4096,
+                                                                1024),
 }
 
 

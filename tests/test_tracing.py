@@ -155,6 +155,47 @@ def test_counters_match_a_hand_count(qwen):
         == [readback] * 3
 
 
+def test_megakernel_grid_counters_match_a_hand_count(qwen):
+    """starcoder2's smoke widths (2 layers, 2 KV heads) on a paged
+    engine of 3 rows, max_len 2048, pages of 16: every decode step takes
+    the megakernel with the plan's 1,024-token KV block, 64 pages, so
+    the grid is 2 heads x 3 rows x 2 blocks a layer.  Rows prefilled to
+    1,022, 1,031 and 200 tokens score contexts 1,023 / 1,032 / 201 (1,
+    2 and 1 blocks), then 1,024 / 1,033 / 202, then 1,025 / 1,034 / 203:
+    row 0 crosses the block edge on the third step.  The qk-norm qwen3
+    engine never reaches the megakernel and counts neither."""
+    cfg = configs.get_config("starcoder2-7b", smoke=True)
+    params, _ = init_params_and_axes(jax.random.PRNGKey(0), cfg)
+    plan = make_serving_plan(cfg, 2048, interpret=True, paged=True,
+                             page_size=16)
+    eng = PagedContinuousBatchingEngine(
+        params, cfg, batch_size=3, max_len=2048, page_size=16,
+        num_pages=3 * 128 + 1, plan=plan, interpret=True)
+    for slot, n in enumerate([1022, 1031, 200]):
+        eng.begin_prefill(slot, _prompt(cfg, 80 + slot, n))
+    eng._advance_prefills()
+    kv, grid = [], []
+    for _ in range(3):
+        eng.decode_once()
+        assert eng.last_dispatch.path == "decode_megakernel"
+        kv.append(tracing.counter("engine.decode_kv_blocks"))
+        grid.append(tracing.counter("engine.decode_grid_steps"))
+    per_step = 2 * 3 * 2 * cfg.n_layers
+    assert grid == [per_step, 2 * per_step, 3 * per_step]
+    heads_layers = 2 * cfg.n_layers
+    assert kv == [4 * heads_layers, 8 * heads_layers, 13 * heads_layers]
+
+    qcfg, qparams = qwen
+    tracing.reset()
+    q = _engine(qcfg, qparams, num_pages=8)
+    q.begin_prefill(0, _prompt(qcfg, 70, 40))
+    for _ in range(3):
+        q.step()
+    assert tracing.counter("engine.decode_launches") == 3
+    assert tracing.counter("engine.decode_kv_blocks") == 0
+    assert tracing.counter("engine.decode_grid_steps") == 0
+
+
 def test_launch_traces_stay_put_on_a_steady_step(qwen):
     cfg, params = qwen
     eng = _engine(cfg, params, num_pages=8)
