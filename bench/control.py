@@ -9,8 +9,8 @@ is freed, the plain float32 reference over the sampled requests' prompts
 and served tokens.  Each row gives the program's two compared numbers
 (the widest logit gap of a served token, the largest relative distance
 of its logits) and the control's: the same reference with every tensor
-the program holds in bf16 held in fp8 (``harness/reference.py``),
-teacher-forced on the same tokens, read at the same positions.  The
+the program holds in bf16 held in fp8 (the architecture module's
+``final_hidden`` with ``lowp``, ``bench/arch/<arch>.py``), teacher-forced on the same tokens, read at the same positions.  The
 benchmark's runs never run the control; these rows set the cell's
 limits.
 """

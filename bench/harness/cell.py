@@ -22,7 +22,6 @@ from typing import Callable, Optional
 from harness import check, stats, trace, traffic
 from harness.driver import Driver
 from harness.manifest import Manifest
-from harness.model import Dims, make_params, program_config
 from harness.recorder import engine_class
 
 
@@ -32,7 +31,8 @@ class RunData:
     timeline: object
     spans: list
     events: Optional[dict]
-    dims: Dims
+    arch: object                # the cell's bench/arch/<arch>.py
+    dims: object                # its dims(cfg)
     peaks: dict
     kernels: list
     setup_s: float
@@ -154,7 +154,8 @@ class Session:
         self.mix = mix or man.traffic(self.cell["traffic"])
         eng_kw = self.mix["engine"]
         self.max_len = int(eng_kw["max_len"])
-        self.dims = Dims.from_config(cfg_json)
+        self.arch = man.arch(cfg_json["arch"])
+        self.dims = self.arch.dims(cfg_json)
         self.devs = jax.devices()
         self.dev = self.devs[0]
         self.peaks = man.peaks(self.dev.device_kind)
@@ -163,17 +164,15 @@ class Session:
             jax.config.update("jax_persistent_cache_min_compile_time_secs",
                               0)
         say(f"cell {cell_name}: config {self.cell['config']} "
-            f"({self.dims.layers} layers, d_model {self.dims.d_model}, "
-            f"{self.dims.heads}/{self.dims.kv_heads} heads, vocab "
-            f"{self.dims.vocab}, {self.dims.dtype}), traffic "
+            f"({self.arch.describe(self.dims)}), traffic "
             f"{self.cell['traffic']} ({self.mix['loop']} loop), engine "
             f"{eng_kw}, seed {seed}")
         t = time.perf_counter()
-        self.params = make_params(self.dims, seed)
+        self.params = self.arch.make_params(self.dims, seed)
         jax.block_until_ready(self.params)
         self.t_weights = time.perf_counter() - t
         self.engine = make_engine(
-            self.params, program_config(cfg_json),
+            self.params, self.arch.program_config(cfg_json),
             batch=int(eng_kw["batch"]), max_len=self.max_len,
             prefill_chunk=int(eng_kw["prefill_chunk"]), paged=True,
             page_size=int(eng_kw["page_size"]),
@@ -283,9 +282,9 @@ class Session:
         uids = check.sample(reqs, self.seed, int(chk["sample_tokens"]),
                             int(chk["max_sequences"]))
         t = time.perf_counter()
-        read = check.readings(self.params, self.dims, reqs, logits, uids,
-                              self.max_len, int(chk["max_sequences"]),
-                              control=control)
+        read = check.readings(self.arch, self.params, self.dims, reqs,
+                              logits, uids, self.max_len,
+                              int(chk["max_sequences"]), control=control)
         say(f"check: {len(uids)} requests, {read['tokens_checked']} served "
             f"tokens against the float32 reference in "
             f"{time.perf_counter() - t:.3f}s")
@@ -323,9 +322,9 @@ def run(man: Manifest, cell_name: str, seed: int, seconds: float,
     ok = ok and not tl.failed
 
     events = s["events"]
-    data = RunData(timeline=tl, spans=spans, events=events, dims=ses.dims,
-                   peaks=ses.peaks, kernels=man.kernels(), setup_s=setup_s,
-                   peak_bytes=peak, notes=[])
+    data = RunData(timeline=tl, spans=spans, events=events, arch=ses.arch,
+                   dims=ses.dims, peaks=ses.peaks, kernels=man.kernels(),
+                   setup_s=setup_s, peak_bytes=peak, notes=[])
     metrics = {}
     for m in (man.per_layer(cell_name) if traced
               else man.end_to_end(cell_name)):

@@ -24,7 +24,6 @@ from __future__ import annotations
 import numpy as np
 
 from harness import reference
-from harness.model import Dims
 
 
 def sample(reqs: dict, seed: int, want_tokens: int, max_seqs: int) -> list:
@@ -85,10 +84,11 @@ def arrays(reqs: dict, logits: dict, uids: list, max_len: int,
     return tokens, pos, tgt, mask, prog, lmask
 
 
-def readings(params, d: Dims, reqs: dict, logits: dict, uids: list,
+def readings(arch, params, d, reqs: dict, logits: dict, uids: list,
              max_len: int, n_seqs: int, control: bool = False) -> dict:
     """The numbers compared (``logit_gap``, ``logit_rel``) and
-    ``tokens_checked``; with ``control``, the control's (``control_gap``,
+    ``tokens_checked``, from the reference of the architecture module
+    ``arch``; with ``control``, the control's (``control_gap``,
     ``control_rel``) at the same positions, and ``altered_gap``, the
     gap that every served token altered (plus one) would read."""
     import jax.numpy as jnp
@@ -98,8 +98,8 @@ def readings(params, d: Dims, reqs: dict, logits: dict, uids: list,
     tokens, pos, tgt, mask, prog, lmask = arrays(reqs, logits, uids,
                                                  max_len, n_seqs)
     g, cg, rel, crel, alt = (np.asarray(a) for a in reference.readings(
-        params, d, jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(tgt),
-        jnp.asarray(prog), control=control))
+        arch, params, d, jnp.asarray(tokens), jnp.asarray(pos),
+        jnp.asarray(tgt), jnp.asarray(prog), control=control))
     out = {"logit_gap": float(g[mask].max()),
            "logit_rel": float(rel[lmask].max()) if lmask.any()
            else float("inf"),

@@ -1,10 +1,11 @@
-"""Model operation counts and kernel roofline shares, from recorded spans.
+"""Model FLOP utilisation and kernel roofline shares, from recorded spans.
 
 A decode span carries the contexts its live rows attended; a prefill
 span its rows and the tokens already in the side cache.  The counts are
 the model's useful work: live rows only, one row of logits per prefill
 chunk (the one the program returns), causal attention over each row's
-own columns.
+own columns.  The model's FLOPs a step and its layer calls through each
+kernel come from the cell's architecture module (``run.arch``).
 """
 
 from __future__ import annotations
@@ -12,24 +13,12 @@ from __future__ import annotations
 from typing import Optional
 
 from harness import trace
-from harness.model import Dims
 
 
 def causal_cols(rows: int, offset: int) -> int:
     """Score columns of ``rows`` causal query rows after ``offset``
     cached tokens: row r attends offset + r + 1 of them."""
     return rows * offset + rows * (rows + 1) // 2
-
-
-def step_flops(d: Dims, span) -> float:
-    """Model FLOPs of one decode step or one prefill chunk."""
-    attn = 4 * d.layers * d.heads * d.head_dim
-    dense = 2 * d.layers * d.layer_params
-    head = 2 * d.d_model * d.vocab
-    if span.kind == "decode":
-        return span.rows * (dense + head) + attn * sum(span.contexts)
-    return (span.rows * dense + head
-            + attn * causal_cols(span.rows, span.offset))
 
 
 def mfu(run, kind: str) -> Optional[float]:
@@ -39,7 +28,7 @@ def mfu(run, kind: str) -> Optional[float]:
     secs = sum(s.t1 - s.t0 for s in spans)
     if not spans or secs <= 0:
         return None
-    flops = sum(step_flops(run.dims, s) for s in spans)
+    flops = sum(run.arch.step_flops(run.dims, s) for s in spans)
     return 100.0 * flops / secs / run.peaks["bf16_flops"]
 
 
@@ -58,9 +47,10 @@ def roofline(run, phase: str) -> Optional[float]:
         if k is None:
             continue
         f, b = k.cost(run.dims, s)
-        least += run.dims.layers * max(f / run.peaks["bf16_flops"],
-                                       b / run.peaks["hbm_bytes_per_s"])
-        calls += run.dims.layers
+        n = run.arch.layer_calls(run.dims, k.PATH)
+        least += n * max(f / run.peaks["bf16_flops"],
+                         b / run.peaks["hbm_bytes_per_s"])
+        calls += n
         used[k.EVENT] = k
     if not used:
         return None

@@ -3,8 +3,22 @@
 A configuration is ``bench/configs/<config>.json``, a traffic mix is
 ``bench/traffic/<traffic>.json``, a per-layer metric is
 ``bench/metrics/<metric>.py`` and a kernel's operation and byte count is
-one ``bench/kernels/<kernel>.py`` each.  Adding any of them is adding a
-file and an entry; no file here names a cell, a model or a metric.
+one ``bench/kernels/<kernel>.py`` each.  A configuration file names its
+architecture with the key ``"arch"``, and ``bench/arch/<arch>.py`` holds
+every fact of it the benchmark needs.  Adding any of them is adding a
+file and an entry; no file here names a cell, a model, an architecture
+or a metric.
+
+An architecture module gives (``bench/arch/gqa.py`` says each in full):
+``dims(cfg)``, a frozen, hashable dataclass of the sizes with at least
+``vocab``; ``describe(d)``, the sizes for the set-up line;
+``make_params(d, seed)``, every weight from the seed in one jitted call,
+``lm_head`` at the top of the tree; ``program_config(cfg)``, the
+program's ``ModelConfig``; ``final_hidden(params, d, tokens, read_pos,
+lowp)``, the plain reference's normed final hidden state (``lowp``: the
+fp8 control's); ``step_flops(d, span)``, model FLOPs of one recorded
+step; ``layer_calls(d, path)``, the layer calls of one step through the
+kernel of dispatch path ``path``.
 """
 
 from __future__ import annotations
@@ -12,6 +26,7 @@ from __future__ import annotations
 import importlib.util
 import json
 import pathlib
+import sys
 from typing import Optional
 
 #: the checkout root: bench/harness/manifest.py -> parents[2]
@@ -23,6 +38,7 @@ class Manifest:
         self.root = pathlib.Path(root or ROOT)
         self.data = json.loads((self.root / "BENCHMARK.json").read_text())
         self.bench = self.root / "bench"
+        self._arch: dict = {}
 
     # -- entries -----------------------------------------------------------
 
@@ -57,6 +73,13 @@ class Manifest:
         return json.loads((self.bench / "traffic" / f"{name}.json")
                           .read_text())
 
+    def arch(self, name: str):
+        """The architecture module ``bench/arch/<name>.py``, loaded once
+        per manifest (its reference caches compiled layers)."""
+        if name not in self._arch:
+            self._arch[name] = load_module(self.bench / "arch" / f"{name}.py")
+        return self._arch[name]
+
     def metric_reader(self, name: str):
         """The ``read(run)`` function of ``bench/metrics/<name>.py``."""
         return load_module(self.bench / "metrics" / f"{name}.py").read
@@ -76,9 +99,11 @@ class Manifest:
 
 def load_module(path: pathlib.Path):
     """Import one file by path (metric and kernel files are named by
-    metric and kernel names, which may hold dots)."""
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_").replace("-", "_"), path)
+    metric and kernel names, which may hold dots).  It is put in
+    ``sys.modules`` before it runs, as a dataclass defined in it needs."""
+    name = "bench_" + path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod

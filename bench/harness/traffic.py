@@ -1,8 +1,9 @@
 """The one traffic generator: every mix is a JSON file of parameters.
 
-A mix gives the loop (``open``: Poisson arrivals at ``rate`` requests a
-second; ``closed``: ``clients`` callers that each send their next
-request when the last one finishes), the prompt and output length
+A mix gives the loop (``open``: arrivals at a mean ``rate`` requests a
+second, Poisson unless ``arrivals`` names another process; ``closed``:
+``clients`` callers that each send their next request when the last one
+finishes), the prompt and output length
 distributions, the grid prompt lengths sit on, and how many rows the
 load holds in steady state (``fill_rows``).
 
@@ -60,6 +61,22 @@ def draw_lengths(spec: dict, n: int, rng: np.random.Generator,
     return _grid(np.clip(x, lo, hi), grid, lo, hi)
 
 
+def arrival_gaps(mix: dict, n: int, rng: np.random.Generator) -> np.ndarray:
+    """``n`` gaps between open-loop arrivals at the mix's mean ``rate``.
+    Without ``arrivals``, or with ``{"dist": "exponential"}``, they are
+    exponential (Poisson arrivals); with ``{"dist": "gamma", "cv": c}``
+    Gamma with shape 1/c^2 and scale c^2/rate: the mean gap stays 1/rate
+    and c is the gaps' coefficient of variation (c > 1: bursts)."""
+    rate = float(mix["rate"])
+    spec = mix.get("arrivals", {"dist": "exponential"})
+    if spec["dist"] == "exponential":
+        return rng.exponential(1.0 / rate, size=n)
+    if spec["dist"] == "gamma":
+        cv2 = float(spec["cv"]) ** 2
+        return rng.gamma(1.0 / cv2, cv2 / rate, size=n)
+    raise ValueError(f"unknown arrival process {spec['dist']!r}")
+
+
 @dataclasses.dataclass
 class Plan:
     fill: list                      # steady-state rows, prefilled in set-up
@@ -84,7 +101,7 @@ def make_plan(mix: dict, seed: int, vocab: int, max_len: int) -> Plan:
     f_age = (pool_rng.uniform(size=n_fill) * f_out).astype(int)
     gaps = None
     if mix["loop"] == "open":
-        gaps = pool_rng.exponential(1.0 / float(mix["rate"]), size=n)
+        gaps = arrival_gaps(mix, n, pool_rng)
 
     rng = np.random.default_rng(seed)
     uid = 0

@@ -4,9 +4,11 @@ set-up.
     python3 bench/sweep.py --workload <cell> --seed <n> --rates 0.1,0.2 \
         [--seconds 51]
 
-For each Poisson rate the engine is emptied, the cell's steady-state rows
+For each mean rate the engine is emptied, the cell's steady-state rows
 are filled again, and the cell's traffic is offered at that rate for the
-window.  A row is printed per rate: requests due and finished, tokens/s,
+window, with the mix's own arrival process (Poisson, or the Gamma gaps
+its ``arrivals`` names: the same draws, scaled to the rate).  A row is
+printed per rate: requests due and finished, tokens/s,
 the TTFT median and 90th percentile, the gap 95th percentile, the number
 of requests waiting for a slot at the start and the end of the window
 and its least-squares slope, and the mean scheduler step with at least
@@ -52,7 +54,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rates", required=True,
-                    help="comma-separated Poisson rates, requests/s")
+                    help="comma-separated mean rates, requests/s")
     ap.add_argument("--seconds", type=float, default=51.0)
     ap.add_argument("--write", action="store_true",
                     help="write 0.8 x the knee into the traffic file")

@@ -16,7 +16,8 @@ sys.path.insert(0, str(ROOT / "bench"))
 sys.path.insert(0, str(ROOT / "src"))
 
 TINY_CONFIG = {
-    "name": "tiny", "model_type": "starcoder2", "hidden_size": 64,
+    "name": "tiny", "model_type": "starcoder2", "arch": "gqa",
+    "hidden_size": 64,
     "intermediate_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2,
     "vocab_size": 256, "rope_theta": 10000.0,

@@ -62,7 +62,8 @@ def test_add_files_and_entries_only(tmp_path):
                       contexts=(4,))]
         events = {"host": [["window", 0, 100], ["decode_step", 0, 100]],
                   "device": [["k.1 custom-call:made_up", 0, 50]]}
-        dims = cell.Dims.from_config(m.config("tiny"))
+        arch = m.arch("gqa")
+        dims = arch.dims(m.config("tiny"))
         peaks = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e12}
         kernels = m.kernels()
         notes: list = []

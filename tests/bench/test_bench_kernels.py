@@ -1,5 +1,6 @@
 """Each kernel's FLOP and byte count (bench/kernels/) against a hand
-computation, and the model FLOPs behind mfu.* (bench/harness/counts.py)."""
+computation, and the model FLOPs behind mfu.* (bench/harness/counts.py,
+with the GQA architecture's bench/arch/gqa.py)."""
 
 import pathlib
 
@@ -7,15 +8,16 @@ import pytest
 
 from harness import counts
 from harness.manifest import load_module
-from harness.model import Dims
 from harness.recorder import Span
 
-KERNELS = pathlib.Path(__file__).parents[2] / "bench" / "kernels"
+BENCH = pathlib.Path(__file__).parents[2] / "bench"
+KERNELS = BENCH / "kernels"
+GQA = load_module(BENCH / "arch" / "gqa.py")
 
 # small numbers so the hand computation is easy to follow
-D = Dims(layers=2, d_model=8, heads=4, kv_heads=2, head_dim=2, d_ff=16,
-         vocab=10, mlp="gelu", qk_norm=False, rope_theta=1e4, eps=1e-6,
-         dtype="bfloat16")
+D = GQA.Dims(layers=2, d_model=8, heads=4, kv_heads=2, head_dim=2,
+             d_ff=16, vocab=10, mlp="gelu", qk_norm=False, rope_theta=1e4,
+             eps=1e-6, dtype="bfloat16")
 
 
 def kernel(name):
@@ -66,16 +68,17 @@ def test_model_flops():
     # a layer: attention 8*8 + 2*8*4 + 8*8 = 192, gelu MLP 2*8*16 = 256
     assert D.layer_params == 448
     dec = Span("decode", 0, 1, "x", rows=2, contexts=(3, 5))
-    assert counts.step_flops(D, dec) == (
+    assert GQA.step_flops(D, dec) == (
         2 * (2 * 2 * 448 + 2 * 8 * 10) + 4 * 2 * 4 * 2 * 8)
     pre = Span("prefill", 0, 1, "x", rows=3, offset=4)
-    assert counts.step_flops(D, pre) == (
+    assert GQA.step_flops(D, pre) == (
         3 * 2 * 2 * 448 + 2 * 8 * 10 + 4 * 2 * 4 * 2 * 18)
 
 
 class _Run:
     def __init__(self, spans, events=None):
         self.spans, self.events, self.dims = spans, events, D
+        self.arch = GQA
         self.peaks = {"bf16_flops": 1e3, "hbm_bytes_per_s": 1e3}
         self.kernels = [kernel(p.stem) for p in sorted(KERNELS.glob("*.py"))]
         self.notes = []
@@ -83,7 +86,7 @@ class _Run:
 
 def test_mfu_over_host_time():
     spans = [Span("decode", 0.0, 2.0, "x", rows=1, contexts=(4,))]
-    want = counts.step_flops(D, spans[0]) / 2.0 / 1e3 * 100
+    want = GQA.step_flops(D, spans[0]) / 2.0 / 1e3 * 100
     assert counts.mfu(_Run(spans), "decode") == pytest.approx(want)
     assert counts.mfu(_Run(spans), "prefill") is None
 

@@ -117,6 +117,11 @@ def test_every_file_found_by_name():
         assert c["file"].startswith("bench/configs/")
         cfg = man.config(c["name"])
         assert cfg["name"] == c["name"]
+        arch = man.arch(cfg["arch"])
+        for fn in ("dims", "describe", "make_params", "program_config",
+                   "final_hidden", "step_flops", "layer_calls"):
+            assert callable(getattr(arch, fn)), (cfg["arch"], fn)
+        hash(arch.dims(cfg))
         for k in c["reduced"]:
             assert k in cfg
     for w in MAN["workloads"]:

@@ -1,6 +1,7 @@
-"""The plain float32 reference (bench/harness/reference.py) against the
-engine's prefill-then-decode logits through the same paged path, on the
-CPU at a tiny size, in both of the benchmark's model forms."""
+"""The plain float32 reference (bench/harness/reference.py with the GQA
+architecture's bench/arch/gqa.py) against the engine's
+prefill-then-decode logits through the same paged path, on the CPU at a
+tiny size, in both of the benchmark's model forms."""
 
 import numpy as np
 import pytest
@@ -65,8 +66,8 @@ def test_reference_matches_engine_logits(tmp_path, mlp, qk_norm):
         ps = sorted(p for (v, p) in logged if v == u)
         pos[j, :len(ps)] = ps
         want[j] = [logged[(u, p)] for p in ps]
-    h = reference.final_hidden(ses.params, ses.dims, jnp.asarray(toks),
-                               jnp.asarray(pos))
+    h = ses.arch.final_hidden(ses.params, ses.dims, jnp.asarray(toks),
+                              jnp.asarray(pos))
     got = np.asarray(jnp.einsum("nre,ev->nrv", h,
                                 ses.params["lm_head"].astype(jnp.float32)))
     n = 0
@@ -81,25 +82,25 @@ def test_blocked_attention_matches_unblocked():
     """The reference attends in blocks of QBLOCK query rows; over several
     blocks it must equal the same layer computed in one piece."""
     import jax.numpy as jnp
-    from harness.model import Dims, make_params
-    d = Dims(layers=1, d_model=32, heads=4, kv_heads=2, head_dim=8,
-             d_ff=64, vocab=64, mlp="silu_glu", qk_norm=True,
-             rope_theta=1e4, eps=1e-6, dtype="float32")
-    p = make_params(d, 1)
+    gqa = Manifest().arch("gqa")
+    d = gqa.Dims(layers=1, d_model=32, heads=4, kv_heads=2, head_dim=8,
+                 d_ff=64, vocab=64, mlp="silu_glu", qk_norm=True,
+                 rope_theta=1e4, eps=1e-6, dtype="float32")
+    p = gqa.make_params(d, 1)
     S = 3 * reference.QBLOCK
     toks = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, S)),
                        jnp.int32)
     pos = jnp.asarray(np.random.default_rng(1).integers(0, S, (2, 16)),
                       jnp.int32)
-    got = reference.final_hidden(p, d, toks, pos)
+    got = gqa.final_hidden(p, d, toks, pos)
     # one block spanning the whole sequence: the same math unblocked
-    old = reference.QBLOCK
+    old = gqa.QBLOCK
     try:
-        reference.QBLOCK = S
-        reference._layer.cache_clear()
-        want = reference.final_hidden(p, d, toks, pos)
+        gqa.QBLOCK = S
+        gqa._layer.cache_clear()
+        want = gqa.final_hidden(p, d, toks, pos)
     finally:
-        reference.QBLOCK = old
-        reference._layer.cache_clear()
+        gqa.QBLOCK = old
+        gqa._layer.cache_clear()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)

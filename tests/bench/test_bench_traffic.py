@@ -1,7 +1,9 @@
 """The seeded traffic generator (bench/harness/traffic.py): deterministic,
 every length inside its clips and on the grid, the same requests at the
-same times for every seed."""
+same times for every seed, Poisson mixes' plans as they were recorded,
+and Gamma arrivals at the asked mean and coefficient of variation."""
 
+import hashlib
 import json
 import pathlib
 
@@ -13,12 +15,19 @@ from harness import traffic
 MIXES = sorted((pathlib.Path(__file__).parents[2] / "bench" / "traffic")
                .glob("*.json"))
 BIG = 2 ** 33 + 12345          # the driver's seeds exceed 32 bits
+#: digests of the Poisson and closed-loop mixes' plans at seed BIG,
+#: recorded before mixes could name an arrival process
+RECORDED = json.loads((pathlib.Path(__file__).parent / "data"
+                       / "hashes_before_arch_move.json").read_text())["plans"]
+
+
+def load(name):
+    return json.loads((MIXES[0].parent / f"{name}.json").read_text())
 
 
 @pytest.fixture(params=[p.stem for p in MIXES])
 def mix(request):
-    return json.loads((MIXES[0].parent / f"{request.param}.json")
-                      .read_text())
+    return load(request.param)
 
 
 def plan(mix, seed):
@@ -78,3 +87,29 @@ def test_lengths_follow_the_distribution():
                              20000, rng)
     assert u.min() >= 256 and u.max() <= 1024
     assert abs(u.mean() - 640) < 10
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED))
+def test_plans_as_recorded(name):
+    mix = load(name)
+    p = traffic.make_plan(mix, BIG, 1000, int(mix["engine"]["max_len"]))
+    sizes, tokens = hashlib.sha256(), hashlib.sha256()
+    for r in p.fill + p.stream:
+        sizes.update(f"{len(r.prompt)} {r.max_new} {r.due!r} {r.fill};"
+                     .encode())
+        tokens.update(np.asarray(r.prompt, np.int64).tobytes())
+    assert sizes.hexdigest() == RECORDED[name]["sizes_and_times"]
+    assert tokens.hexdigest() == RECORDED[name]["tokens"]
+
+
+def test_gamma_arrivals_keep_mean_and_cv():
+    mixes = [m for m in map(load, (p.stem for p in MIXES))
+             if m.get("arrivals", {}).get("dist") == "gamma"]
+    assert mixes
+    for mix in mixes:
+        p = traffic.make_plan(mix, 3, 1000, int(mix["engine"]["max_len"]))
+        gaps = np.diff([0.0] + [r.due for r in p.stream])
+        assert len(gaps) == int(mix["pool"])
+        assert abs(gaps.mean() * float(mix["rate"]) - 1) <= 0.10
+        cv = gaps.std() / gaps.mean()
+        assert abs(cv / float(mix["arrivals"]["cv"]) - 1) <= 0.15
