@@ -15,6 +15,9 @@ runtime:
   (+ RoPE), masked scores, softmax, P.V, output projection AND the
   residual add in one Pallas launch
   (``kernels/fused_decode_block.py``).
+* ``mla_decode``       — absorbed MLA decode over a paged latent cache:
+  every query head against one latent "KV head", the block table
+  scalar-prefetched (``kernels/fused_mla_decode.py``).
 * ``schedule_for``     — the legacy shape-driven selector
   (core.fusion.select_schedule), kept for the paper-rule API.
 * ``ssd``/``ssd_step`` — Mamba-2 SSD chunked scan / decode update.
@@ -65,6 +68,8 @@ from repro.kernels.fused_decode_block import (
     fused_decode_block as _pallas_decode_block)
 from repro.kernels.fused_decode_block import (
     fused_decode_block_paged as _pallas_decode_block_paged)
+from repro.kernels.fused_mla_decode import (
+    fused_mla_decode_paged as _pallas_mla_decode_paged)
 from repro.kernels.fused_qproj_attention import (
     fused_qproj_attention as _pallas_qproj_attn)
 from repro.kernels.fused_qproj_attention import (
@@ -76,7 +81,8 @@ from repro.kernels.xla_fallback import ssd_step  # re-export
 from repro.lower import cache as _plan_cache
 from repro.lower import runtime as _plan_rt
 
-__all__ = ["attention", "qproj_attention", "decode_block", "ssd",
+__all__ = ["attention", "qproj_attention", "decode_block", "mla_decode",
+           "ssd",
            "ssd_step", "schedule_for", "default_impl",
            "reset_lengths_downgrade_warning"]
 
@@ -513,6 +519,47 @@ def decode_block(x, wq, k, v, wo, residual, lengths, *,
                        wo.astype(jnp.float32))
         return (residual.astype(jnp.float32) + y).astype(x.dtype)
     raise ValueError(f"unknown impl {impl!r}")
+
+
+def mla_decode(q, pool, lengths, block_tables, *, d_v: int, scale: float,
+               impl: str = "auto", block_k: Optional[int] = None,
+               interpret: bool = False,
+               plan: Optional[_plan_rt.PlanDispatch] = None):
+    """Absorbed MLA decode attention over a paged latent pool: q (B, H,
+    W), every head against the one latent "KV head" of pool (num_pages,
+    1, page, W), P.V over its first ``d_v`` columns; lengths (B,),
+    block_tables (B, max_pages).  Returns (B, H, d_v).  The Pallas impl
+    is ``fused_mla_decode_paged``; the others gather the pool dense and
+    compose the same math (``xla``: streaming, ``reference``:
+    materialised scores)."""
+    if plan is not None:
+        impl = plan.impl if impl == "auto" else impl
+        block_k = block_k or plan.block_k
+        interpret = interpret or plan.interpret
+    elif impl == "auto":
+        impl = default_impl()
+    page = pool.shape[2]
+    block_k = block_k or 512
+    if impl == "pallas":
+        reason = _paged_unsupported(q, lengths, block_tables, False, None,
+                                    1, page)
+        if reason is None:
+            return _pallas_mla_decode_paged(
+                q, pool, lengths, block_tables, d_v=d_v, scale=scale,
+                block_k=block_k, interpret=interpret)
+        impl = _downgrade_paged(plan, reason)
+    lat = _xla.gather_paged_kv(pool, block_tables)    # (B, 1, S, W)
+    q4 = q[:, :, None]
+    if impl == "xla":
+        o = _xla.chunked_attention(q4, lat, lat[..., :d_v], causal=False,
+                                   scale=scale, lengths=lengths,
+                                   block_k=block_k)
+    elif impl == "reference":
+        o = _ref.attention_reference(q4, lat, lat[..., :d_v], scale=scale,
+                                     lengths=lengths)
+    else:
+        raise ValueError(f"unknown impl {impl!r}")
+    return o[:, :, 0]
 
 
 def ssd(x, dt, a, b, c, d=None, *, chunk: int = 128,

@@ -32,6 +32,7 @@ from repro.models import transformer as tf
 from repro.serve import (ContinuousBatchingEngine,
                          PagedContinuousBatchingEngine, Request,
                          RequestBatcher, make_serving_plan, tracing)
+from repro.serve.engine import pool_pages
 
 
 def make_engine(params, cfg, *, batch: int, max_len: int,
@@ -41,7 +42,8 @@ def make_engine(params, cfg, *, batch: int, max_len: int,
     """The continuous-batching engine this entry point serves with:
     plan-driven (``plan=False`` keeps the config's own kernel choice),
     dense or ``paged`` (a pool sized for every row at ``max_len``, plus
-    the reserved null page).  With ``mesh`` the KV cache is placed on
+    the reserved null page, or where two of those do not fit beside the
+    weights, what fits twice: ``serve.engine.pool_pages``).  With ``mesh`` the KV cache is placed on
     it by its logical axes; the caller places the parameters and serves
     under ``sharding.set_rules_for_mesh(mesh)``.  ``engine_cls``
     substitutes a subclass of the dense or paged engine."""
@@ -54,7 +56,8 @@ def make_engine(params, cfg, *, batch: int, max_len: int,
     if paged:
         cls = engine_cls or PagedContinuousBatchingEngine
         eng = cls(params, cfg, page_size=page_size,
-                  num_pages=batch * max_len // page_size + 1, **kw)
+                  num_pages=pool_pages(cfg, batch, max_len, page_size,
+                                       dtype, params), **kw)
     else:
         cls = engine_cls or ContinuousBatchingEngine
         eng = cls(params, cfg, **kw)

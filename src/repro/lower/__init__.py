@@ -17,12 +17,13 @@ from repro.lower.lowering import lower, lower_phase_plan, supported
 from repro.lower.plan import (DECODE_MEGAKERNEL, FUSED_ATTENTION,
                               KERNEL_PATHS, QPROJ_ATTENTION, UNFUSED,
                               BlockPlan, Downgrade, ExecutionPlan)
-from repro.lower.runtime import (PlanDispatch, ServingPlan, dispatch,
-                                 impl_for, rung_down, serving_plan)
+from repro.lower.runtime import (MLA_DECODE, PlanDispatch, ServingPlan,
+                                 dispatch, impl_for, rung_down,
+                                 serving_plan)
 
 __all__ = [
     "UNFUSED", "FUSED_ATTENTION", "QPROJ_ATTENTION",
-    "DECODE_MEGAKERNEL", "KERNEL_PATHS",
+    "DECODE_MEGAKERNEL", "MLA_DECODE", "KERNEL_PATHS",
     "BlockPlan", "Downgrade", "ExecutionPlan",
     "lower", "lower_phase_plan", "supported",
     "bucket_for", "resolve_plan", "plan_cache_info", "clear_plan_cache",

@@ -34,7 +34,13 @@ from repro.lower.plan import (DECODE_MEGAKERNEL, FUSED_ATTENTION,
                               QPROJ_ATTENTION, UNFUSED, ExecutionPlan)
 
 __all__ = ["PlanDispatch", "dispatch", "impl_for", "rung_down",
-           "ServingPlan", "serving_plan"]
+           "ServingPlan", "serving_plan", "MLA_DECODE"]
+
+#: Absorbed MLA decode: every query head against one paged latent
+#: "KV head" (``kernels/fused_mla_decode.py``).  The path of an M=1
+#: decode step of a model whose ``attention`` is ``"mla"``; its
+#: chunks take ``fused_attention`` over the latent side cache.
+MLA_DECODE = "mla_decode"
 
 
 def impl_for(path: str, backend: str = "cpu",
@@ -193,7 +199,13 @@ def rung_down(d: PlanDispatch,
     step one rung lower — same math, progressively less fused — so a
     sick fused kernel degrades service instead of killing the batch.
     """
-    if d.path != UNFUSED:
+    if d.path == MLA_DECODE:
+        # no lower rung computes absorbed MLA from a pool: fall to the
+        # gather-and-stream XLA composition of the same math
+        if d.impl == "xla":
+            return None
+        new_path, new_impl = MLA_DECODE, "xla"
+    elif d.path != UNFUSED:
         new_path = _LADDER[_LADDER.index(d.path) + 1]
         new_impl = ("reference" if new_path == UNFUSED else d.impl)
     elif d.impl != "xla":
@@ -218,6 +230,9 @@ class ServingPlan:
 
     cfg: object
     max_len: int
+    #: what plans are resolved for: ``cfg``, or for MLA the absorbed
+    #: head (:func:`absorbed_head`)
+    attn_cfg: object = None
     backend: str = "cpu"
     interpret: bool = False
     n_blocks: int = 1
@@ -233,10 +248,18 @@ class ServingPlan:
         (empty when the planned paths are the paths that ran)."""
         return [d for p in self.plans.values() for d in p.downgrades]
 
+    def __post_init__(self):
+        if self.attn_cfg is None:
+            self.attn_cfg = self.cfg
+
+    @property
+    def mla(self) -> bool:
+        return getattr(self.cfg, "attention", "gqa") == "mla"
+
     @property
     def head_dim(self) -> int:
-        return getattr(self.cfg, "head_dim", 0) or \
-            self.cfg.d_model // self.cfg.n_heads
+        c = self.attn_cfg
+        return getattr(c, "head_dim", 0) or c.d_model // c.n_heads
 
     @property
     def crossover_ctx(self) -> int:
@@ -247,25 +270,56 @@ class ServingPlan:
 
     def _dispatch(self, phase: str, n: int,
                   decode_tokens: int = 1) -> PlanDispatch:
-        plan = plan_cache.resolve_plan(self.cfg, phase, n,
-                                       decode_tokens=decode_tokens,
-                                       n_blocks=self.n_blocks)
-        # the model runtime (models/attention.py) hands the kernel
-        # whatever the deepest decode fusion needs: pre-projection
-        # activations + Wq always, and Wo + the residual on M=1 decode
-        # steps — so the planned ladder rung is executable end-to-end
-        entry = "attention"
-        if phase == "decode":
-            entry = "decode_block" if decode_tokens == 1 \
-                else "qproj_attention"
-        d = dispatch(plan, backend=self.backend, interpret=self.interpret,
-                     entry=entry,
-                     rope=getattr(self.cfg, "rope_theta", 0) > 0,
-                     qk_norm=getattr(self.cfg, "qk_norm", False),
-                     lengths_masked=True, paged=self.paged)
+        if self.mla:
+            d = self._mla_dispatch(
+                phase, decode_tokens if phase == "decode" else n)
+        else:
+            plan = plan_cache.resolve_plan(self.cfg, phase, n,
+                                           decode_tokens=decode_tokens,
+                                           n_blocks=self.n_blocks)
+            # the model runtime (models/attention.py) hands the kernel
+            # whatever the deepest decode fusion needs: pre-projection
+            # activations + Wq always, and Wo + the residual on M=1
+            # decode steps — so the planned ladder rung is executable
+            # end-to-end
+            entry = "attention"
+            if phase == "decode":
+                entry = "decode_block" if decode_tokens == 1 \
+                    else "qproj_attention"
+            d = dispatch(plan, backend=self.backend,
+                         interpret=self.interpret, entry=entry,
+                         rope=getattr(self.cfg, "rope_theta", 0) > 0,
+                         qk_norm=getattr(self.cfg, "qk_norm", False),
+                         lengths_masked=True, paged=self.paged)
+        plan = d.plan
         self.resolutions.append((phase, n, plan.bucket, d.path, d.impl))
         self.plans[id(plan)] = plan
         return d
+
+    def _mla_dispatch(self, phase: str, rows: int) -> PlanDispatch:
+        """Absorbed MLA has two kernels, whatever the context: an M=1
+        decode step runs ``MLA_DECODE`` over the latent pool, every
+        other call (prefill chunks over the B=1 side cache) the masked
+        ``fused_attention`` — its query is materialised, and an unfused
+        chunk would hold (heads, rows, max_len) scores.  Both take the
+        tiling the DSE resolves for the absorbed head at the deepest
+        context, so a context crossing a bucket edge compiles
+        nothing new."""
+        plan = plan_cache.resolve_plan(self.attn_cfg, "decode",
+                                       self.max_len, decode_tokens=rows,
+                                       n_blocks=self.n_blocks)
+        path = MLA_DECODE if rows == 1 and phase == "decode" \
+            else FUSED_ATTENTION
+        if path == MLA_DECODE:
+            plan.note("absorbed MLA decode: every query head against "
+                      "the one latent KV head, paged")
+        t = plan.tiling
+        return PlanDispatch(plan=plan, path=path,
+                            impl=impl_for(path, self.backend,
+                                          self.interpret),
+                            block_q=t.block_q, block_k=t.block_kv,
+                            interpret=self.interpret,
+                            paged=self.paged and path == MLA_DECODE)
 
     def prefill_dispatch(self, seq_len: int) -> PlanDispatch:
         return self._dispatch("prefill", seq_len)
@@ -323,6 +377,18 @@ class ServingPlan:
             return self.max_len
 
 
+def absorbed_head(cfg):
+    """MLA in absorbed form, as the DSE sees an attention head: MQA,
+    ``n_heads`` query heads over one latent head as wide as the latent
+    (``kv_lora_rank + qk_rope_head_dim``)."""
+    d_ff = (getattr(cfg, "d_expert", 0) or cfg.d_ff) \
+        * max(getattr(cfg, "top_k", 1), 1)
+    return plan_cache.HeadConfig(
+        name=cfg.name, d_model=cfg.d_model, n_heads=cfg.n_heads,
+        n_kv_heads=1, d_head=cfg.kv_lora_rank + cfg.qk_rope_head_dim,
+        d_ff=d_ff, mlp=getattr(cfg, "mlp", "silu_glu"))
+
+
 def serving_plan(cfg, max_len: int, *, backend: str = "cpu",
                  interpret: bool = False,
                  n_blocks: Optional[int] = None,
@@ -330,19 +396,24 @@ def serving_plan(cfg, max_len: int, *, backend: str = "cpu",
                  page_size: Optional[int] = None
                  ) -> Optional[ServingPlan]:
     """Build the ServingPlan for ``cfg``, or None when the config is
-    not lowerable (MLA/SSM/hybrid blocks) — the serving engine then
-    keeps its config-driven dispatch.  ``paged``/``page_size``: the
+    not lowerable (SSM/hybrid blocks) — the serving engine then keeps
+    its config-driven dispatch.  MLA is planned as its absorbed head
+    (:func:`absorbed_head`), its M=1 decode on ``MLA_DECODE``.  ``paged``/``page_size``: the
     engine stores KV as a free-list page pool + block tables; every
     dispatch is then legalised on the ``paged`` axis (Pallas impls take
     the block-table-indirect kernels, others record the honest
     paged->masked-dense downgrade)."""
-    if not lowering.supported(cfg):
+    attn_cfg = cfg
+    if getattr(cfg, "attention", "gqa") == "mla":
+        attn_cfg = absorbed_head(cfg)
+    elif not lowering.supported(cfg):
         return None
     if n_blocks is None:
         n_blocks = getattr(cfg, "n_layers", 1) or 1
     if paged and page_size is not None and max_len % page_size:
         raise ValueError(
             f"max_len {max_len} not a multiple of page_size {page_size}")
-    return ServingPlan(cfg=cfg, max_len=max_len, backend=backend,
-                       interpret=interpret, n_blocks=n_blocks,
+    return ServingPlan(cfg=cfg, max_len=max_len, attn_cfg=attn_cfg,
+                       backend=backend, interpret=interpret,
+                       n_blocks=n_blocks,
                        paged=paged, page_size=page_size)

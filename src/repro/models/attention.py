@@ -27,9 +27,11 @@ append becomes a vmapped per-row scatter, ``q_offset`` is dropped and
 ``lengths = cache_len + 1`` alone carries each row's causal frontier.
 
 KV caches: GQA stores (k, v) per layer; MLA stores the *latent* cache
-(c_kv + rope key), decoding in absorbed form — (B, S, 576) instead of
-(B, H, S, 192+128): the MLA memory win integrates naturally with the
-fused kernel because fused_attention supports d_v != d_k.
+(c_kv + rope key), decoding in absorbed form — (B, 1, S, 640) instead
+of (B, H, S, 192+128): one 576-wide latent "KV head" (padded to the
+lane tile), the first 512 columns of which are also the value.  Chunks
+run the masked fused_attention (d_v != d_k); paged decode runs
+``ops.mla_decode``.
 """
 
 from __future__ import annotations
@@ -312,12 +314,31 @@ def init_mla(key, cfg: ModelConfig):
     }
 
 
+def mla_latent_width(cfg: ModelConfig) -> int:
+    """Width of a token's cached latent: the compressed KV
+    (``kv_lora_rank``) and the shared rotary key
+    (``qk_rope_head_dim``), zero-padded to the 128-lane tile the TPU
+    stores a row in anyway (576 -> 640 at DeepSeek-V3 widths): a
+    576-wide page cannot be the target of the decode kernel's DMAs."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    """The attention logits' scale: (qk_nope + qk_rope)^-1/2, times
+    YaRN's mscale^2 when the rotary embedding is scaled."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_scaling is not None:
+        scale *= cfg.rope_scaling.softmax_mscale
+    return scale
+
+
 def _mla_q(params, cfg, x, positions, dt):
     cq = jnp.einsum("bsd,dr->bsr", x, params["wq_a"].astype(dt))
     cq = rms_norm(cq, params["q_a_norm"])
     q = jnp.einsum("bsr,rhe->bhse", cq, params["wq_b"].astype(dt))
     q_nope = q[..., :cfg.qk_nope_head_dim]
-    q_rope = rope(q[..., cfg.qk_nope_head_dim:], positions, cfg.rope_theta)
+    q_rope = rope(q[..., cfg.qk_nope_head_dim:], positions, cfg.rope_theta,
+                  cfg.rope_scaling)
     return q_nope, q_rope
 
 
@@ -326,8 +347,17 @@ def _mla_latent(params, cfg, x, positions, dt):
     c, k_rope = (ckv[..., :cfg.kv_lora_rank],
                  ckv[..., cfg.kv_lora_rank:])
     c = rms_norm(c, params["kv_a_norm"])
-    k_rope = rope(k_rope[:, None], positions, cfg.rope_theta)[:, 0]
+    k_rope = rope(k_rope[:, None], positions, cfg.rope_theta,
+                  cfg.rope_scaling)[:, 0]
     return c, k_rope  # (B,S,r_kv), (B,S,d_rope)
+
+
+def _pad_lanes(x, width: int):
+    """Zero-pad the last axis of ``x`` to ``width``."""
+    pad = width - x.shape[-1]
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
 def mla_forward(params, cfg: ModelConfig, x, positions, *,
@@ -338,22 +368,39 @@ def mla_forward(params, cfg: ModelConfig, x, positions, *,
                 plan=None,
                 residual: Optional[jax.Array] = None):
     """Prefill/train: non-absorbed (per-head K/V, fused kernel, causal).
-    Decode: absorbed MQA form over the latent cache (d_k = r_kv + rope,
-    d_v = r_kv) — one shared latent 'kv head'.  MLA blocks are not
-    lowerable to DSE workloads yet, so ``plan`` only overrides the
-    kernel args when a caller resolved one by hand.  ``residual`` is
-    folded into the returned output (same contract as
-    :func:`gqa_forward`; no megakernel path here)."""
-    if block_tables is not None:
-        raise NotImplementedError(
-            "paged KV is not supported for MLA latent caches")
+    With a cache: absorbed MQA form over the latent cache — every query
+    head scores the latent (``q_nope @ W_UK`` beside ``q_rope``, d_k =
+    the latent width) and P.V reads its first ``kv_lora_rank`` columns
+    (W_UV applied after).  The latent cache is ``(B, 1, S, W)``, one
+    shared "KV head" of width :func:`mla_latent_width`; with
+    ``block_tables`` it is a page pool ``(num_pages, 1, page, W)``, each
+    row's new latent appended at its own position through the table,
+    and single-token decode runs the paged MLA kernel
+    (``ops.mla_decode``).  ``plan`` carries the kernel args.
+    ``residual`` is folded into the returned output (same contract as
+    :func:`gqa_forward`)."""
+    with jax.named_scope("mla"):
+        out, new_cache = _mla(params, cfg, x, positions, cache, cache_len,
+                              block_tables, interpret, plan)
+    if residual is not None:
+        out = residual + out
+    return out, new_cache
+
+
+def _mla(params, cfg, x, positions, cache, cache_len, block_tables,
+         interpret, plan):
     dt = x.dtype
     b, s, _ = x.shape
     impl, bq, bk, interpret = _plan_kernel_args(cfg, plan, interpret)
     q_nope, q_rope = _mla_q(params, cfg, x, positions, dt)
     c, k_rope = _mla_latent(params, cfg, x, positions, dt)
+    scale = mla_scale(cfg)
 
     if cache is None:
+        if block_tables is not None:
+            raise NotImplementedError(
+                "paged KV is a decode-time storage format; prefill runs "
+                "dense and is paged at insert() time")
         k_nope = jnp.einsum("bsr,rhe->bhse", c, params["wk_b"].astype(dt))
         v = jnp.einsum("bsr,rhe->bhse", c, params["wv_b"].astype(dt))
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
@@ -361,46 +408,64 @@ def mla_forward(params, cfg: ModelConfig, x, positions, *,
             [k_nope, jnp.broadcast_to(k_rope[:, None],
                                       (b, cfg.n_heads, s,
                                        cfg.qk_rope_head_dim))], axis=-1)
-        scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
         o = ops.attention(q, k, v, causal=cfg.causal, scale=scale,
                           impl=impl, block_q=bq, block_k=bk,
                           interpret=interpret, plan=plan)
         new_cache = None
     else:
+        width = mla_latent_width(cfg)
+        r = cfg.kv_lora_rank
         # absorbed: q' = q_nope @ W_UK -> latent space
         q_lat = jnp.einsum("bhse,rhe->bhsr", q_nope,
                            params["wk_b"].astype(dt))
-        q_full = jnp.concatenate([q_lat, q_rope], axis=-1)
-        latent_new = jnp.concatenate([c, k_rope], axis=-1)
+        q_full = _pad_lanes(jnp.concatenate([q_lat, q_rope], axis=-1),
+                            width)
+        latent_new = _pad_lanes(jnp.concatenate([c, k_rope], axis=-1),
+                                width)[:, None]          # (B,1,S,W)
         starts, lengths, q_off, per_row = _cache_write(cache_len, b, s)
-        if per_row:
-            buf = _scatter_rows(cache["latent"], latent_new, starts, 1)
+        buf = cache["latent"]
+        if block_tables is not None:
+            if not per_row or s != 1:
+                raise NotImplementedError(
+                    "paged MLA takes single-token steps with per-row "
+                    "(B,) cache_len")
+            # page-indirect append: row b's new latent lands in page
+            # block_tables[b, pos // page] at offset pos % page
+            page = buf.shape[2]
+            page_ids = block_tables[jnp.arange(b), starts // page]
+            buf = buf.at[page_ids, 0, starts % page].set(
+                latent_new[:, 0, 0].astype(buf.dtype))
+            o_lat = ops.mla_decode(
+                q_full[:, :, 0], buf, lengths, block_tables, d_v=r,
+                scale=scale, impl=impl, block_k=bk, interpret=interpret,
+                plan=plan)[:, :, None]                  # (B,H,1,r_kv)
         else:
-            buf = jax.lax.dynamic_update_slice(
-                cache["latent"], latent_new.astype(cache["latent"].dtype),
-                (0, starts, 0))
+            if per_row:
+                buf = _scatter_rows(buf, latent_new, starts, 2)
+            else:
+                buf = jax.lax.dynamic_update_slice(
+                    buf, latent_new.astype(buf.dtype), (0, 0, starts, 0))
+            lat = buf.astype(dt)
+            o_lat = ops.attention(q_full, lat, lat[..., :r],
+                                  causal=cfg.causal, q_offset=q_off,
+                                  scale=scale, lengths=lengths,
+                                  impl=impl, block_q=bq, block_k=bk,
+                                  interpret=interpret,
+                                  plan=plan)            # (B,H,S,r_kv)
         new_cache = {"latent": buf}
-        k_lat = buf.astype(dt)[:, None]                  # (B,1,S,r+rope)
-        v_lat = buf.astype(dt)[:, None, :, :cfg.kv_lora_rank]
-        scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
-        o_lat = ops.attention(q_full, k_lat, v_lat, causal=cfg.causal,
-                              q_offset=q_off,
-                              scale=scale, lengths=lengths,
-                              impl=impl, block_q=bq, block_k=bk,
-                              interpret=interpret,
-                              plan=plan)                # (B,H,S,r_kv)
-        o = jnp.einsum("bhsr,rhe->bhse", o_lat, params["wv_b"].astype(dt))
+        o = jnp.einsum("bhsr,rhe->bhse", o_lat.astype(dt),
+                       params["wv_b"].astype(dt))
 
-    out = jnp.einsum("bhse,hed->bsd", o, params["wo"].astype(dt))
-    if residual is not None:
-        out = residual + out
-    return out, new_cache
+    return jnp.einsum("bhse,hed->bsd", o, params["wo"].astype(dt)), \
+        new_cache
 
 
 def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int,
                    dtype) -> dict:
-    width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
-    return {"latent": jnp.zeros((batch, max_len, width), dtype)}
+    """The dense latent cache: ``(batch, 1, max_len, W)``, the layout of
+    one page-pool row (:func:`mla_latent_width`)."""
+    return {"latent": jnp.zeros((batch, 1, max_len, mla_latent_width(cfg)),
+                                dtype)}
 
 
 # ---------------------------------------------------------------------------

@@ -20,8 +20,62 @@ from repro.sharding import constrain
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN RoPE scaling (arXiv:2309.00071) as DeepSeek-V3 publishes it
+    (``rope_scaling`` of its config.json): frequencies past the
+    ``beta_fast`` correction dimension are interpolated by ``factor``,
+    those before ``beta_slow``'s kept, a linear ramp between; the
+    attention logits are scaled by ``softmax_mscale``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _m(factor: float, mscale: float) -> float:
+        return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 \
+            else 1.0
+
+    def correction_range(self, d: int, base: float) -> tuple[int, int]:
+        """(low, high) rotary pair indices of the ramp, clamped to
+        [0, d - 1]."""
+        def find(r):
+            return d * math.log(self.original_max_position
+                                / (2 * math.pi * r)) / (2 * math.log(base))
+        low = math.floor(find(self.beta_fast))
+        high = math.ceil(find(self.beta_slow))
+        return max(low, 0), min(high, d - 1)
+
+    def inv_freq(self, d: int, base: float):
+        """(d/2,) float32 frequencies: ``inter * ramp + extra * (1 -
+        ramp)``, with extra = base^(-2i/d), inter = extra / factor."""
+        half = d // 2
+        i = jnp.arange(half, dtype=jnp.float32)
+        extra = 1.0 / (base ** (2.0 * i / d))
+        inter = extra / self.factor
+        low, high = self.correction_range(d, base)
+        ramp = jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return inter * ramp + extra * (1.0 - ramp)
+
+    @property
+    def cos_scale(self) -> float:
+        """What cos and sin are multiplied by."""
+        return self._m(self.factor, self.mscale) \
+            / self._m(self.factor, self.mscale_all_dim)
+
+    @property
+    def softmax_mscale(self) -> float:
+        """What the attention logits' scale is multiplied by: m^2, with
+        m = 0.1 ln(factor) mscale_all_dim + 1."""
+        return self._m(self.factor, self.mscale_all_dim) ** 2
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """One config covers all 10 assigned architectures (DESIGN.md §3)."""
+    """One config covers all 10 assigned architectures (``configs/``)."""
 
     name: str
     n_layers: int
@@ -36,6 +90,7 @@ class ModelConfig:
     qk_norm: bool = False
     causal: bool = True                 # False: encoder-only (hubert)
     rope_theta: float = 1e6
+    rope_scaling: Optional[YaRN] = None  # None: plain RoPE
     # MLA (deepseek-v3)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -49,6 +104,17 @@ class ModelConfig:
     top_k: int = 2
     d_expert: int = 0
     capacity_factor: float = 1.25
+    # router: "softmax" (top-k over softmax probs, capacity drops) or
+    # "sigmoid" (deepseek-v3 noaux_tc: sigmoid scores, a selection-only
+    # bias, group-limited top-k, no drops)
+    router: str = "softmax"
+    n_groups: int = 1                   # sigmoid: expert groups
+    topk_groups: int = 1                # sigmoid: groups kept per token
+    routed_scale: float = 1.0           # sigmoid: routed_scaling_factor
+    # the experts this layer holds: [expert_offset, + experts_held) of
+    # the n_experts routed over (0 held: all of them)
+    expert_offset: int = 0
+    experts_held: int = 0
     first_dense_layers: int = 0         # deepseek: dense FFN prefix
     moe_every: int = 1                  # jamba: MoE every 2nd layer
     # SSM / hybrid
@@ -67,7 +133,7 @@ class ModelConfig:
     mlp: str = "silu_glu"               # silu_glu | gelu
     tie_embeddings: bool = False
     # §Perf beyond-paper optimizations (default off = paper-faithful
-    # baseline; see EXPERIMENTS.md §Perf)
+    # baseline)
     distributed_decode: bool = False    # partial-softmax decode combine
     head_parallel_decode: bool = False  # head-partitioned decode step:
     #                                     each shard runs its heads'
@@ -106,6 +172,11 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head or (self.d_model // self.n_heads)
+
+    @property
+    def held_experts(self) -> int:
+        """How many experts this layer's weights hold."""
+        return self.experts_held or self.n_experts
 
     @property
     def inner_dim(self) -> int:
@@ -211,18 +282,26 @@ def rms_norm(x, weight, eps: float = 1e-6):
     return (out * weight.astype(jnp.float32)).astype(dt)
 
 
-def rope(x, positions, theta: float):
-    """Rotary embedding. x: (..., S, D) pairs-rotation on last dim;
-    positions: (..., S) int32."""
+def rope(x, positions, theta: float, scaling: Optional[YaRN] = None):
+    """Rotary embedding. x: (..., S, D) half-split rotation on the last
+    dim; positions: (..., S) int32.  ``scaling``: YaRN frequencies and
+    cos/sin scale (None, or a factor of 1, is plain RoPE bit for
+    bit)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
-                    * (math.log(theta) / half))
+    yarn = scaling is not None and scaling.factor != 1
+    if yarn:
+        freqs = scaling.inv_freq(d, theta)
+    else:
+        freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
+                        * (math.log(theta) / half))
     ang = positions.astype(jnp.float32)[..., None] * freqs   # (...,S,half)
     # insert head axes between batch and seq to match x's rank
     while ang.ndim < x.ndim:
         ang = jnp.expand_dims(ang, -3)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn and scaling.cos_scale != 1.0:
+        cos, sin = cos * scaling.cos_scale, sin * scaling.cos_scale
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate([xf1 * cos - xf2 * sin,

@@ -1,8 +1,19 @@
 """Mixture-of-Experts FFN (phi3.5-moe 16e top-2; deepseek-v3 256e top-8
 + 1 shared; jamba 16e top-2).
 
-Dispatch is sort-based token-choice with static capacity (production
-style — no (tokens, E, C) one-hot blowup):
+The router follows the config's ``router``.  ``"sigmoid"`` is
+DeepSeek-V3's (``noaux_tc``, :func:`route_sigmoid`): sigmoid scores in
+float32, a correction bias that steers selection only, top-k within the
+best groups, the chosen scores normalised and scaled.  Its layer holds a
+share of the experts (``expert_offset``, ``experts_held``: one chip of
+an expert-parallel deployment): it routes every token over all
+``n_experts`` and computes its own experts' part of the result, with no
+capacity and no drops (:func:`moe_forward_share`), and adds the shared
+expert once.  On one chip the share runs without the exchange that
+would carry tokens to and from the other chips.
+
+``"softmax"`` dispatch is sort-based token-choice with static capacity
+(production style — no (tokens, E, C) one-hot blowup):
 
   1. router top-k per token (softmax probs, renormalised);
   2. token copies sorted by expert id; position-in-expert from a
@@ -30,21 +41,24 @@ from repro.sharding import constrain
 
 
 def init_moe(key, cfg: ModelConfig):
-    d, e = cfg.d_model, cfg.n_experts
+    d, e, held = cfg.d_model, cfg.n_experts, cfg.held_experts
     ff = cfg.d_expert or cfg.d_ff
     ks = jax.random.split(key, 5)
     p = {
         "router": param(ks[0], (d, e), ("embed", "experts"), jnp.float32),
-        "w_gate": param(ks[1], (e, d, ff),
+        "w_gate": param(ks[1], (held, d, ff),
                         ("experts", "expert_embed", "expert_mlp"),
                         cfg.pdtype),
-        "w_up": param(ks[2], (e, d, ff),
+        "w_up": param(ks[2], (held, d, ff),
                       ("experts", "expert_embed", "expert_mlp"),
                       cfg.pdtype),
-        "w_down": param(ks[3], (e, ff, d),
+        "w_down": param(ks[3], (held, ff, d),
                         ("experts", "expert_mlp", "expert_embed"),
                         cfg.pdtype),
     }
+    if cfg.router == "sigmoid":
+        # selection-only correction bias (a trained model's is learned)
+        p["router_bias"] = cm.zeros_param((e,), ("experts",), jnp.float32)
     if cfg.n_shared_experts:
         shared_ff = ff * cfg.n_shared_experts
         p["shared"] = cm.init_mlp(ks[4], d, shared_ff, cfg.mlp, cfg.pdtype)
@@ -119,14 +133,83 @@ def _capacity(cfg: ModelConfig, tokens_per_group: int) -> int:
     return max(8, -(-c // 8) * 8)
 
 
+def route_sigmoid(logits, bias, *, top_k: int, n_groups: int,
+                  topk_groups: int, scale: float):
+    """DeepSeek-V3's router over (T, E) float32 logits: scores s =
+    sigmoid(logits); b = s + bias chooses; a group's score is the sum of
+    its two best b; the best ``topk_groups`` groups stay and the rest
+    are masked to -inf; the top-``top_k`` b are selected and weighted by
+    their s, normalised over the k and multiplied by ``scale``.
+    Returns (weights (T, k) float32, expert ids (T, k) int32)."""
+    t, e = logits.shape
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    b = s + bias.astype(jnp.float32)
+    grouped = b.reshape(t, n_groups, e // n_groups)
+    group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)       # (T, G)
+    _, keep = jax.lax.top_k(group_score, topk_groups)
+    kept = jnp.zeros((t, n_groups), bool).at[
+        jnp.arange(t)[:, None], keep].set(True)
+    b = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(t, e)
+    _, idx = jax.lax.top_k(b, top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * scale
+    return w, idx.astype(jnp.int32)
+
+
+def moe_forward_share(params, cfg: ModelConfig, x):
+    """The sigmoid-routed layer over this chip's share of the experts.
+    x: (B, S, D) -> (B, S, D), aux {"moe_held_pairs": the token-expert
+    pairs routed to the held experts, "moe_routes": (B*S, k) int32, the
+    experts each token was routed to}.
+
+    Every held expert runs densely over every token, weighted by the
+    token's routed weight for it (0 where it was not chosen): exact, no
+    capacity, and at a few tokens an expert the same weight stream a
+    ragged dispatch would read."""
+    dt = x.dtype
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    with jax.named_scope("router"):
+        # float32 scores, as published: a bf16 pass would move the
+        # top-k choice near its ties
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                            params["router"],
+                            precision=jax.lax.Precision.HIGHEST)
+        w, idx = route_sigmoid(logits, params["router_bias"],
+                               top_k=cfg.top_k, n_groups=cfg.n_groups,
+                               topk_groups=cfg.topk_groups,
+                               scale=cfg.routed_scale)
+        local = idx - cfg.expert_offset                     # (T, k)
+        held = (local >= 0) & (local < cfg.held_experts)
+        gate = jnp.einsum(
+            "tk,tke->te", jnp.where(held, w, 0.0),
+            jax.nn.one_hot(local, cfg.held_experts, dtype=jnp.float32))
+    with jax.named_scope("experts"):
+        g = jnp.einsum("td,edf->etf", xt, params["w_gate"].astype(dt))
+        u = jnp.einsum("td,edf->etf", xt, params["w_up"].astype(dt))
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(dt) * u
+        out = jnp.einsum("etf,efd->etd", h, params["w_down"].astype(dt))
+        y = jnp.einsum("etd,te->td", out.astype(jnp.float32),
+                       gate).astype(dt).reshape(b, s, d)
+    if "shared" in params:
+        with jax.named_scope("shared"):
+            y = y + cm.mlp_forward(params["shared"], x, cfg.mlp)
+    return y, {"moe_held_pairs": held.sum().astype(jnp.int32),
+               "moe_routes": idx}
+
+
 def moe_forward(params, cfg: ModelConfig, x):
     """x: (B, S, D) -> (B, S, D), aux dict.
+
+    A ``"sigmoid"`` router takes :func:`moe_forward_share`.
 
     Baseline groups = batch rows (tokens sharded over (pod, data) only).
     With cfg.moe_group_size > 0 (§Perf), tokens regroup into
     (B*S/g, g, D) sharded over ALL mesh axes before dispatch, so the
     expert all-to-all moves 1/TP-degree as many bytes per device.
     """
+    if cfg.router == "sigmoid":
+        return moe_forward_share(params, cfg, x)
     if cfg.moe_local_dispatch:
         from repro.models.moe_local import _mesh, moe_forward_local
         if _mesh() is not None:

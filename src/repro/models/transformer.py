@@ -131,7 +131,8 @@ def _layer_forward(lp, cfg: ModelConfig, i_kind: tuple, x, positions,
         h = rms_norm(x, lp["ffn_norm"])
         with jax.named_scope("mlp"):
             if ffn_kind == "moe" and "moe" in lp:
-                h, aux = moe_mod.moe_forward(lp["moe"], cfg, h)
+                with jax.named_scope("moe"):
+                    h, aux = moe_mod.moe_forward(lp["moe"], cfg, h)
             else:
                 h = cm.mlp_forward(lp["mlp"], h, cfg.mlp)
         x = x + h
@@ -141,6 +142,10 @@ def _layer_forward(lp, cfg: ModelConfig, i_kind: tuple, x, positions,
 
 def _kinds(cfg: ModelConfig, i: int) -> tuple:
     return (cfg.block_kind(i), cfg.ffn_kind(i))
+
+
+#: aux outputs one per MoE layer, returned stacked in layer order
+PER_LAYER_AUX = ("moe_held_pairs", "moe_routes")
 
 
 def forward(params, cfg: ModelConfig, tokens=None, embeds=None, *,
@@ -159,7 +164,11 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None, *,
     ``block_tables``: (B, max_pages) int32 page table for paged KV
     caches; shared by all layers, so it enters the scanned body as a
     closure constant (scan-invariant), never a scanned input.
-    Returns logits (+ new cache if cache given) (+ aux if asked)."""
+    Returns logits (+ new cache if cache given) (+ aux if asked).  The
+    aux dict sums the MoE losses over layers; a sigmoid-routed model's
+    also holds, stacked over its MoE layers in layer order,
+    ``moe_held_pairs`` (n_moe_layers,) int32 and ``moe_routes``
+    (n_moe_layers, B*S, top_k) int32."""
     parts = []
     if embeds is not None:
         fp = params["frontend_proj"]
@@ -183,11 +192,16 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None, *,
     x = constrain(x, "batch", "seq_stream", "embed_act")
 
     aux_sum = {"moe_lb_loss": 0.0, "moe_z_loss": 0.0}
+    # per-layer outputs: the prefix's one at a time, then scan stacks
+    per_layer = {k: [] for k in PER_LAYER_AUX}
 
     def add_aux(aux):
         for k in aux_sum:
             if k in aux:
                 aux_sum[k] = aux_sum[k] + aux[k]
+        for k in per_layer:
+            if k in aux:
+                per_layer[k].append(aux[k][None])
 
     # dense prefix (python loop)
     new_prefix_caches = []
@@ -209,6 +223,7 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None, *,
         layer_params, layer_caches = xs
         new_caches = []
         aux_acc = {"moe_lb_loss": 0.0, "moe_z_loss": 0.0}
+        stacks = {k: [] for k in PER_LAYER_AUX}
         for pos in range(period):
             lc = None if layer_caches is None else layer_caches[pos]
             x, nc, aux = _layer_forward(
@@ -218,6 +233,10 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None, *,
             for k in aux_acc:
                 if k in aux:
                     aux_acc[k] = aux_acc[k] + aux[k]
+            for k in stacks:
+                if k in aux:
+                    stacks[k].append(aux[k])
+        aux_acc.update({k: jnp.stack(v) for k, v in stacks.items() if v})
         ys = (tuple(new_caches) if layer_caches is not None else None,
               aux_acc)
         return x, ys
@@ -235,6 +254,10 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None, *,
         x, (new_scan_caches, aux_stack) = jax.lax.scan(period_fn, x, xs)
         for k in aux_sum:
             aux_sum[k] = aux_sum[k] + jnp.sum(aux_stack[k])
+        for k in per_layer:
+            if k in aux_stack:
+                v = aux_stack[k]
+                per_layer[k].append(v.reshape((-1,) + v.shape[2:]))
     else:
         # unrolled (used by the roofline cost probes: XLA cost_analysis
         # counts a while body once, so probes lower without the scan)
@@ -245,12 +268,17 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None, *,
             per_trip.append(nc)
             for k in aux_sum:
                 aux_sum[k] = aux_sum[k] + aux_j[k]
+            for k in per_layer:
+                if k in aux_j:
+                    per_layer[k].append(aux_j[k])
         if cache is not None:
             new_scan_caches = jax.tree.map(
                 lambda *leaves: jnp.stack(leaves), *per_trip)
         else:
             new_scan_caches = None
 
+    aux_sum.update({k: jnp.concatenate(v) for k, v in per_layer.items()
+                    if v})
     x = rms_norm(x, params["final_norm"])
     with jax.named_scope("logits"):
         if "lm_head" in params:

@@ -36,11 +36,13 @@ scattered into a free batch row without stopping the decode loop.
 :class:`ContinuousBatchingEngine` packages the lifecycle with host
 mirrors of per-slot state so step dispatch never reads device memory.
 
-Caches: GQA k/v ring, MLA latent (B,S,576), Mamba conv+state.
+Caches: GQA k/v ring, MLA latent (B,1,S,640), Mamba conv+state.
 
 Paged KV: :class:`PagedContinuousBatchingEngine` swaps the dense
 per-row cache for page *pools* — per-layer ``(num_pages, Hkv, page,
-Dh)`` buffers plus one ``(B, max_pages)`` int32 block table shared by
+Dh)`` k and v buffers (GQA), or one ``(num_pages, 1, page, W)`` latent
+buffer (MLA: the latent is both K and, in its first ``kv_lora_rank``
+columns, V), plus one ``(B, max_pages)`` int32 block table shared by
 every layer — managed by a host-side :class:`PageAllocator` (free
 list; page 0 is a reserved null page that dead rows harmlessly
 reference).  KV memory is then bounded by the *pool*, not by
@@ -69,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.lower.runtime import MLA_DECODE
 from repro.models import transformer as tf
 from repro.models.common import ModelConfig
 from repro.serve import tracing
@@ -86,7 +89,7 @@ def make_serving_plan(cfg: ModelConfig, max_len: int, *,
                       interpret: bool = False, paged: bool = False,
                       page_size: Optional[int] = None):
     """The ServingPlan for ``cfg`` (None when the config is not
-    lowerable — MLA/SSM; serving then keeps config-driven dispatch).
+    lowerable — SSM/hybrid; serving then keeps config-driven dispatch).
     Resolved here so serve callers never touch jax backend strings.
     ``paged``/``page_size``: resolve the plan for paged-KV dispatch
     (the block-table axis of every bucket's PlanDispatch)."""
@@ -172,8 +175,8 @@ def chunked_prefill(params, cfg: ModelConfig, tokens,
 
 def decode_step(params, cfg: ModelConfig, state: DecodeState, *,
                 plan=None, dispatch=None, active=None,
-                block_tables=None, interpret: bool = False
-                ) -> tuple[DecodeState, jax.Array]:
+                block_tables=None, interpret: bool = False,
+                return_aux: bool = False):
     """One token for every row (M=1: the paper's M<N schedule regime).
 
     With a ``ServingPlan`` the step re-resolves its ExecutionPlan for
@@ -192,24 +195,27 @@ def decode_step(params, cfg: ModelConfig, state: DecodeState, *,
     advancing — their lane's output is computed and discarded).
     ``block_tables``: (B, max_pages) int32 page table when ``state``
     is paged (pool-shaped cache leaves); the state dataclass is
-    preserved either way.
+    preserved either way.  Returns ``(state, logits)``, and the
+    forward's aux dict third with ``return_aux``.
     """
     if dispatch is None and plan is not None:
         ctx = plan.concrete_ctx(state.cache_len) + 1
         dispatch = plan.decode_dispatch(ctx)
-    logits, new_cache = tf.forward(
+    logits, new_cache, aux = tf.forward(
         params, cfg, tokens=state.last_token[:, None],
         cache=state.cache, cache_len=state.cache_len,
-        interpret=interpret, plan=dispatch, block_tables=block_tables)
+        interpret=interpret, plan=dispatch, block_tables=block_tables,
+        return_aux=True)
     nxt = greedy_sample(logits)
     step = jnp.ones_like(state.cache_len)
     if active is not None:
         act = jnp.asarray(active)
         nxt = jnp.where(act, nxt, state.last_token)
         step = act.astype(state.cache_len.dtype)
-    return dataclasses.replace(
+    out = (dataclasses.replace(
         state, cache=new_cache, cache_len=state.cache_len + step,
-        last_token=nxt), logits[:, -1]
+        last_token=nxt), logits[:, -1])
+    return out + (aux,) if return_aux else out
 
 
 def serve_step(params, cfg: ModelConfig, state: DecodeState, *,
@@ -355,6 +361,9 @@ class ContinuousBatchingEngine:
         #: (B, vocab) — the supervisor's NaN-detection window.
         self.last_logits: Optional[np.ndarray] = None
         self.last_dispatch = None
+        #: slot -> its request's entry of ``tracing.routes`` (only while
+        #: ``tracing.keep_routes`` is on)
+        self._route_log: dict = {}
 
     def _init_state(self):
         return init_decode_state(self.cfg, self.batch_size, self.max_len,
@@ -386,6 +395,9 @@ class ContinuousBatchingEngine:
             sp["side_cache_bytes"] = nbytes
             self._pending[slot] = {"tokens": toks, "pos": 0,
                                    "cache": side.cache}
+            if tracing.routes is not None:
+                self._route_log[slot] = tracing.routes.setdefault(
+                    tuple(toks[0].tolist()), {})
         tracing.count("engine.side_cache_bytes", nbytes)
 
     def _reserve(self, slot: int, n_tokens: int) -> None:
@@ -443,22 +455,26 @@ class ContinuousBatchingEngine:
         Returns the rows whose block table grew."""
         return 0
 
-    def _megakernel_grid(self, dispatch) -> tuple[int, int]:
+    def _decode_grid(self, dispatch) -> tuple[int, int]:
         """(KV blocks scored, grid steps iterated) by this decode step's
-        paged megakernel launches, over every attention layer, counted
-        on the host; (0, 0) off that path.  The dense engine never runs
-        the paged kernel."""
+        paged decode kernel launches (the megakernel's or the MLA
+        kernel's), over every attention layer, counted on the host;
+        (0, 0) off those paths.  The dense engine never runs a paged
+        kernel."""
         return 0, 0
 
     def _launch(self, kind: str, dispatch):
         """The jitted launch for one legalised dispatch: ``"decode"``
-        (one whole-batch token step) or ``"prefill"`` (one chunk into a
-        B=1 side cache).  One compiled program per (kind, path, impl,
-        block sizes, paged); ``cache_len``, the chunk offset, the
-        active mask and the cache are traced arguments, so a steady
-        step compiles nothing.  Kernel-side work that ``kernels.ops``
-        does per call (tiling, downgrade records) now runs once, when
-        the program is traced."""
+        (one whole-batch token step: the new state, the last position's
+        logits and the MoE layers' ``moe_held_pairs`` and ``moe_routes``,
+        an empty dict without MoE layers) or
+        ``"prefill"`` (one chunk into a B=1 side cache).  One compiled
+        program per (kind, path, impl, block sizes, paged);
+        ``cache_len``, the chunk offset, the active mask and the cache
+        are traced arguments, so a steady step compiles nothing.
+        Kernel-side work that ``kernels.ops`` does per call (tiling,
+        downgrade records) now runs once, when the program is traced,
+        and ``tracing.program`` notes it then."""
         key = (kind, None if dispatch is None else (
             dispatch.path, dispatch.impl, dispatch.block_q,
             dispatch.block_k, dispatch.interpret, dispatch.paged))
@@ -471,21 +487,27 @@ class ContinuousBatchingEngine:
         if kind == "decode":
             def fn(params, state, active):
                 tracing.count("engine.launch_traces")
+                tracing.program(name, jitted, (params, state, active))
                 with jax.named_scope("decode_step"):
-                    return decode_step(
+                    state, logits, aux = decode_step(
                         params, cfg, state, dispatch=dispatch,
                         active=active, interpret=interpret,
-                        block_tables=getattr(state, "block_tables", None))
+                        block_tables=getattr(state, "block_tables", None),
+                        return_aux=True)
+                return state, logits, {k: aux[k] for k in tf.PER_LAYER_AUX
+                                       if k in aux}
         else:
             def fn(params, tokens, cache, pos):
                 tracing.count("engine.launch_traces")
+                tracing.program(name, jitted, (params, tokens, cache, pos))
                 with jax.named_scope("prefill_chunk"):
                     logits, cache = tf.forward(
                         params, cfg, tokens=tokens, cache=cache,
                         cache_len=pos, interpret=interpret, plan=dispatch)
                 return logits[:, -1:], cache
-        fn = self._launches[key] = jax.jit(fn)
-        return fn
+        name = f"{kind}:{'-' if dispatch is None else dispatch.path}"
+        jitted = self._launches[key] = jax.jit(fn)
+        return jitted
 
     def _check_kernel(self, dispatch) -> None:
         """Fault hook, consulted on the host before every launch: a
@@ -555,12 +577,12 @@ class ContinuousBatchingEngine:
                 self.last_dispatch = dispatch
                 self._check_kernel(dispatch)
                 traces = tracing.counter("engine.launch_traces")
-                new_state, logits = self._launch("decode", dispatch)(
+                new_state, logits, moe = self._launch("decode", dispatch)(
                     self.params, self.state, jnp.asarray(self.live))
                 launch["traced"] = \
                     tracing.counter("engine.launch_traces") > traces
             tracing.count("engine.decode_launches")
-            blocks, steps = self._megakernel_grid(dispatch)
+            blocks, steps = self._decode_grid(dispatch)
             if steps:
                 tracing.count("engine.decode_kv_blocks", blocks)
                 tracing.count("engine.decode_grid_steps", steps)
@@ -570,7 +592,22 @@ class ContinuousBatchingEngine:
                 self._inject_nan()
                 tokens = np.asarray(self.state.last_token)
                 rb["bytes"] = self.last_logits.nbytes + tokens.nbytes
+                held = routes = None
+                if "moe_held_pairs" in moe:
+                    held = np.asarray(moe["moe_held_pairs"])
+                    rb["bytes"] += held.nbytes
+                if self._route_log and "moe_routes" in moe:
+                    routes = np.asarray(moe["moe_routes"])
+                    rb["bytes"] += routes.nbytes
             tracing.count("engine.readback_bytes", rb["bytes"])
+            if held is not None:
+                tracing.count("engine.moe_held_pairs", int(held.sum()))
+            if routes is not None:
+                # (n_moe_layers, B, k): each live row's token at its
+                # position, before the position advances below
+                for slot, log in self._route_log.items():
+                    if self.live[slot]:
+                        log[self.row_ctx[slot]] = routes[:, slot]
             for i in range(self.batch_size):
                 if self.live[i]:
                     self.row_ctx[i] += 1
@@ -638,6 +675,8 @@ class ContinuousBatchingEngine:
         request continues bit-identically, no prefill recompute."""
         if self.live[slot] or slot in self._pending:
             raise ValueError(f"slot {slot} is not free")
+        # the snapshot holds no prompt: a resumed row's routing is not kept
+        self._route_log.pop(slot, None)
         with tracing.span("engine.resume", slot=slot):
             res = PrefillResult(
                 cache=jax.tree.map(jnp.asarray, pre.kv),
@@ -662,6 +701,7 @@ class ContinuousBatchingEngine:
         self.state = evict(self.state, slot)
         self.row_ctx[slot] = 0
         self.live[slot] = False
+        self._route_log.pop(slot, None)
 
 
 # ---------------------------------------------------------------------------
@@ -755,10 +795,9 @@ class PageAllocator:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PagedDecodeState:
-    """DecodeState whose cache leaves are page pools
-    ``(num_pages, Hkv, page, Dh)`` (scan layers carry the usual leading
-    n_periods axis) plus the ``(B, max_pages)`` int32 block table every
-    layer shares."""
+    """DecodeState whose cache leaves are page pools (:func:`pool_leaves`;
+    scan layers carry the usual leading n_periods axis) plus the
+    ``(B, max_pages)`` int32 block table every layer shares."""
     cache: Any
     cache_len: jax.Array          # (B,) int32: per-row filled prefix
     last_token: jax.Array         # (B,) int32
@@ -769,7 +808,7 @@ class PagedDecodeState:
 class PreemptedRequest:
     """A preempted request's host-side snapshot: the gathered page
     contents per layer (same {"prefix","scan"} structure as the cache,
-    attn leaves shaped (n, Hkv, page, Dh) / (n_periods, n, ...)), its
+    attn leaves shaped (n, H, page, D) / (n_periods, n, ...)), its
     token position and last sampled token.  ``resume`` scatters the
     snapshot into freshly allocated pages — the KV bits are identical,
     so the continuation is identical."""
@@ -780,14 +819,60 @@ class PreemptedRequest:
 
 
 def _check_paged_cfg(cfg: ModelConfig) -> None:
-    if cfg.attention == "mla":
-        raise NotImplementedError(
-            "paged KV is not supported for MLA latent caches")
     for i in range(cfg.n_layers):
         if cfg.block_kind(i) != "attn":
             raise NotImplementedError(
-                "paged KV pools cover GQA attention caches only "
+                "paged KV pools cover attention caches only "
                 f"(layer {i} is {cfg.block_kind(i)!r})")
+
+
+def pool_leaves(cfg: ModelConfig, num_pages: int, page_size: int,
+                dtype) -> dict:
+    """One layer's page pools: GQA k and v ``(num_pages, Hkv, page,
+    Dh)``; MLA one latent ``(num_pages, 1, page, W)`` (the dense
+    cache's ``(B, 1, S, W)`` rows cut into pages)."""
+    if cfg.attention == "mla":
+        from repro.models.attention import mla_latent_width
+        return {"latent": jnp.zeros(
+            (num_pages, 1, page_size, mla_latent_width(cfg)), dtype)}
+    shape = (num_pages, cfg.kv_heads, page_size, cfg.head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+#: device memory a cut pool leaves free beside two copies of itself and
+#: the weights, as a share of the device's limit: a step's activations,
+#: per-layer weight and pool slices, the logits, side caches of pending
+#: prefills
+POOL_RESERVE = 0.15
+
+
+def pool_pages(cfg: ModelConfig, batch: int, max_len: int,
+               page_size: int, dtype, params, device=None) -> int:
+    """Pages of the KV pool (the reserved null page included): one for
+    every row at ``max_len``, unless two such pools do not fit beside
+    the weights ``params`` in the device's memory limit; then as many
+    as fit twice beside them in all but ``POOL_RESERVE`` of it.  Twice:
+    a decode step and an admission each write a new pool before the old
+    one is dropped, as the state is not donated to them.  Every size is
+    static (the device's limit, the weights' bytes, a page's bytes), so
+    nothing else resident moves it.  A device that reports no limit
+    gets the full pool."""
+    pages = batch * max_len // page_size + 1
+    device = device or jax.devices()[0]
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if limit is None:
+        return pages
+    weights = sum(a.nbytes for a in jax.tree.leaves(params))
+    one = jax.eval_shape(lambda: pool_leaves(cfg, 1, page_size, dtype))
+    # as the TPU lays a page out: rows padded to the 128-lane tile
+    per_page = sum(
+        np.prod(a.shape[:-1]) * (-(-a.shape[-1] // 128) * 128)
+        * np.dtype(a.dtype).itemsize for a in jax.tree.leaves(one)) \
+        * sum(cfg.block_kind(i) == "attn" for i in range(cfg.n_layers))
+    if weights + 2 * pages * per_page <= limit:
+        return pages
+    return int(max(2, (limit * (1 - POOL_RESERVE) - weights)
+                   // (2 * per_page)))
 
 
 def init_paged_decode_state(cfg: ModelConfig, batch: int, max_len: int,
@@ -800,16 +885,13 @@ def init_paged_decode_state(cfg: ModelConfig, batch: int, max_len: int,
     if max_len % page_size:
         raise ValueError(f"max_len {max_len} must be a multiple of the "
                          f"page size {page_size}")
-    hk, dh = cfg.kv_heads, cfg.head_dim
-
     def pool():
-        return jnp.zeros((num_pages, hk, page_size, dh), dtype)
+        return pool_leaves(cfg, num_pages, page_size, dtype)
 
-    prefix = [{"attn": {"k": pool(), "v": pool()}}
-              for _ in range(cfg.first_dense_layers)]
+    prefix = [{"attn": pool()} for _ in range(cfg.first_dense_layers)]
     scan = [jax.tree.map(
         lambda a: jnp.broadcast_to(a, (cfg.n_periods,) + a.shape),
-        {"attn": {"k": pool(), "v": pool()}})
+        {"attn": pool()})
         for _ in range(cfg.layer_period)]
     return PagedDecodeState(
         cache={"prefix": prefix, "scan": scan},
@@ -841,38 +923,58 @@ def _map_attn_pairs(cache, other, fn):
 
 
 def _page_chunks(dense_row, n: int, page: int):
-    """(Hkv, max_len, Dh) dense row -> its first n pages,
-    (n, Hkv, page, Dh)."""
+    """(H, max_len, D) dense row -> its first n pages, (n, H, page, D):
+    GQA k or v (H = Hkv, D = Dh) and the MLA latent (H = 1, D = its
+    width) alike."""
     hkv, _, dh = dense_row.shape
     return jnp.moveaxis(
         dense_row[:, :n * page].reshape(hkv, n, page, dh), 1, 0)
 
 
-def _set_table_row(tables, slot: int, idx):
-    """Zero row ``slot`` and write ``idx`` as its leading prefix."""
-    row = jnp.zeros((tables.shape[1],), jnp.int32)
-    row = jax.lax.dynamic_update_slice(row, idx, (0,))
-    return tables.at[slot].set(row)
+def _set_table_row(tables, slot: int, page_ids: list):
+    """Row ``slot`` set to ``page_ids``, zero-padded.  The row is built
+    on the host, so the update has one shape for every page count."""
+    row = np.zeros((tables.shape[1],), np.int32)
+    row[:len(page_ids)] = page_ids
+    return tables.at[slot].set(jnp.asarray(row))
+
+
+def _bucket(n: int) -> int:
+    """The page count an insert of ``n`` pages is padded to: the next of
+    1, 2, 3, 4, 6, 8, 12, 16, ... (each power of two and the point three
+    quarters of the way to it), at most 1.5 x ``n``."""
+    p = 1 << max(n - 1, 0).bit_length()
+    return p * 3 // 4 if p >= 4 and p * 3 // 4 >= n else p
 
 
 def insert_paged(state: PagedDecodeState, result: PrefillResult,
                  slot: int, page_ids: list) -> PagedDecodeState:
     """Scatter a *dense* B=1 prefill cache into pool pages: each
-    layer's (1, Hkv, max_len, Dh) rows are cut into page chunks and
+    layer's (1, H, max_len, D) rows are cut into page chunks and
     written to ``page_ids``; the slot's block-table row becomes
     ``page_ids`` (zero-padded).  Prefill itself stays dense-side —
-    paging happens once, here, at admission."""
-    idx = jnp.asarray(page_ids, jnp.int32)
+    paging happens once, here, at admission.
+
+    The ops run eagerly, each compiled once per shape, so the chunks
+    are cut for ``_bucket(len(page_ids))`` pages (within the side
+    cache) and the padding's are written past the last page, where the
+    scatter drops them: a few shapes for every prompt length."""
     n = len(page_ids)
+    # a table row spans the side cache: max_len // page pages
+    nb = min(_bucket(n), state.block_tables.shape[1])
+    ids = np.full((nb,), np.iinfo(np.int32).max, np.int32)
+    ids[:n] = page_ids
+    idx = jnp.asarray(ids)
 
     def put(pool, dense, scanned):
         if scanned:
-            # (n_periods, num_pages, ...) vs (n_periods, 1, Hkv, S, Dh)
+            # (n_periods, num_pages, ...) vs (n_periods, 1, H, S, D)
             return jax.vmap(lambda p, d: p.at[idx].set(
-                _page_chunks(d, n, p.shape[2]).astype(p.dtype)))(
-                    pool, dense[:, 0])
+                _page_chunks(d, nb, p.shape[2]).astype(p.dtype),
+                mode="drop"))(pool, dense[:, 0])
         return pool.at[idx].set(
-            _page_chunks(dense[0], n, pool.shape[2]).astype(pool.dtype))
+            _page_chunks(dense[0], nb, pool.shape[2]).astype(pool.dtype),
+            mode="drop")
 
     return PagedDecodeState(
         cache=_map_attn_pairs(state.cache, result.cache, put),
@@ -880,7 +982,7 @@ def insert_paged(state: PagedDecodeState, result: PrefillResult,
             jnp.asarray(result.length, jnp.int32)),
         last_token=state.last_token.at[slot].set(
             jnp.asarray(result.next_token, jnp.int32)),
-        block_tables=_set_table_row(state.block_tables, slot, idx))
+        block_tables=_set_table_row(state.block_tables, slot, page_ids))
 
 
 def evict_paged(state: PagedDecodeState, slot: int) -> PagedDecodeState:
@@ -920,7 +1022,7 @@ def resume_paged(state: PagedDecodeState, pre: PreemptedRequest,
         cache=_map_attn_pairs(state.cache, pre.kv, put),
         cache_len=state.cache_len.at[slot].set(pre.length),
         last_token=state.last_token.at[slot].set(pre.last_token),
-        block_tables=_set_table_row(state.block_tables, slot, idx))
+        block_tables=_set_table_row(state.block_tables, slot, page_ids))
 
 
 class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
@@ -966,6 +1068,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                          max_len=max_len, plan=plan, dtype=dtype,
                          prefill_chunk=prefill_chunk,
                          interpret=interpret)
+        if cfg.attention == "mla":
+            tracing.count("engine.latent_pool_bytes", sum(
+                a.nbytes for a in jax.tree.leaves(self.state.cache)))
 
     def _init_state(self):
         return init_paged_decode_state(
@@ -1044,21 +1149,23 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 self._table_pages[i] = start + len(new)
         return len(updates)
 
-    def _megakernel_grid(self, dispatch) -> tuple[int, int]:
+    def _decode_grid(self, dispatch) -> tuple[int, int]:
         """From ``row_ctx`` and the static shapes, no device sync: each
         live row scores ``ceil(ctx / (ppb * page))`` blocks per KV head,
-        where ``ctx`` counts the token this step appends; the grid is
-        (KV heads, batch rows, blocks).  Their ratio is the share of the
-        grid that does work."""
-        if dispatch is None or not dispatch.fuse_wo \
-                or dispatch.impl != "pallas":
+        where ``ctx`` counts the token this step appends; the
+        megakernel's grid is (KV heads, batch rows, blocks), the MLA
+        kernel's (batch rows, blocks), one latent head.  Their ratio is
+        the share of the grid that does work."""
+        if dispatch is None or dispatch.impl != "pallas" or not (
+                dispatch.fuse_wo or dispatch.path == MLA_DECODE):
             return 0, 0
         from repro.kernels.fused_decode_block import paged_blocks
         cfg = self.cfg
         ppb, n_blocks = paged_blocks(dispatch.block_k, self.page_size,
                                      self.state.block_tables.shape[1])
         bk = ppb * self.page_size
-        heads_layers = cfg.n_kv_heads * sum(
+        heads = 1 if dispatch.path == MLA_DECODE else cfg.n_kv_heads
+        heads_layers = heads * sum(
             cfg.block_kind(i) == "attn" for i in range(cfg.n_layers))
         live = sum(-(-(c + 1) // bk)
                    for c, alive in zip(self.row_ctx, self.live) if alive)
@@ -1071,6 +1178,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self.row_ctx[slot] = 0
         self.live[slot] = False
         self._table_pages[slot] = 0
+        self._route_log.pop(slot, None)
 
     def preempt(self, slot: int) -> PreemptedRequest:
         """Save row ``slot``'s KV pages + position to host memory and
@@ -1093,6 +1201,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         """Re-admit a preempted snapshot into free slot ``slot``."""
         if self.live[slot] or slot in self._pending:
             raise ValueError(f"slot {slot} is not free")
+        self._route_log.pop(slot, None)
         with tracing.span("engine.resume", slot=slot):
             ids = self.allocator.alloc(slot, pre.n_pages)
             self.state = resume_paged(self.state, pre, slot, ids)

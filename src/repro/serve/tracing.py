@@ -13,6 +13,20 @@ profile can be laid on one timeline.
 ``count(name, n)`` keeps cumulative counters.  A ``gc.callbacks`` hook
 records every Python collection as a ``host.gc`` span.
 
+``program(name, fn, args)`` notes a jitted launch as it is traced (the
+engine's decode step and prefill chunks), by the shapes of its
+arguments; ``compiled_texts(kind)`` gives those programs' compiled HLO,
+whose instruction names are the names a device trace's ops carry and
+whose op metadata holds each op's ``jax.named_scope`` path (``mla``,
+``moe``, ...).  What a scope's device time is, is for the reader to
+say.
+
+``keep_routes()`` makes the engine keep, from then on, which experts a
+sigmoid-routed model's decode steps sent each request's tokens to
+(``routes``): what a check against a reference needs where the router's
+choice sits within rounding of a tie.  It is off by default, as it grows
+with every token served.
+
 The spans the engine and scheduler open (``docs/serving.md``, "Spans and
 counters"): ``sched.step``, ``sched.admit``, ``engine.begin_prefill``,
 ``engine.prefill``, ``engine.insert``, ``engine.evict``,
@@ -53,6 +67,12 @@ class Span(NamedTuple):
 
 _ring: collections.deque = collections.deque(maxlen=CAPACITY)
 _counters: dict = {}
+#: (name, jitted fn, argument shapes) of every traced launch
+_programs: list = []
+_texts: dict = {}              # program index -> compiled HLO text
+#: with ``keep_routes``: prompt (a tuple of token ids) -> {position:
+#: (n_moe_layers, top_k) int32 expert ids the token there was routed to}
+routes: Optional[dict] = None
 _ids = itertools.count(1)
 _local = threading.local()
 
@@ -113,6 +133,40 @@ def reset() -> None:
     """Empty the ring and zero every counter."""
     _ring.clear()
     _counters.clear()
+
+
+def keep_routes() -> None:
+    """Keep every request's routing in ``routes`` from now on."""
+    global routes
+    if routes is None:
+        routes = {}
+
+
+def program(name: str, fn, args) -> None:
+    """Note the jitted ``fn`` under ``name`` (``"decode:<path>"``,
+    ``"prefill:<path>"``), called on ``args`` (arrays or tracers: only
+    their shapes and dtypes are kept)."""
+    if enabled:
+        import jax
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+        _programs.append((name, fn, shapes))
+
+
+def compiled_texts(kind: str) -> list:
+    """The compiled HLO text of every noted program of ``kind``
+    (``"decode"`` or ``"prefill"``).  A program is lowered and compiled
+    again from its jitted function and argument shapes: the same
+    computation as the launch's, so the same instruction names (with a
+    compile cache, the same executable)."""
+    out = []
+    for i, (name, fn, shapes) in enumerate(_programs):
+        if name.split(":")[0] != kind:
+            continue
+        if i not in _texts:
+            _texts[i] = fn.lower(*shapes).compile().as_text()
+        out.append(_texts[i])
+    return out
 
 
 def self_times(recorded) -> dict:

@@ -320,6 +320,67 @@ def test_preempt_resume_bit_identical(qwen):
     assert toks == toks2
 
 
+def test_insert_writes_each_lease_to_its_table_row(qwen):
+    """Admissions of prompts that take different page counts each write
+    their lease to their block-table row, zero-padded, and the
+    allocator counts every leased page."""
+    cfg, params = qwen
+    eng = PagedContinuousBatchingEngine(
+        params, cfg, batch_size=2, max_len=48, page_size=8, num_pages=16)
+    eng.begin_prefill(0, _prompt(cfg, 41, 5))
+    eng.step()
+    eng.begin_prefill(1, _prompt(cfg, 42, 30))
+    eng.step()
+    for slot in (0, 1):
+        ids = eng.allocator.pages[slot]
+        row = np.asarray(eng.state.block_tables[slot])
+        assert row[:len(ids)].tolist() == ids and not row[len(ids):].any()
+    assert eng.allocator.used_pages == sum(
+        len(v) for v in eng.allocator.pages.values())
+
+
+@pytest.mark.parametrize("n, bucket", [
+    (1, 1), (2, 2), (3, 3), (5, 6), (7, 8), (9, 12), (13, 16)])
+def test_insert_pads_to_a_bucket_and_writes_only_its_pages(qwen, n, bucket):
+    """An insert cuts its chunks for a bucket of pages (a few shapes for
+    every prompt length: its eager ops compile once a bucket) and writes
+    the lease's pages alone: the padding's chunks are dropped, every
+    other page keeps its bits."""
+    from repro.serve.engine import (PrefillResult, _bucket,
+                                    init_decode_state, insert_paged)
+    assert _bucket(n) == bucket and _bucket(700) == 768
+    cfg, params = qwen
+    eng = PagedContinuousBatchingEngine(
+        params, cfg, batch_size=1, max_len=128, page_size=8, num_pages=24)
+    side = init_decode_state(cfg, 1, 128, jnp.float32).cache
+    keys = iter(jax.random.split(jax.random.PRNGKey(n), 64))
+    side = jax.tree.map(lambda a: jax.random.normal(next(keys), a.shape,
+                                                    a.dtype), side)
+    pool0 = jax.tree.map(lambda a: a + 1.0, eng.state.cache)
+    state = eng.state.__class__(cache=pool0, cache_len=eng.state.cache_len,
+                                last_token=eng.state.last_token,
+                                block_tables=eng.state.block_tables)
+    ids = [int(i) for i in np.random.default_rng(n).permutation(
+        np.arange(1, 24))[:n]]
+    res = PrefillResult(cache=side, length=jnp.asarray(n * 8, jnp.int32),
+                        next_token=jnp.asarray(0, jnp.int32))
+    new = insert_paged(state, res, 0, ids)
+    assert np.asarray(new.block_tables[0]).tolist() == ids + [0] * (16 - n)
+    rest = [i for i in range(24) if i not in ids]
+    for old, got, dense in zip(jax.tree.leaves(pool0),
+                               jax.tree.leaves(new.cache),
+                               jax.tree.leaves(side)):
+        old, got, dense = (np.asarray(a) for a in (old, got, dense))
+        if old.ndim == 4:                       # one unscanned layer
+            old, got, dense = old[None], got[None], dense[None]
+        for o, g, d in zip(old, got, dense):    # each scanned layer
+            h, _, dh = d.shape[1:]
+            chunks = d[0, :, :n * 8].reshape(h, n, 8, dh).transpose(
+                1, 0, 2, 3)
+            np.testing.assert_array_equal(g[ids], chunks)
+            np.testing.assert_array_equal(g[rest], o[rest])
+
+
 def test_evict_vs_preempt_page_accounting(qwen):
     """Both verbs return every page to the pool; only preempt carries
     a snapshot forward.  Slot reuse after either is clean."""

@@ -220,11 +220,14 @@ def test_kernel_plan_prefill_shares_bucket_entries():
 
 
 def test_serving_plan_unsupported_config_is_none():
-    mla = SimpleNamespace(name="mla-ish", d_model=128, n_heads=4,
+    # an SSM block is not lowerable; MLA is, as its absorbed head
+    # (tests/test_mla_moe.py)
+    ssm = SimpleNamespace(name="ssm-ish", d_model=128, n_heads=4,
                           kv_heads=4, head_dim=32, d_ff=256,
-                          attention="mla", rope_theta=1e6,
-                          qk_norm=False, n_layers=2)
-    assert lower.serving_plan(mla, max_len=64) is None
+                          attention="gqa", rope_theta=1e6,
+                          qk_norm=False, n_layers=2,
+                          block_kind=lambda i: "ssm")
+    assert lower.serving_plan(ssm, max_len=64) is None
     assert lower.serving_plan(toy_cfg(), max_len=64) is not None
 
 

@@ -2,7 +2,8 @@
 dispatches to, for a described (not attached) TPU v5e, at the published
 starcoder2-7b widths in bf16 (36 query heads, 4 KV heads, d_head 128,
 d_model 4608) and at the serving smoke's shapes (batch 8, page 16,
-max_len 1024, prefill chunk 256).
+max_len 1024, prefill chunk 256); DeepSeek-V3's absorbed MLA kernels at
+its benchmark cell's batch and depth.
 
 Interpret mode never checks TPU block-shape legality or VMEM limits;
 the TPU compiler does, and it is installed here even without a chip.
@@ -24,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.kernels import fused_attention as fa
 from repro.kernels import fused_decode_block as fdb
+from repro.kernels import fused_mla_decode as fmd
 from repro.kernels import fused_qproj_attention as fqa
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -161,6 +163,33 @@ def _decode_block_paged_at(b, max_len, bk):
     return build
 
 
+#: DeepSeek-V3's absorbed MLA: 128 query heads over one latent head of
+#: 576 columns padded to 640, the value its first 512
+MLA_H, MLA_W, MLA_V = 128, 640, 512
+
+
+def _mla_decode_paged_at(b, max_len, bk):
+    """The paged MLA decode kernel at ``dsv3.reasoning-decode``'s batch
+    and depth."""
+    def build(s):
+        pool = s((b * max_len // PAGE + 1, 1, PAGE, MLA_W))
+        return (lambda q, p, n, t: fmd.fused_mla_decode_paged(
+            q, p, n, t, d_v=MLA_V, scale=0.1, block_k=bk)), \
+            (s((b, MLA_H, MLA_W)), pool, s((b,), I32),
+             s((b, max_len // PAGE), I32))
+    return build
+
+
+def _mla_chunk(s):
+    """An absorbed MLA prefill chunk on the masked kernel over the B=1
+    latent side cache."""
+    lat = s((1, 1, 12288, MLA_W))
+    return (lambda q, k, v, n: fa.fused_attention_masked(
+        q, k, v, n, block_q=256, block_k=512)), \
+        (s((1, MLA_H, CHUNK, MLA_W)), lat, s((1, 1, 12288, MLA_V)),
+         s((1,), I32))
+
+
 CASES = {
     # training / cacheless forward: the lse residual's block layout
     "fused_attention_fwd": _unmasked_fwd,
@@ -183,6 +212,10 @@ CASES = {
                                                                 1024),
     "fused_decode_block_paged_b16_4096": _decode_block_paged_at(16, 4096,
                                                                 1024),
+    # the benchmark's deepseek-v3 cell: paged MLA decode, absorbed chunks
+    "fused_mla_decode_paged_b64_12288": _mla_decode_paged_at(64, 12288,
+                                                             512),
+    "fused_attention_masked_mla_chunk": _mla_chunk,
 }
 
 
@@ -196,10 +229,11 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-#: the paged decode kernels at starcoder2-7b widths, by the name their
-#: pallas_call gives them
+#: the paged decode kernels (starcoder2-7b's, DeepSeek-V3's MLA), by the
+#: name their pallas_call gives them
 NAMED = {"fused_decode_block_paged": _decode_block_paged,
-         "fused_attention_paged": _paged}
+         "fused_attention_paged": _paged,
+         "fused_mla_decode_paged": _mla_decode_paged_at(B, MAX_LEN, 512)}
 
 
 @pytest.mark.parametrize("name", sorted(NAMED))
