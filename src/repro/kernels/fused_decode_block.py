@@ -1,6 +1,7 @@
 """Decode megakernel: the whole M=1 attention sub-block in ONE Pallas
-launch — Q projection (+ in-register RoPE), masked scores, online
-softmax, P.V, output projection, residual add.
+launch — Q projection (+ the per-head Q RMS norm of qk-norm models, +
+in-register RoPE), masked scores, online softmax, P.V, output
+projection, residual add.
 
 This pushes the paper's Fig. 5b fusion boundary outward for the decode
 regime the inference surveys identify as launch-overhead- and
@@ -29,6 +30,13 @@ every row at once when a KV head starts, and every row's group output
 is folded through Wo at once when it ends, into an output accumulator
 for all B rows written back once.
 
+qk-norm (qwen3): given a ``q_norm`` weight, both kernels normalise
+each query head's f32 row of the Q tile over its ``D`` lanes
+(``q * rsqrt(mean(q^2) + eps) * w``) before the rotation.  The K norm
+is applied before K is written to the cache, so the kernels never see
+it.  Without a weight neither kernel takes the extra input and each
+traces as it did before the fold.
+
 Forward-only: decode serving never differentiates.
 """
 
@@ -47,10 +55,24 @@ NEG_INF = fa.NEG_INF
 LANES = fa.LANES
 
 
+def _rms_rows(q, w, eps: float):
+    """qk-norm of the f32 Q tile: each row (one query head) over its D
+    lanes, times the (1, D) weight.  All-zero padding rows stay zero."""
+    return q * jax.lax.rsqrt(jnp.mean(q * q, axis=-1, keepdims=True)
+                             + eps) * w
+
+
+def _split_norm(refs, norm_eps):
+    """(the Q-norm weight's ref or None, the refs after it): the weight
+    is an input only when the kernel folds qk-norm."""
+    return (refs[0], refs[1:]) if norm_eps is not None else (None, refs)
+
+
 def _decode_block_kernel(len_ref, x_ref, wq_ref, k_ref, v_ref, wo_ref,
-                         res_ref, o_ref,
-                         q_scr, acc_ref, m_ref, l_ref, y_scr, *,
-                         scale: float, rope_theta):
+                         res_ref, *refs, scale: float, rope_theta,
+                         norm_eps):
+    qn_ref, (o_ref, q_scr, acc_ref, m_ref, l_ref, y_scr) = _split_norm(
+        refs, norm_eps)
     h = pl.program_id(1)
     kj = pl.program_id(2)
     nh = pl.num_programs(1)
@@ -65,6 +87,8 @@ def _decode_block_kernel(len_ref, x_ref, wq_ref, k_ref, v_ref, wo_ref,
         q = jax.lax.dot_general(
             x_ref[0], wq_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if qn_ref is not None:
+            q = _rms_rows(q, qn_ref[...], norm_eps)
         if rope_theta is not None:
             q = fa._rope_tile(q, length - 1, rope_theta)
         q_scr[...] = q
@@ -176,6 +200,14 @@ def _vmem_bytes(bp: int, bq: int, e: int, group: int, d: int, dv: int,
     return max(weights + rows + kv + f32 + (8 << 20), 32 << 20)
 
 
+def _norm_operand(q_norm) -> list:
+    """The kernel's Q-norm input: the (D,) weight as one f32 (1, D)
+    block, or nothing at all when there is no norm to fold."""
+    if q_norm is None:
+        return []
+    return [q_norm.astype(jnp.float32).reshape(1, -1)]
+
+
 def _take_row(tile, r):
     """Row ``r`` (a traced index) of a 2-D f32 tile, as (1, lanes): a
     masked sublane sum, where a dynamic one-row slice would leave a
@@ -185,11 +217,11 @@ def _take_row(tile, r):
 
 
 def _paged_decode_block_kernel(len_ref, tbl_ref, x_ref, wq_ref, wo_ref,
-                               res_ref, k_hbm, v_hbm, o_ref,
-                               k_buf, v_buf, sems, flight, q_all, q_scr,
-                               acc_ref, m_ref, l_ref, o_all, y_scr, *,
-                               scale: float, rope_theta, group: int,
-                               ppb: int, page: int):
+                               res_ref, *refs, scale: float, rope_theta,
+                               norm_eps, group: int, ppb: int, page: int):
+    qn_ref, (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, flight, q_all,
+             q_scr, acc_ref, m_ref, l_ref, o_all, y_scr) = _split_norm(
+        refs, norm_eps)
     h, b, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nh, nb, nj = pl.num_programs(0), pl.num_programs(1), pl.num_programs(2)
     bq, d = q_scr.shape
@@ -226,6 +258,8 @@ def _paged_decode_block_kernel(len_ref, tbl_ref, x_ref, wq_ref, wo_ref,
         q = jnp.zeros((bq, d), jnp.float32)
         for g in range(group):
             q = jnp.where(rows == g, row[:, g * d:(g + 1) * d], q)
+        if qn_ref is not None:
+            q = _rms_rows(q, qn_ref[...], norm_eps)
         if rope_theta is not None:
             q = fa._rope_tile_at(q, length - 1, rope_theta)
         q_scr[...] = q
@@ -315,7 +349,8 @@ def _paged_decode_block_kernel(len_ref, tbl_ref, x_ref, wq_ref, wo_ref,
 
 def fused_decode_block_paged(x, wq, k_pool, v_pool, wo, residual,
                              lengths, block_tables, *, scale=None,
-                             rope_theta=None, block_k: int = 512,
+                             rope_theta=None, q_norm=None,
+                             norm_eps: float = 1e-6, block_k: int = 512,
                              interpret: bool = False):
     """The decode megakernel over a paged KV pool: one Pallas launch for
     the whole M=1 attention sub-block, with KV gathered through a
@@ -362,9 +397,12 @@ def fused_decode_block_paged(x, wq, k_pool, v_pool, wo, residual,
         pl.BlockSpec((group, e, d), lambda h, b_, j, lens_, tbl_: (h, 0, 0)),
         pl.BlockSpec((group * dv, e), lambda h, b_, j, lens_, tbl_: (h, 0)),
         pl.BlockSpec((bp, e), whole),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
     ]
+    norm = _norm_operand(q_norm)
+    if norm:
+        in_specs.append(pl.BlockSpec((1, d), whole))
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                 pl.BlockSpec(memory_space=pl.ANY)]
     scratch = [
         pltpu.VMEM((2, ppb, page, d), k_pool.dtype),
         pltpu.VMEM((2, ppb, page, dv), v_pool.dtype),
@@ -383,8 +421,9 @@ def fused_decode_block_paged(x, wq, k_pool, v_pool, wo, residual,
         out_specs=pl.BlockSpec((bp, e), whole), scratch_shapes=scratch)
     out = pl.pallas_call(
         functools.partial(_paged_decode_block_kernel, scale=scale,
-                          rope_theta=rope_theta, group=group, ppb=ppb,
-                          page=page),
+                          rope_theta=rope_theta,
+                          norm_eps=norm_eps if norm else None,
+                          group=group, ppb=ppb, page=page),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bp, e), x.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -394,18 +433,20 @@ def fused_decode_block_paged(x, wq, k_pool, v_pool, wo, residual,
                                          k_pool.dtype.itemsize)),
         interpret=interpret,
         name="fused_decode_block_paged",
-    )(lens, tbl, xr, wqr, wor, rr, k_pool, v_pool)
+    )(lens, tbl, xr, wqr, wor, rr, *norm, k_pool, v_pool)
     return out[:b].reshape(b, 1, e)
 
 
 def fused_decode_block(x, wq, k, v, wo, residual, lengths, *,
-                       scale=None, rope_theta=None, block_k: int = 512,
+                       scale=None, rope_theta=None, q_norm=None,
+                       norm_eps: float = 1e-6, block_k: int = 512,
                        interpret: bool = False):
     """One Pallas launch for the whole decode attention sub-block.
 
     x, residual: (B, 1, E); wq: (E, Hq, D); k, v: (B, Hkv, Skv, D[v]);
     wo: (Hq, Dv, E) (the model's output-projection layout); lengths:
-    (B,) valid KV prefix per row.  Returns (B, 1, E) =
+    (B,) valid KV prefix per row; ``q_norm``: (D,) qk-norm weight, as
+    in :func:`fused_decode_block_paged`.  Returns (B, 1, E) =
     ``residual + attn_out @ Wo``.
     """
     b, sq, e = x.shape
@@ -429,6 +470,8 @@ def fused_decode_block(x, wq, k, v, wo, residual, lengths, *,
     lens = jnp.minimum(lengths.astype(jnp.int32), skv)
 
     kv_index = functools.partial(_kv_index, hkv=hkv, group=group, bk=bk)
+    norm = _norm_operand(q_norm)
+    whole = lambda b_, h, j, lens_: (0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, hq, nk),
@@ -439,7 +482,7 @@ def fused_decode_block(x, wq, k, v, wo, residual, lengths, *,
             pl.BlockSpec((1, bk, dv), kv_index),
             pl.BlockSpec((1, dv, e), lambda b_, h, j, lens_: (h, 0, 0)),
             pl.BlockSpec((1, bq, e), lambda b_, h, j, lens_: (b_, 0, 0)),
-        ],
+        ] + [pl.BlockSpec((1, d), whole)] * len(norm),
         out_specs=pl.BlockSpec((1, bq, e),
                                lambda b_, h, j, lens_: (b_, 0, 0)),
         scratch_shapes=[
@@ -452,12 +495,13 @@ def fused_decode_block(x, wq, k, v, wo, residual, lengths, *,
     )
     out = pl.pallas_call(
         functools.partial(_decode_block_kernel, scale=scale,
-                          rope_theta=rope_theta),
+                          rope_theta=rope_theta,
+                          norm_eps=norm_eps if norm else None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, bq, e), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="fused_decode_block",
-    )(lens, xr, wqr, kr, vr, wo, rr)
+    )(lens, xr, wqr, kr, vr, wo, rr, *norm)
     return out[:, :1]

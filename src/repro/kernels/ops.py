@@ -12,8 +12,8 @@ runtime:
   the score kernel; Q never stored).  RoPE rides along in-kernel: the
   Q tile is rotated in-register between projection and scores.
 * ``decode_block``     — the M=1 decode megakernel: Q projection
-  (+ RoPE), masked scores, softmax, P.V, output projection AND the
-  residual add in one Pallas launch
+  (+ qk-norm, + RoPE), masked scores, softmax, P.V, output projection
+  AND the residual add in one Pallas launch
   (``kernels/fused_decode_block.py``).
 * ``mla_decode``       — absorbed MLA decode over a paged latent cache:
   every query head against one latent "KV head", the block table
@@ -440,12 +440,15 @@ def decode_block(x, wq, k, v, wo, residual, lengths, *,
                  block_tables: Optional[jax.Array] = None,
                  scale: Optional[float] = None,
                  rope_theta: Optional[float] = None,
+                 q_norm: Optional[jax.Array] = None,
+                 norm_eps: float = 1e-6,
                  impl: str = "auto",
                  block_k: Optional[int] = None,
                  interpret: bool = False,
                  plan: Optional[_plan_rt.PlanDispatch] = None):
     """The M=1 decode megakernel entry point: the whole attention
-    sub-block — Q projection (+ RoPE at ``lengths[b] - 1``), masked
+    sub-block — Q projection (+ the per-head RMS norm of qk-norm
+    models given ``q_norm``, + RoPE at ``lengths[b] - 1``), masked
     scores over the valid prefix, online softmax, P.V, output
     projection and residual add — in ONE Pallas launch
     (``kernels/fused_decode_block.py``).
@@ -477,12 +480,14 @@ def decode_block(x, wq, k, v, wo, residual, lengths, *,
             else:
                 return _pallas_decode_block_paged(
                     x, wq, k, v, wo, residual, lengths, block_tables,
-                    scale=scale, rope_theta=rope_theta, block_k=block_k,
+                    scale=scale, rope_theta=rope_theta, q_norm=q_norm,
+                    norm_eps=norm_eps, block_k=block_k,
                     interpret=interpret)
         if impl == "reference":
             return _ref.paged_decode_block_reference(
                 x, wq, k, v, wo, residual, lengths, block_tables,
-                rope_theta=rope_theta, scale=scale)
+                rope_theta=rope_theta, scale=scale, q_norm=q_norm,
+                norm_eps=norm_eps)
         if impl == "xla":
             k = _xla.gather_paged_kv(k, block_tables)
             v = _xla.gather_paged_kv(v, block_tables)
@@ -502,14 +507,16 @@ def decode_block(x, wq, k, v, wo, residual, lengths, *,
         else:
             return _pallas_decode_block(
                 x, wq, k, v, wo, residual, lengths, scale=scale,
-                rope_theta=rope_theta, block_k=block_k,
-                interpret=interpret)
+                rope_theta=rope_theta, q_norm=q_norm, norm_eps=norm_eps,
+                block_k=block_k, interpret=interpret)
     if impl == "reference":
         return _ref.decode_block_reference(
             x, wq, k, v, wo, residual, lengths, rope_theta=rope_theta,
-            scale=scale)
+            scale=scale, q_norm=q_norm, norm_eps=norm_eps)
     if impl == "xla":
         q = jnp.einsum("bse,ehd->bhsd", x, wq.astype(x.dtype))
+        if q_norm is not None:
+            q = _ref.rms_norm(q, q_norm, norm_eps)
         if rope_theta is not None:
             pos = _ref.rope_positions(sq, skv, lengths=lengths)
             q = _ref.rope(q, pos, rope_theta)

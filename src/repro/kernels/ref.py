@@ -106,6 +106,16 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    """RMS norm oracle over the last axis, in fp32 (Zhang & Sennrich,
+    2019): ``x / sqrt(mean(x^2) + eps) * weight`` — qk-norm of each
+    query head, independent of ``models.common``."""
+    xf = x.astype(jnp.float32)
+    out = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                             + eps)
+    return (out * weight.astype(jnp.float32)).astype(x.dtype)
+
+
 def rope_positions(sq: int, skv: int,
                    lengths: Optional[jax.Array] = None,
                    q_offset: Optional[int] = None) -> jax.Array:
@@ -153,14 +163,19 @@ def decode_block_reference(
     *,
     rope_theta: Optional[float] = None,
     scale: Optional[float] = None,
+    q_norm: Optional[jax.Array] = None,     # (D,) qk-norm weight
+    norm_eps: float = 1e-6,
 ):
     """Unfused oracle of the whole M=1 decode attention sub-block the
-    megakernel folds into one launch: Q projection (+ RoPE at position
-    ``lengths[b] - 1``), masked attention over the valid prefix, output
-    projection, residual add.  At M=1 the end-anchored causal triangle
-    degenerates to the lengths mask itself (``cols < lengths[b]``)."""
+    megakernel folds into one launch: Q projection (+ the per-head RMS
+    norm given ``q_norm``, + RoPE at position ``lengths[b] - 1``),
+    masked attention over the valid prefix, output projection, residual
+    add.  At M=1 the end-anchored causal triangle degenerates to the
+    lengths mask itself (``cols < lengths[b]``)."""
     assert x.shape[1] == 1
     q = jnp.einsum("bse,ehd->bhsd", x, wq.astype(x.dtype))
+    if q_norm is not None:
+        q = rms_norm(q, q_norm, norm_eps)
     if rope_theta is not None:
         pos = rope_positions(1, k.shape[2], lengths=lengths)
         q = rope(q, pos, rope_theta)

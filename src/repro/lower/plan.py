@@ -13,8 +13,9 @@ dispatch site can act on without re-deriving the schedule, plus the
 prediction hooks (`predict`) and the honesty ledger (`record_downgrade`,
 `note`) that keep measured-vs-predicted tables truthful when the
 runtime cannot execute the ideal path (e.g. qk-norm between projection
-and scores makes Q-fusion illegal; RoPE no longer does — the fused
-kernels rotate the Q tile in-register).
+and scores makes ``qproj_attention`` illegal; RoPE does not — the fused
+kernels rotate the Q tile in-register — and neither blocks the decode
+megakernel, which folds the Q norm too).
 
 Pure Python — importable without JAX, like all of ``core/``.
 """

@@ -6,9 +6,10 @@ Two layers:
 
 * :func:`dispatch` — legalise one plan for one call site: map the
   kernel path onto an ``ops`` impl string for the backend, downgrade
-  paths the runtime cannot execute (Q-projection fusion under qk-norm,
-  megakernel without Wo/residual at the call site — RoPE no longer
-  blocks anything: the fused kernels rotate the Q tile in-register),
+  paths the runtime cannot execute (``qproj_attention`` under qk-norm,
+  megakernel without Wo/residual at the call site — RoPE blocks
+  nothing: the fused kernels rotate the Q tile in-register, and the
+  megakernel normalises it too under qk-norm),
   and record every deviation on the plan so validation tables label
   measured numbers with the path actually run.
 * :class:`ServingPlan` — the serving engine's handle: holds the
@@ -103,9 +104,10 @@ def dispatch(plan: ExecutionPlan, *, backend: str = "cpu",
         rope / qk_norm: transformations applied between the Q
                  projection and the scores.  RoPE is *fused
                  in-kernel* (the Q tile is rotated in-register) and
-                 no longer blocks anything; qk-norm still breaks
-                 Q-fusion (a data-dependent normalisation the kernel
-                 does not fold).
+                 blocks nothing.  qk-norm is folded by the decode
+                 megakernel (each query head's row normalised in VMEM
+                 before RoPE) but still breaks ``qproj_attention``,
+                 whose kernels have no norm.
         lengths_masked: the call carries a ``lengths`` mask (decode /
                  chunked prefill over a partially-filled cache).
                  Masked decode is **legal Pallas**: the scalar-prefetch
@@ -127,25 +129,21 @@ def dispatch(plan: ExecutionPlan, *, backend: str = "cpu",
                  gather).
     """
     path = plan.kernel_path
-    if path == DECODE_MEGAKERNEL:
-        blocked = []
-        if entry != "decode_block":
-            blocked.append("Wo/residual not available at this call site")
-        if qk_norm:
-            blocked.append("qk-norm between projection and scores")
-        if blocked:
-            # fall down the ladder: Q-fusion survives when the call
-            # site still hands over x/Wq and nothing but RoPE sits
-            # between projection and scores
-            if entry in ("qproj_attention", "decode_block") \
-                    and not qk_norm:
-                new = QPROJ_ATTENTION
-            elif plan.block(0).fuse_scores:
-                new = FUSED_ATTENTION
-            else:
-                new = UNFUSED
-            plan.record_downgrade("; ".join(blocked), path, new)
-            path = new
+    if path == DECODE_MEGAKERNEL and entry != "decode_block":
+        # fall down the ladder: Q-fusion survives when the call site
+        # still hands over x/Wq and nothing but RoPE sits between
+        # projection and scores (the megakernel folds qk-norm, the
+        # qproj kernels do not)
+        blocked = ["Wo/residual not available at this call site"]
+        if entry == "qproj_attention" and not qk_norm:
+            new = QPROJ_ATTENTION
+        else:
+            if entry == "qproj_attention":
+                blocked.append("qk-norm between projection and scores")
+            new = FUSED_ATTENTION if plan.block(0).fuse_scores \
+                else UNFUSED
+        plan.record_downgrade("; ".join(blocked), path, new)
+        path = new
     if path == QPROJ_ATTENTION:
         blocked = []
         if entry not in ("qproj_attention", "decode_block"):

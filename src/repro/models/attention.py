@@ -10,8 +10,9 @@ Schedule selection (the paper's contribution as a runtime feature):
     hits HBM — RoPE rides along in-register — and at M = 1 the whole
     sub-block escalates to the decode megakernel (ops.decode_block):
     projection, scores, softmax, P.V, output projection and residual
-    add in one launch.  Q-fusion is only legal without qk-norm between
-    projection and scores; the lowering layer records the downgrade.
+    add in one launch.  qk-norm between projection and scores is folded
+    by the megakernel alone: under it ``qproj_attention`` is illegal,
+    and the lowering layer records that downgrade.
 
 The decision reaches this module two ways: ``impl="auto"`` resolves an
 LRU-cached ExecutionPlan from the call shapes inside kernels/ops.py,
@@ -165,12 +166,16 @@ def gqa_forward(params, cfg: ModelConfig, x, positions, *,
     hp = decode and cfg.head_parallel_decode and s == 1 and not dist \
         and _shrules._current()[0] is not None
     # Q-fusion: the kernel projects (and rotates) Q from x itself, so
-    # Q never exists host-side.  Legal only without qk-norm (a
-    # data-dependent transform between projection and scores the
-    # kernel does not fold) — dispatch legalisation already downgrades
-    # such plans; this guard refuses hand-built inconsistent ones.
+    # Q never exists host-side.  ``fuse_wo``: the M=1 step escalates to
+    # the decode megakernel, which also folds the qk-norm of Q; the
+    # qproj kernels do not, so under qk-norm Q-fusion is legal on the
+    # megakernel alone — dispatch legalisation already downgrades other
+    # plans; this guard refuses hand-built inconsistent ones.
     fuse_q = decode and not dist and not hp and plan is not None \
-        and getattr(plan, "fuse_q", False) and not cfg.qk_norm
+        and getattr(plan, "fuse_q", False)
+    fuse_wo = fuse_q and getattr(plan, "fuse_wo", False) and s == 1 \
+        and residual is not None
+    fuse_q = fuse_q and (fuse_wo or not cfg.qk_norm)
 
     def project_kv():
         k = jnp.einsum("bsd,dhe->bhse", x, params["wk"].astype(dt))
@@ -245,13 +250,12 @@ def gqa_forward(params, cfg: ModelConfig, x, positions, *,
             # cache_len + r — exactly this module's `positions`
             theta = float(cfg.rope_theta) if cfg.rope_theta else None
             wq = params["wq"].astype(dt)
-            if getattr(plan, "fuse_wo", False) and s == 1 \
-                    and residual is not None:
+            if fuse_wo:
                 out = ops.decode_block(
                     x, wq, k_buf.astype(dt), v_buf.astype(dt),
                     params["wo"].astype(dt), residual, lengths,
-                    block_tables=block_tables,
-                    rope_theta=theta, impl=impl, block_k=bk,
+                    block_tables=block_tables, rope_theta=theta,
+                    q_norm=params.get("q_norm"), impl=impl, block_k=bk,
                     interpret=interpret, plan=plan)
                 return out, new_cache
             o = ops.qproj_attention(

@@ -13,11 +13,12 @@ edge is the crossover itself.  Without a plan the config-driven
 dispatch is unchanged.
 
 Past the crossover, M=1 decode climbs the whole fusion ladder:
-``decode_megakernel`` (Q projection + in-kernel RoPE, scores, softmax,
-P.V, output projection and the residual add in one Pallas launch) for
-RoPE-only configs, ``qproj_attention`` when the step has multiple rows
-(chunked prefill), ``fused_attention`` when qk-norm keeps Q-fusion
-illegal — the downgrade recorded on the plan, never silent.
+``decode_megakernel`` (Q projection + in-kernel qk-norm and RoPE,
+scores, softmax, P.V, output projection and the residual add in one
+Pallas launch), ``qproj_attention`` when the step has multiple rows
+(chunked prefill), ``fused_attention`` for those chunks when qk-norm
+keeps ``qproj_attention`` illegal — the downgrade recorded on the
+plan, never silent.
 
 Every KV-cached step (decode and each chunked-prefill chunk) carries a
 ``lengths`` mask and stays on the planned Pallas path: the masked

@@ -131,32 +131,47 @@ def test_paged_qproj_and_decode_block_match_oracles():
 
 
 MEGAKERNEL_SWEEP = [
-    # dtype, hq, hkv, page, max_pages, block_k, lengths
+    # dtype, hq, hkv, page, max_pages, block_k, lengths, d, q_norm
     # group 1, 3 pages a block over 7 (not a multiple): 0, 1, page - 1,
     # page, a block edge - 1 / at / + 1, the full table
-    (jnp.float32, 2, 2, 8, 7, 24, [0, 1, 7, 8, 23, 24, 25, 56]),
-    (jnp.bfloat16, 2, 2, 8, 7, 24, [0, 1, 7, 8, 23, 24, 25, 56]),
+    (jnp.float32, 2, 2, 8, 7, 24, [0, 1, 7, 8, 23, 24, 25, 56], 32, False),
+    (jnp.bfloat16, 2, 2, 8, 7, 24, [0, 1, 7, 8, 23, 24, 25, 56], 32, False),
     # group 4, 2 pages a block over 5; two length-0 rows in a row break
     # the prefetch chain
-    (jnp.float32, 8, 2, 16, 5, 32, [31, 0, 0, 33, 80, 16, 1, 32]),
-    (jnp.bfloat16, 8, 2, 16, 5, 32, [31, 0, 0, 33, 80, 16, 1, 32]),
+    (jnp.float32, 8, 2, 16, 5, 32, [31, 0, 0, 33, 80, 16, 1, 32], 32, False),
+    (jnp.bfloat16, 8, 2, 16, 5, 32, [31, 0, 0, 33, 80, 16, 1, 32], 32,
+     False),
     # group 9 (starcoder2's 36/4) padded to 16 sublanes; a KV block
     # longer than the table is clamped to it
-    (jnp.float32, 9, 1, 8, 6, 32, [48, 31, 33, 0, 9, 15]),
-    (jnp.bfloat16, 18, 2, 16, 4, 1024, [64, 0, 17, 16, 63]),
+    (jnp.float32, 9, 1, 8, 6, 32, [48, 31, 33, 0, 9, 15], 32, False),
+    (jnp.bfloat16, 18, 2, 16, 4, 1024, [64, 0, 17, 16, 63], 32, False),
+    # qk-norm (qwen3's 32/8): group 4 at D 128, a length-0 row, rows one
+    # past a block edge and ending on one
+    (jnp.float32, 8, 2, 16, 5, 32, [33, 0, 80, 1, 64], 128, True),
+    (jnp.bfloat16, 8, 2, 16, 5, 32, [33, 0, 80, 1, 64], 128, True),
 ]
 
 
-@pytest.mark.parametrize("dtype,hq,hkv,page,max_pages,block_k,lengths",
-                         MEGAKERNEL_SWEEP)
+def _megakernel_id(i, case):
+    """The id pytest gives a case without ``d`` and ``q_norm``, and
+    ``-q_norm`` on the qk-norm cases."""
+    head = "-".join([jnp.dtype(case[0]).name, *map(str, case[1:6])])
+    return f"{head}-lengths{i}" + ("-q_norm" if case[8] else "")
+
+
+@pytest.mark.parametrize(
+    "dtype,hq,hkv,page,max_pages,block_k,lengths,d,q_norm",
+    MEGAKERNEL_SWEEP,
+    ids=[_megakernel_id(i, c) for i, c in enumerate(MEGAKERNEL_SWEEP)])
 def test_paged_decode_block_retiled_matches_oracle(dtype, hq, hkv, page,
                                                    max_pages, block_k,
-                                                   lengths):
+                                                   lengths, d, q_norm):
     """The paged megakernel's (KV head, row, block of pages) grid ==
     the gather-dense oracle: scattered, non-monotone page ids, page 0
     in every slot past a row's length, rows of length 0 emitting the
-    residual."""
-    b, d, e = len(lengths), 32, 64
+    residual; with a qk-norm weight, each query head normalised before
+    RoPE."""
+    b, e = len(lengths), 64
     n_pages = b * max_pages + 1
     kp, vp, tbl = _pools(b, hkv, n_pages, page, d, max_pages, seed=hq)
     live = np.arange(max_pages)[None, :] < -(
@@ -169,13 +184,15 @@ def test_paged_decode_block_retiled_matches_oracle(dtype, hq, hkv, page,
     wo = jax.random.normal(ks[2], (hq, d, e), jnp.float32) * 0.2
     res = jax.random.normal(ks[3], (b, 1, e), jnp.float32)
     args = [a.astype(dtype) for a in (x, wq, kp, vp, wo, res)]
+    w = (1.0 + 0.3 * jax.random.normal(KEYS[0], (d,))).astype(dtype) \
+        if q_norm else None
     y = fused_decode_block_paged(*args[:5], args[5], lens, tbl,
-                                 rope_theta=1e4, block_k=block_k,
-                                 interpret=True)
+                                 rope_theta=1e4, q_norm=w,
+                                 block_k=block_k, interpret=True)
     assert y.dtype == dtype and y.shape == (b, 1, e)
     y_ref = ref.paged_decode_block_reference(
         *[a.astype(jnp.float32) for a in args[:6]], lens, tbl,
-        rope_theta=1e4)
+        rope_theta=1e4, q_norm=w)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(y_ref), rtol=tol, atol=tol)

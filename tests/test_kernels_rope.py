@@ -129,28 +129,49 @@ def test_masked_qproj_rope_matches_oracle(b, hq, hkv, sq, skv, d,
 # ---------------------------------------------------------------------------
 
 MEGA_SWEEP = [
-    # b, hq, hkv, skv, d, e, lengths, theta
-    (3, 4, 2, 192, 32, 64, [100, 192, 17], THETA),   # GQA, random lens
-    (2, 8, 2, 256, 64, 128, [3, 250], THETA),        # GQA group 4
-    (3, 2, 2, 192, 32, 64, [0, 192, 64], THETA),     # length-0 row
-    (2, 4, 1, 200, 32, 64, [131, 77], THETA),        # MQA, ragged skv
-    (2, 4, 2, 128, 32, 64, [70, 128], None),         # no rope
+    # b, hq, hkv, skv, d, e, lengths, theta, q_norm
+    (3, 4, 2, 192, 32, 64, [100, 192, 17], THETA, False),  # GQA
+    (2, 8, 2, 256, 64, 128, [3, 250], THETA, False),       # group 4
+    (3, 2, 2, 192, 32, 64, [0, 192, 64], THETA, False),    # length-0 row
+    (2, 4, 1, 200, 32, 64, [131, 77], THETA, False),       # MQA, ragged
+    (2, 4, 2, 128, 32, 64, [70, 128], None, False),        # no rope
+    # qk-norm (qwen3): group 4 at D 128, a length-0 row, a row one past
+    # the 64-token block edge
+    (3, 8, 2, 192, 128, 128, [0, 65, 150], THETA, True),
 ]
 
 
-@pytest.mark.parametrize("b,hq,hkv,skv,d,e,lengths,theta", MEGA_SWEEP)
+def _mega_id(i, case):
+    """``lengths<i>`` as pytest would name it, ``-q_norm`` on the
+    qk-norm cases."""
+    head = "-".join(map(str, case[:6]))
+    return f"{head}-lengths{i}-{case[7]}" + ("-q_norm" if case[8] else "")
+
+
+def _q_norm_weight(d, on: bool):
+    """A (D,) qk-norm weight away from 1, or None."""
+    if not on:
+        return None
+    return 1.0 + 0.3 * jax.random.normal(KEYS[6], (d,))
+
+
+@pytest.mark.parametrize(
+    "b,hq,hkv,skv,d,e,lengths,theta,q_norm", MEGA_SWEEP,
+    ids=[_mega_id(i, c) for i, c in enumerate(MEGA_SWEEP)])
 def test_decode_megakernel_matches_oracle(b, hq, hkv, skv, d, e,
-                                          lengths, theta):
-    """One launch == projection + RoPE(lengths-1) + masked attention +
-    output projection + residual, to fp32 tolerance."""
+                                          lengths, theta, q_norm):
+    """One launch == projection + qk-norm (when given) + RoPE(lengths-1)
+    + masked attention + output projection + residual, to fp32
+    tolerance."""
     x, wq, k, v = _inputs(b, hq, hkv, 1, skv, d, e)
     wo = jax.random.normal(KEYS[4], (hq, d, e)) / np.sqrt(hq * d)
     res = jax.random.normal(KEYS[5], (b, 1, e))
     lens = jnp.array(lengths, jnp.int32)
-    o = fused_decode_block(x, wq, k, v, wo, res, lens,
-                           rope_theta=theta, block_k=64, interpret=True)
+    w = _q_norm_weight(d, q_norm)
+    o = fused_decode_block(x, wq, k, v, wo, res, lens, rope_theta=theta,
+                           q_norm=w, block_k=64, interpret=True)
     o_ref = ref.decode_block_reference(x, wq, k, v, wo, res, lens,
-                                       rope_theta=theta)
+                                       rope_theta=theta, q_norm=w)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -170,19 +191,24 @@ def test_decode_megakernel_length0_emits_residual():
 
 def test_ops_decode_block_impls_agree():
     """ops.decode_block: pallas (interpret) / xla / reference compose
-    the same math."""
+    the same math, with and without a qk-norm weight — and the norm
+    does change the answer."""
     x, wq, k, v = _inputs(2, 4, 2, 1, 192, 32, 64)
     wo = jax.random.normal(KEYS[4], (4, 32, 64)) / np.sqrt(4 * 32)
     res = jax.random.normal(KEYS[5], (2, 1, 64))
     lens = jnp.array([100, 192], jnp.int32)
-    outs = {impl: ops.decode_block(
-        x, wq, k, v, wo, res, lens, rope_theta=THETA, impl=impl,
-        interpret=(impl == "pallas"))
-        for impl in ("pallas", "xla", "reference")}
-    for impl in ("pallas", "xla"):
-        np.testing.assert_allclose(np.asarray(outs[impl]),
-                                   np.asarray(outs["reference"]),
-                                   rtol=2e-5, atol=2e-5)
+    refs = []
+    for w in (None, _q_norm_weight(32, True)):
+        outs = {impl: ops.decode_block(
+            x, wq, k, v, wo, res, lens, rope_theta=THETA, q_norm=w,
+            impl=impl, interpret=(impl == "pallas"))
+            for impl in ("pallas", "xla", "reference")}
+        for impl in ("pallas", "xla"):
+            np.testing.assert_allclose(np.asarray(outs[impl]),
+                                       np.asarray(outs["reference"]),
+                                       rtol=2e-5, atol=2e-5)
+        refs.append(np.asarray(outs["reference"]))
+    assert np.abs(refs[0] - refs[1]).max() > 1e-2
 
 
 def test_ops_qproj_rope_fallbacks_agree():

@@ -133,16 +133,18 @@ def test_plan_resolved_tiling():
 def test_dispatch_legalises_qproj_and_records():
     plan = lower.lower(toy_cfg(qk_norm=True), "decode", 256)
     assert plan.kernel_path == lower.DECODE_MEGAKERNEL
-    d = lower.dispatch(plan, backend="cpu", rope=True, qk_norm=True,
-                       lengths_masked=False)
+    d = lower.dispatch(plan, backend="cpu", entry="qproj_attention",
+                       rope=True, qk_norm=True, lengths_masked=False)
     assert d.path == lower.FUSED_ATTENTION and d.impl == "xla"
     assert len(plan.downgrades) == 1
-    # qk-norm is what breaks Q-fusion now; RoPE is fused in-kernel and
-    # must never appear as a downgrade reason
+    # qk-norm is what keeps a qproj_attention entry off Q-fusion (its
+    # kernels have no norm); RoPE is fused in-kernel and must never
+    # appear as a downgrade reason
     assert "qk-norm" in plan.downgrades[0].reason
     assert "RoPE" not in plan.downgrades[0].reason
     # dedup: same deviation again only bumps the count
-    lower.dispatch(plan, backend="cpu", rope=True, qk_norm=True)
+    lower.dispatch(plan, backend="cpu", entry="qproj_attention",
+                   rope=True, qk_norm=True)
     assert len(plan.downgrades) == 1 and plan.downgrades[0].count == 2
     assert "downgrade" in plan.describe()
 
@@ -173,6 +175,24 @@ def test_dispatch_megakernel_ladder():
     assert "Wo/residual" in plan.downgrades[-1].reason
     d2 = lower.dispatch(plan, backend="tpu", entry="attention")
     assert d2.path == lower.FUSED_ATTENTION and not d2.fuse_q
+
+
+def test_dispatch_qk_norm_folds_into_the_megakernel():
+    """qk-norm blocks nothing on a ``decode_block`` entry: the
+    megakernel normalises the Q tile itself, so the plan keeps its rung
+    with an empty ledger; the same plan from a ``qproj_attention``
+    entry still falls past Q-fusion to fused_attention for qk-norm."""
+    plan = lower.lower(toy_cfg(qk_norm=True), "decode", 256)
+    d = lower.dispatch(plan, backend="tpu", entry="decode_block",
+                       rope=True, qk_norm=True, lengths_masked=True,
+                       paged=True)
+    assert d.path == lower.DECODE_MEGAKERNEL and d.impl == "pallas"
+    assert d.fuse_q and d.fuse_wo
+    assert not plan.downgrades
+    d2 = lower.dispatch(plan, backend="tpu", entry="qproj_attention",
+                        qk_norm=True)
+    assert d2.path == lower.FUSED_ATTENTION and not d2.fuse_q
+    assert "qk-norm" in plan.downgrades[-1].reason
 
 
 def test_dispatch_masked_lengths_stays_pallas():
@@ -397,12 +417,10 @@ def test_serve_plan_end_to_end_equivalence_and_crossover(arch):
     for a, b in zip(ref_toks, toks):
         np.testing.assert_array_equal(a, b)
 
-    # (b) the kernel path switched exactly at the crossover, and the
-    # above-crossover rung is arch-dependent: RoPE-only starcoder2
-    # climbs to the decode megakernel (RoPE is fused in-kernel);
-    # qwen3's qk-norm legitimately pins it to fused_attention
-    fused = lower.FUSED_ATTENTION if cfg.qk_norm \
-        else lower.DECODE_MEGAKERNEL
+    # (b) the kernel path switched exactly at the crossover, and above
+    # it both archs climb to the decode megakernel: RoPE is fused
+    # in-kernel, and so is qwen3's qk-norm
+    fused = lower.DECODE_MEGAKERNEL
     decode_res = [r for r in plan.resolutions if r[0] == "decode"]
     assert len(decode_res) == steps
     paths = {ctx: path for (_, ctx, _, path, _) in decode_res}
@@ -419,17 +437,10 @@ def test_serve_plan_end_to_end_equivalence_and_crossover(arch):
     assert fused_steps and all(r[4] == "pallas" for r in fused_steps)
     above = lower.resolve_plan(cfg, "decode", crossover + 1,
                                n_blocks=cfg.n_layers)
-    if cfg.qk_norm:
-        # the only ledger entry is the qk-norm rung-down — never RoPE,
-        # never masked lengths
-        assert above.downgrades
-        assert all("qk-norm" in g.reason and "RoPE" not in g.reason
-                   for g in above.downgrades), above.downgrades
-    else:
-        # RoPE-bearing config on the Q-fused megakernel path with an
-        # EMPTY downgrade ledger (tentpole acceptance)
-        assert not above.downgrades, above.downgrades
-        assert any("RoPE fused in-kernel" in n for n in above.notes)
+    # RoPE-bearing configs, qk-norm or not, on the Q-fused megakernel
+    # path with an EMPTY downgrade ledger
+    assert not above.downgrades, above.downgrades
+    assert any("RoPE fused in-kernel" in n for n in above.notes)
     assert not any("masked-lengths" in g.reason
                    for g in above.downgrades), above.downgrades
     assert any("masked-lengths" in n for n in above.notes)

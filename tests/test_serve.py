@@ -138,8 +138,9 @@ def test_serving_plan_step_dispatch_follows_deepest_live_row():
     plan = lower.serving_plan(cfg, max_len=192)
     # shallow rows only: below the crossover, materialising is free
     assert plan.step_dispatch([3, 10]).path == lower.UNFUSED
-    # a deep live row pulls the whole step past the crossover
-    assert plan.step_dispatch([3, 100]).path == lower.FUSED_ATTENTION
+    # a deep live row pulls the whole step past the crossover, up to
+    # the decode megakernel (which folds qwen3's qk-norm)
+    assert plan.step_dispatch([3, 100]).path == lower.DECODE_MEGAKERNEL
     # the deep row finished and was evicted: back to the cheap path
     assert plan.step_dispatch([3, 10]).path == lower.UNFUSED
     # drained batch resolves the minimal plan instead of a stale depth

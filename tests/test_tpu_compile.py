@@ -2,8 +2,9 @@
 dispatches to, for a described (not attached) TPU v5e, at the published
 starcoder2-7b widths in bf16 (36 query heads, 4 KV heads, d_head 128,
 d_model 4608) and at the serving smoke's shapes (batch 8, page 16,
-max_len 1024, prefill chunk 256); DeepSeek-V3's absorbed MLA kernels at
-its benchmark cell's batch and depth.
+max_len 1024, prefill chunk 256); the qk-norm paged megakernel at
+qwen3-8b's widths and DeepSeek-V3's absorbed MLA kernels at their
+benchmark cells' batch and depth.
 
 Interpret mode never checks TPU block-shape legality or VMEM limits;
 the TPU compiler does, and it is installed here even without a chip.
@@ -163,6 +164,27 @@ def _decode_block_paged_at(b, max_len, bk):
     return build
 
 
+#: qwen3-8b's attention widths: 32 query heads over 8 KV heads of 128
+Q3_HQ, Q3_HKV, Q3_E = 32, 8, 4096
+
+
+def _decode_block_paged_qk_norm_at(b, max_len, bk):
+    """The paged megakernel at ``qwen3-8b.chat``'s batch and depth,
+    folding qk-norm: the (1, D) norm weight block beside the per-KV-head
+    Wq and Wo blocks of a group of 4."""
+    def build(s):
+        x, wq = s((b, 1, Q3_E)), s((Q3_E, Q3_HQ, D))
+        wo = s((Q3_HQ, D, Q3_E))
+        pool = s((b * max_len // PAGE + 1, Q3_HKV, PAGE, D))
+        return (lambda x, wq, k, v, wo, r, n, t, w:
+                fdb.fused_decode_block_paged(x, wq, k, v, wo, r, n, t,
+                                             rope_theta=1e6, q_norm=w,
+                                             block_k=bk)), \
+            (x, wq, pool, pool, wo, x, s((b,), I32),
+             s((b, max_len // PAGE), I32), s((D,)))
+    return build
+
+
 #: DeepSeek-V3's absorbed MLA: 128 query heads over one latent head of
 #: 576 columns padded to 640, the value its first 512
 MLA_H, MLA_W, MLA_V = 128, 640, 512
@@ -212,6 +234,9 @@ CASES = {
                                                                 1024),
     "fused_decode_block_paged_b16_4096": _decode_block_paged_at(16, 4096,
                                                                 1024),
+    # the benchmark's qwen3-8b chat cell: qk-norm folded
+    "fused_decode_block_paged_qk_norm_b16_2048":
+        _decode_block_paged_qk_norm_at(16, 2048, 1024),
     # the benchmark's deepseek-v3 cell: paged MLA decode, absorbed chunks
     "fused_mla_decode_paged_b64_12288": _mla_decode_paged_at(64, 12288,
                                                              512),
