@@ -323,105 +323,6 @@ def fused_attention_masked(q, k, v, lengths, *, causal: bool = True,
 
 
 # ---------------------------------------------------------------------------
-# Paged forward (block-table-indirect KV-cached serving)
-# ---------------------------------------------------------------------------
-
-def _paged_kv_index(h, i, j, lens, tbl, *, hq: int, hkv: int, page: int):
-    """KV *page* index for the paged kernels (grid dim 0 is b*hq): the
-    j-th logical KV block of row b lives wherever the scalar-prefetched
-    block table says — ``tbl[b, j]`` — so the pool needs no per-slot
-    contiguity.  Skipped iterations (pages wholly past lengths[b]) are
-    clamped to the last *live* table entry, so they re-address an
-    already-fetched page instead of issuing fresh HBM DMA; a length-0
-    row reads ``tbl[b, 0]`` (the engine zeroes freed table rows, and
-    page 0 is the allocator's reserved null page)."""
-    b = h // hq
-    last = jnp.maximum((lens[b] + page - 1) // page - 1, 0)
-    return (tbl[b, jnp.minimum(j, last)] * hkv
-            + (h % hq) // (hq // hkv), 0, 0)
-
-
-def _paged_fwd_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                      acc_ref, m_ref, l_ref, **kw):
-    """The paged forward body IS the masked body: the block table only
-    changes *where* a KV block is fetched from (the index map), never
-    the math — lengths masking, block skip and the end-anchored causal
-    triangle all act on logical positions ``kj * page + col``."""
-    _masked_fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, **kw)
-
-
-def fused_attention_paged(q, k_pool, v_pool, lengths, block_tables, *,
-                          causal: bool = True, scale=None,
-                          block_q: int = 512, interpret: bool = False):
-    """Paged-KV layer-fused attention forward: the serving path over a
-    page pool instead of dense per-row caches.
-
-    q: (B, Hq, Sq, D); k_pool, v_pool: (num_pages, Hkv, page, D[v]) —
-    the shared page pool; block_tables: (B, max_pages) int32 page ids
-    (row b's j-th logical KV block lives in pool page
-    ``block_tables[b, j]``); lengths: (B,) valid KV prefix per row.
-
-    Both ``lengths`` and the block table are scalar-prefetched into
-    SMEM (``num_scalar_prefetch=2``) and consumed by the KV index map,
-    so indirection costs no gather: each grid step DMAs exactly the one
-    page the table names.  The KV block size IS the page size, and the
-    masked kernels' block-skip machinery carries over verbatim — pages
-    wholly past ``lengths[b]`` are skipped and their DMAs clamped to
-    the last live page, so a row pays for its *actual* context in both
-    compute and HBM traffic.  Causal semantics and zero-length rows
-    behave exactly as in :func:`fused_attention_masked`.
-
-    Forward-only: serving never differentiates.
-    """
-    b, hq, sq, d = q.shape
-    n_pages, hkv, page, dv = v_pool.shape
-    assert k_pool.shape[:3] == (n_pages, hkv, page)
-    assert page % 8 == 0, "page size must be sublane-aligned (8)"
-    max_pages = block_tables.shape[1]
-    scale = scale if scale is not None else d ** -0.5
-    bq = min(block_q, _round_up(sq))
-    sq_p = _pad_to(sq, bq)
-    nq = sq_p // bq
-    qr = _pad_seq(q.reshape(b * hq, sq, d), sq_p)
-    kr = k_pool.reshape(n_pages * hkv, page, d)
-    vr = v_pool.reshape(n_pages * hkv, page, dv)
-    lens = jnp.minimum(lengths.astype(jnp.int32), max_pages * page)
-    tbl = block_tables.astype(jnp.int32)
-
-    kv_index = functools.partial(_paged_kv_index, hq=hq, hkv=hkv,
-                                 page=page)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b * hq, nq, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, bq, d),
-                         lambda h, i, j, lens, tbl: (h, i, 0)),
-            pl.BlockSpec((1, page, d), kv_index),
-            pl.BlockSpec((1, page, dv), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, bq, dv),
-                               lambda h, i, j, lens, tbl: (h, i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((bq, dv), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-        ],
-    )
-    o = pl.pallas_call(
-        functools.partial(_paged_fwd_kernel, causal=causal, scale=scale,
-                          hq=hq, sq=sq),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, dv), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="fused_attention_paged",
-    )(lens, tbl, qr, kr, vr)
-    return o[:, :sq].reshape(b, hq, sq, dv)
-
-
-# ---------------------------------------------------------------------------
 # Backward
 # ---------------------------------------------------------------------------
 

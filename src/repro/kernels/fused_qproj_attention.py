@@ -253,86 +253,6 @@ def fused_qproj_attention_masked(x, wq, k, v, lengths, *,
     return o[:, :sq].reshape(b, hq, sq, dv)
 
 
-# ---------------------------------------------------------------------------
-# Paged forward (block-table-indirect KV-cached serving)
-# ---------------------------------------------------------------------------
-
-def _qproj_paged_fwd_kernel(len_ref, tbl_ref, x_ref, wq_ref, k_ref,
-                            v_ref, o_ref, q_scr, acc_ref, m_ref, l_ref,
-                            **kw):
-    """Paged body == masked body: the block table only redirects the KV
-    DMAs (index map); the fused Q build, in-register RoPE and masking
-    all act on logical positions."""
-    _qproj_masked_fwd_kernel(len_ref, x_ref, wq_ref, k_ref, v_ref,
-                             o_ref, q_scr, acc_ref, m_ref, l_ref, **kw)
-
-
-def fused_qproj_attention_paged(x, wq, k_pool, v_pool, lengths,
-                                block_tables, *, causal: bool = True,
-                                scale=None, rope_theta=None,
-                                block_q: int = 256,
-                                interpret: bool = False):
-    """Paged-KV Fig. 5b forward: Q = x @ Wq fused into the score kernel
-    over a page pool.  k_pool, v_pool: (num_pages, Hkv, page, D[v]);
-    block_tables: (B, max_pages) int32 page ids; both ``lengths`` and
-    the table are scalar-prefetched (``num_scalar_prefetch=2``) and
-    consumed by the KV index map — see :func:`repro.kernels.
-    fused_attention.fused_attention_paged` for the paging contract.
-    Forward-only."""
-    b, sq, e = x.shape
-    eh, hq, d = wq.shape
-    assert eh == e
-    n_pages, hkv, page, dv = v_pool.shape
-    assert k_pool.shape[:3] == (n_pages, hkv, page)
-    assert page % 8 == 0, "page size must be sublane-aligned (8)"
-    max_pages = block_tables.shape[1]
-    scale = scale if scale is not None else d ** -0.5
-    bq = min(block_q, fa._round_up(sq))
-    sq_p = fa._pad_to(sq, bq)
-    nq = sq_p // bq
-    xr = fa._pad_seq(x, sq_p, axis=1)
-    wqr = jnp.moveaxis(wq, 1, 0)                     # (Hq, E, D)
-    kr = k_pool.reshape(n_pages * hkv, page, d)
-    vr = v_pool.reshape(n_pages * hkv, page, dv)
-    lens = jnp.minimum(lengths.astype(jnp.int32), max_pages * page)
-    tbl = block_tables.astype(jnp.int32)
-
-    kv_index = functools.partial(fa._paged_kv_index, hq=hq, hkv=hkv,
-                                 page=page)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b * hq, nq, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, bq, e),
-                         lambda h, i, j, lens, tbl: (h // hq, i, 0)),
-            pl.BlockSpec((1, e, d),
-                         lambda h, i, j, lens, tbl: (h % hq, 0, 0)),
-            pl.BlockSpec((1, page, d), kv_index),
-            pl.BlockSpec((1, page, dv), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, bq, dv),
-                               lambda h, i, j, lens, tbl: (h, i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, dv), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-        ],
-    )
-    o = pl.pallas_call(
-        functools.partial(_qproj_paged_fwd_kernel, causal=causal,
-                          scale=scale, hq=hq, sq=sq,
-                          rope_theta=rope_theta),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, dv), x.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="fused_qproj_attention_paged",
-    )(lens, tbl, xr, wqr, kr, vr)
-    return o[:, :sq].reshape(b, hq, sq, dv)
-
-
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def fused_qproj_attention(x, wq, k, v, causal=True, scale=None,

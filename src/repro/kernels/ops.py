@@ -35,13 +35,15 @@ A ``lengths`` mask (KV-cached decode / chunked prefill) stays on the
 Pallas path: the masked scalar-prefetch kernels
 (``fused_attention_masked`` / ``fused_qproj_attention_masked``) mask
 score tiles in-kernel and skip KV blocks wholly past each row's valid
-prefix.  A ``block_tables`` argument additionally switches k/v to a
-*paged* pool (``num_pages, Hkv, page, D``) indexed block-table-
-indirectly by the paged kernel variants — the serving engine's
-free-list-allocated KV cache.  Only genuinely unsupported calls
-(non-float dtypes, malformed lengths/tables) warn once *per reason*
-and fall back to the chunked-XLA path (paged calls gather the pool
-dense first), with the concrete reason recorded on the plan's
+prefix.  A ``block_tables`` argument switches k/v to a *paged* pool
+(``num_pages, Hkv, page, D``) — the serving engine's free-list-
+allocated KV cache.  Two kernels read a pool through its table: the
+decode megakernel (``fused_decode_block_paged``) and the MLA decode
+kernel (``fused_mla_decode_paged``).  Every other paged call gathers
+the pool dense once (:func:`_gather_pools`) and takes the masked
+routing.  Only genuinely unsupported calls (non-float dtypes,
+malformed lengths/tables) warn once *per reason* and fall back to the
+chunked-XLA path, with the concrete reason recorded on the plan's
 downgrade ledger so measured-vs-predicted tables never mislabel the
 executed path.
 """
@@ -62,8 +64,6 @@ from repro.kernels import xla_fallback as _xla
 from repro.kernels.fused_attention import fused_attention as _pallas_attn
 from repro.kernels.fused_attention import (
     fused_attention_masked as _pallas_attn_masked)
-from repro.kernels.fused_attention import (
-    fused_attention_paged as _pallas_attn_paged)
 from repro.kernels.fused_decode_block import (
     fused_decode_block as _pallas_decode_block)
 from repro.kernels.fused_decode_block import (
@@ -74,8 +74,6 @@ from repro.kernels.fused_qproj_attention import (
     fused_qproj_attention as _pallas_qproj_attn)
 from repro.kernels.fused_qproj_attention import (
     fused_qproj_attention_masked as _pallas_qproj_attn_masked)
-from repro.kernels.fused_qproj_attention import (
-    fused_qproj_attention_paged as _pallas_qproj_attn_paged)
 from repro.kernels.ssd_scan import ssd_scan as _pallas_ssd
 from repro.kernels.xla_fallback import ssd_step  # re-export
 from repro.lower import cache as _plan_cache
@@ -84,7 +82,7 @@ from repro.lower import runtime as _plan_rt
 __all__ = ["attention", "qproj_attention", "decode_block", "mla_decode",
            "ssd",
            "ssd_step", "schedule_for", "default_impl",
-           "reset_lengths_downgrade_warning"]
+           "reset_downgrade_warnings"]
 
 
 def default_impl() -> str:
@@ -127,8 +125,12 @@ def _auto_dispatch(entry: str, sq: int, skv: int, d: int, hq: int,
 #: (each distinct failure mode surfaces exactly once per process).
 _warned_downgrade_reasons: set = set()
 
+#: the kernels a call can miss, as :func:`_downgrade` names them
+_MASKED_KERNEL = "masked-lengths Pallas kernel"
+_PAGED_KERNEL = "paged-KV Pallas kernel"
 
-def reset_lengths_downgrade_warning() -> None:
+
+def reset_downgrade_warnings() -> None:
     """Re-arm the per-reason warn-once registry of :func:`_downgrade`
     (test isolation: the registry must not leak an 'already warned'
     state between tests)."""
@@ -145,7 +147,7 @@ def _downgrade(plan, reason: str, *, kernel: str) -> str:
         warnings.warn(
             f"attention: call cannot take the {kernel} ({reason}); "
             "downgrading impl='pallas' to the chunked-XLA streaming "
-            "path (recorded on the ExecutionPlan)", stacklevel=4)
+            "path (recorded on the ExecutionPlan)", stacklevel=3)
         _warned_downgrade_reasons.add(key)
     if plan is not None:
         plan.plan.record_downgrade(
@@ -153,16 +155,26 @@ def _downgrade(plan, reason: str, *, kernel: str) -> str:
     return "xla"
 
 
-def _downgrade_lengths(plan, reason: str) -> str:
-    return _downgrade(plan, reason,
-                      kernel="masked-lengths Pallas kernel")
+def _gather_pools(impl: str, block_tables, *pools) -> list:
+    """Densify page pools (num_pages, Hkv, page, D) through the
+    (B, max_pages) table into (B, Hkv, max_pages*page, D): the one
+    paged step of every path but the two table-reading kernels.  The
+    reference impl gathers with its oracle (``ref.gather_pages``), every
+    other impl with the one XLA gather (``xla_fallback.gather_paged_kv``,
+    which also takes the malformed tables a downgrade lands with)."""
+    gather = _ref.gather_pages if impl == "reference" \
+        else _xla.gather_paged_kv
+    return [gather(p, block_tables) for p in pools]
 
 
-def _downgrade_paged(plan, reason: str) -> str:
-    """The honest paged->masked-dense downgrade: the fallback gathers
-    the pool dense through the table, then runs the lengths-masked
-    chunked-XLA path."""
-    return _downgrade(plan, reason, kernel="paged-KV Pallas kernel")
+def _kv_len(k, lengths, block_tables) -> int:
+    """Score columns of a call: the dense cache's length, or a paged
+    call's table width in tokens (a paged call must carry lengths)."""
+    if block_tables is None:
+        return k.shape[2]
+    if lengths is None:
+        raise ValueError("paged KV requires lengths")
+    return block_tables.shape[1] * k.shape[2]
 
 
 _MASKED_DTYPES = ("float32", "bfloat16", "float16")
@@ -214,15 +226,13 @@ def _masked_unsupported(x, lengths, causal: bool, q_offset,
     return None
 
 
-def _paged_unsupported(x, lengths, block_tables, causal: bool, q_offset,
-                       sq: int, page: int) -> Optional[str]:
-    """Reason string when the paged Pallas kernels cannot serve this
-    call, else None.  Paged kernels inherit every masked-kernel
-    constraint (they mask the same way) plus the block-table
-    contract: a 2-D integral (B, max_pages) table and a sublane-aligned
-    page size."""
-    if lengths is None:
-        return "paged call without lengths (the table has no row depth)"
+def _paged_unsupported(x, lengths, block_tables, page: int
+                       ) -> Optional[str]:
+    """Reason string when a table-reading kernel (the paged decode
+    megakernel, the MLA decode kernel) cannot serve this call, else
+    None: the masked-kernel constraints (M=1, not causal) plus the
+    block-table contract — a 2-D integral (B, max_pages) table and a
+    sublane-aligned page size."""
     if getattr(block_tables, "ndim", 0) != 2:
         return ("block_tables must be (B, max_pages), got shape "
                 f"{getattr(block_tables, 'shape', None)}")
@@ -233,14 +243,14 @@ def _paged_unsupported(x, lengths, block_tables, causal: bool, q_offset,
                 f"lengths rows {lengths.shape[0]}")
     if page % 8:
         return f"page size {page} not sublane-aligned (8)"
-    return _masked_unsupported(x, lengths, causal, q_offset, sq)
+    return _masked_unsupported(x, lengths, False, None, 1)
 
 
 def _resolve(entry: str, impl: str, plan, sq: int, skv: int, d: int,
              hq: int, hkv: int, lengths, block_q, block_k, interpret):
     """Shared impl/tiling resolution for the attention entry points.
     Returns the (possibly auto-resolved) plan too, so the caller can
-    record lengths downgrades on it."""
+    record downgrades on it."""
     if plan is not None:
         if impl == "auto":
             impl = plan.impl
@@ -287,47 +297,21 @@ def attention(q, k, v, *, causal: bool = True,
 
     ``block_tables``: (B, max_pages) int32 page ids — k and v are then
     the *page pools* (num_pages, Hkv, page, D[v]) instead of dense
-    caches, indexed block-table-indirectly by the paged Pallas kernel
-    (``lengths`` required).  Unsupported paged calls gather the pool
-    dense and take the masked chunked-XLA path, with the paged->masked-
-    dense downgrade warned + recorded.
+    caches (``lengths`` required).  The pools are gathered dense once
+    and the call takes the masked routing above: no kernel of this
+    entry reads a pool through its table.
     """
     b, hq, sq, d = q.shape
-    if block_tables is not None:
-        if lengths is None:
-            raise ValueError("paged attention requires lengths")
-        n_pages, hkv, page, dv = v.shape
-        skv = block_tables.shape[1] * page
-        impl, block_q, block_k, interpret, plan = _resolve(
-            "attention", impl, plan, sq, skv, d, hq, hkv, lengths,
-            block_q, block_k, interpret)
-        if impl == "pallas":
-            reason = _paged_unsupported(q, lengths, block_tables,
-                                        causal, q_offset, sq, page)
-            if reason is not None:
-                impl = _downgrade_paged(plan, reason)
-            else:
-                return _pallas_attn_paged(
-                    q, k, v, lengths, block_tables, causal=causal,
-                    scale=scale, block_q=block_q, interpret=interpret)
-        if impl == "xla":
-            return _xla.paged_chunked_attention(
-                q, k, v, lengths, block_tables, causal=causal,
-                scale=scale, q_offset=q_offset, block_q=block_q,
-                block_k=block_k)
-        if impl == "reference":
-            return _ref.paged_attention_reference(
-                q, k, v, lengths, block_tables, causal=causal,
-                scale=scale, q_offset=q_offset)
-        raise ValueError(f"unknown impl {impl!r}")
-    skv, hkv = k.shape[2], k.shape[1]
+    skv = _kv_len(k, lengths, block_tables)
     impl, block_q, block_k, interpret, plan = _resolve(
-        "attention", impl, plan, sq, skv, d, hq, hkv, lengths,
+        "attention", impl, plan, sq, skv, d, hq, k.shape[1], lengths,
         block_q, block_k, interpret)
+    if block_tables is not None:
+        k, v = _gather_pools(impl, block_tables, k, v)
     if lengths is not None and impl == "pallas":
         reason = _masked_unsupported(q, lengths, causal, q_offset, sq)
         if reason is not None:
-            impl = _downgrade_lengths(plan, reason)
+            impl = _downgrade(plan, reason, kernel=_MASKED_KERNEL)
         else:
             return _pallas_attn_masked(
                 q, k, v, lengths, causal=causal, scale=scale,
@@ -365,52 +349,20 @@ def qproj_attention(x, wq, k, v, *, causal: bool = True,
     the Pallas kernels (row r sits at ``q_offset + r``, or
     ``lengths[b] - Sq + r`` on the masked path), on the materialised Q
     in the fallbacks.  ``block_tables``: (B, max_pages) page ids — k, v
-    become pools (num_pages, Hkv, page, D[v]); see :func:`attention`."""
+    become pools (num_pages, Hkv, page, D[v]), gathered dense once
+    before the masked routing, as in :func:`attention`."""
     b, sq, e = x.shape
     hq, d = wq.shape[1], wq.shape[-1]
-    if block_tables is not None:
-        if lengths is None:
-            raise ValueError("paged qproj_attention requires lengths")
-        n_pages, hkv, page, dv = v.shape
-        skv = block_tables.shape[1] * page
-        impl, block_q, block_k, interpret, plan = _resolve(
-            "qproj_attention", impl, plan, sq, skv, d, hq, hkv, lengths,
-            block_q, block_k, interpret)
-        if impl == "pallas":
-            reason = _paged_unsupported(x, lengths, block_tables,
-                                        causal, q_offset, sq, page)
-            if reason is not None:
-                impl = _downgrade_paged(plan, reason)
-            else:
-                return _pallas_qproj_attn_paged(
-                    x, wq, k, v, lengths, block_tables, causal=causal,
-                    scale=scale, rope_theta=rope_theta, block_q=block_q,
-                    interpret=interpret)
-        if impl == "reference":
-            return _ref.paged_qproj_attention_reference(
-                x, wq, k, v, lengths, block_tables, causal=causal,
-                scale=scale, rope_theta=rope_theta, q_offset=q_offset)
-        if impl == "xla":
-            kd = _xla.gather_paged_kv(k, block_tables)
-            vd = _xla.gather_paged_kv(v, block_tables)
-            q = jnp.einsum("bse,ehd->bhsd", x, wq.astype(x.dtype))
-            if rope_theta is not None:
-                pos = _ref.rope_positions(sq, skv, lengths=lengths,
-                                          q_offset=q_offset)
-                q = _ref.rope(q, pos, rope_theta)
-            return _xla.chunked_attention(
-                q, kd, vd, causal=causal, scale=scale,
-                q_offset=q_offset, lengths=lengths, block_q=block_q,
-                block_k=block_k)
-        raise ValueError(f"unknown impl {impl!r}")
-    skv, hkv = k.shape[2], k.shape[1]
+    skv = _kv_len(k, lengths, block_tables)
     impl, block_q, block_k, interpret, plan = _resolve(
-        "qproj_attention", impl, plan, sq, skv, d, hq, hkv, lengths,
-        block_q, block_k, interpret)
+        "qproj_attention", impl, plan, sq, skv, d, hq, k.shape[1],
+        lengths, block_q, block_k, interpret)
+    if block_tables is not None:
+        k, v = _gather_pools(impl, block_tables, k, v)
     if lengths is not None and impl == "pallas":
         reason = _masked_unsupported(x, lengths, causal, q_offset, sq)
         if reason is not None:
-            impl = _downgrade_lengths(plan, reason)
+            impl = _downgrade(plan, reason, kernel=_MASKED_KERNEL)
         else:
             return _pallas_qproj_attn_masked(
                 x, wq, k, v, lengths, causal=causal, scale=scale,
@@ -460,50 +412,31 @@ def decode_block(x, wq, k, v, wo, residual, lengths, *,
     more HBM round-trips).  ``block_tables``: (B, max_pages) page ids —
     k, v become pools (num_pages, Hkv, page, D[v]) and the one-launch
     kernel gathers KV through the table, ``block_k // page`` pages a
-    grid step."""
+    grid step; a call it cannot serve, and every non-Pallas impl,
+    gathers the pools dense first."""
     b, sq, e = x.shape
     assert sq == 1, "decode_block is the M=1 decode schedule"
     hq, d = wq.shape[1], wq.shape[-1]
+    skv = _kv_len(k, lengths, block_tables)
+    impl, _, block_k, interpret, plan = _resolve(
+        "decode_block", impl, plan, sq, skv, d, hq, k.shape[1], lengths,
+        None, block_k, interpret)
     if block_tables is not None:
-        if lengths is None:
-            raise ValueError("paged decode_block requires lengths")
-        n_pages, hkv, page, dv = v.shape
-        skv = block_tables.shape[1] * page
-        impl, _, block_k, interpret, plan = _resolve(
-            "decode_block", impl, plan, sq, skv, d, hq, hkv, lengths,
-            None, block_k, interpret)
         if impl == "pallas":
             reason = _paged_unsupported(x, lengths, block_tables,
-                                        False, None, sq, page)
-            if reason is not None:
-                impl = _downgrade_paged(plan, reason)
-            else:
+                                        k.shape[2])
+            if reason is None:
                 return _pallas_decode_block_paged(
                     x, wq, k, v, wo, residual, lengths, block_tables,
                     scale=scale, rope_theta=rope_theta, q_norm=q_norm,
                     norm_eps=norm_eps, block_k=block_k,
                     interpret=interpret)
-        if impl == "reference":
-            return _ref.paged_decode_block_reference(
-                x, wq, k, v, wo, residual, lengths, block_tables,
-                rope_theta=rope_theta, scale=scale, q_norm=q_norm,
-                norm_eps=norm_eps)
-        if impl == "xla":
-            k = _xla.gather_paged_kv(k, block_tables)
-            v = _xla.gather_paged_kv(v, block_tables)
-            block_tables = None     # fall through to the dense XLA path
-        if impl not in ("xla",):
-            raise ValueError(f"unknown impl {impl!r}")
-    else:
-        skv, hkv = k.shape[2], k.shape[1]
-        dv = v.shape[-1]
-        impl, _, block_k, interpret, plan = _resolve(
-            "decode_block", impl, plan, sq, skv, d, hq, hkv, lengths,
-            None, block_k, interpret)
+            impl = _downgrade(plan, reason, kernel=_PAGED_KERNEL)
+        k, v = _gather_pools(impl, block_tables, k, v)
     if impl == "pallas":
         reason = _masked_unsupported(x, lengths, False, None, sq)
         if reason is not None:
-            impl = _downgrade_lengths(plan, reason)
+            impl = _downgrade(plan, reason, kernel=_MASKED_KERNEL)
         else:
             return _pallas_decode_block(
                 x, wq, k, v, wo, residual, lengths, scale=scale,
@@ -545,17 +478,16 @@ def mla_decode(q, pool, lengths, block_tables, *, d_v: int, scale: float,
         interpret = interpret or plan.interpret
     elif impl == "auto":
         impl = default_impl()
-    page = pool.shape[2]
     block_k = block_k or 512
     if impl == "pallas":
-        reason = _paged_unsupported(q, lengths, block_tables, False, None,
-                                    1, page)
+        reason = _paged_unsupported(q, lengths, block_tables,
+                                    pool.shape[2])
         if reason is None:
             return _pallas_mla_decode_paged(
                 q, pool, lengths, block_tables, d_v=d_v, scale=scale,
                 block_k=block_k, interpret=interpret)
-        impl = _downgrade_paged(plan, reason)
-    lat = _xla.gather_paged_kv(pool, block_tables)    # (B, 1, S, W)
+        impl = _downgrade(plan, reason, kernel=_PAGED_KERNEL)
+    lat, = _gather_pools(impl, block_tables, pool)    # (B, 1, S, W)
     q4 = q[:, :, None]
     if impl == "xla":
         o = _xla.chunked_attention(q4, lat, lat[..., :d_v], causal=False,

@@ -200,24 +200,6 @@ def gather_pages(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
     return jnp.moveaxis(g, 2, 1).reshape(b, hkv, max_pages * page, d)
 
 
-def paged_attention_reference(q, k_pool, v_pool, lengths, block_tables,
-                              **kw):
-    """Oracle for :func:`fused_attention_paged`: gather the pages dense
-    (the memory layout the paged kernel exists to avoid), then the
-    unfused lengths-masked attention."""
-    return attention_reference(
-        q, gather_pages(k_pool, block_tables),
-        gather_pages(v_pool, block_tables), lengths=lengths, **kw)
-
-
-def paged_qproj_attention_reference(x, wq, k_pool, v_pool, lengths,
-                                    block_tables, **kw):
-    """Oracle for :func:`fused_qproj_attention_paged`."""
-    return qproj_attention_reference(
-        x, wq, gather_pages(k_pool, block_tables),
-        gather_pages(v_pool, block_tables), lengths=lengths, **kw)
-
-
 def paged_decode_block_reference(x, wq, k_pool, v_pool, wo, residual,
                                  lengths, block_tables, **kw):
     """Oracle for :func:`fused_decode_block_paged`."""

@@ -114,34 +114,15 @@ def chunked_attention(
 def gather_paged_kv(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
     """Densify a paged KV pool with one XLA gather:
     (num_pages, Hkv, page, D) through (B, max_pages) int32 page ids ->
-    (B, Hkv, max_pages*page, D).  This is the honest non-TPU fallback
-    for the paged Pallas kernels — the gather materialises exactly the
-    dense layout the block-table-indirect DMAs avoid."""
+    (B, Hkv, max_pages*page, D).  ``kernels.ops`` densifies a pool with
+    it on every impl but the reference, wherever a call does not take
+    one of the two kernels that read a pool through its table."""
     b, max_pages = block_tables.shape
     _, hkv, page, d = pool.shape
     # tolerate the malformed tables this path is the downgrade for
     idx = block_tables.astype(jnp.int32)
     g = jnp.take(pool, idx, axis=0)           # (B, maxP, Hkv, page, D)
     return jnp.moveaxis(g, 2, 1).reshape(b, hkv, max_pages * page, d)
-
-
-def paged_chunked_attention(
-    q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-    lengths: jax.Array, block_tables: jax.Array, *,
-    causal: bool = True,
-    scale: Optional[float] = None,
-    q_offset: Optional[int] = None,
-    block_q: int = 512,
-    block_k: int = 1024,
-) -> jax.Array:
-    """Paged-KV attention on any backend: gather the pages dense, then
-    the chunked online-softmax fallback with the ``lengths`` mask (the
-    masked semantics are identical — the table only changes storage)."""
-    return chunked_attention(
-        q, gather_paged_kv(k_pool, block_tables),
-        gather_paged_kv(v_pool, block_tables),
-        causal=causal, scale=scale, q_offset=q_offset, lengths=lengths,
-        block_q=block_q, block_k=block_k)
 
 
 def chunked_ssd(

@@ -119,14 +119,15 @@ def dispatch(plan: ExecutionPlan, *, backend: str = "cpu",
                  on the plan, never a downgrade.
         paged:   the call site stores KV as a page pool + (B, max_pages)
                  block tables (the serving engine's free-list cache).
-                 On a Pallas impl this is **legal**: the paged kernel
-                 variants scalar-prefetch the table and index KV
-                 through it (a note, never a downgrade).  On any other
-                 impl the pool must be gathered dense before the masked
-                 path runs — recorded as the honest paged->masked-dense
-                 downgrade (the dispatch stays ``paged`` so the call
-                 site still passes its tables; ``kernels.ops`` does the
-                 gather).
+                 Only ``decode_megakernel`` on a Pallas impl reads the
+                 pool through its table (a note, never a downgrade);
+                 every other Pallas path gathers the pool dense and
+                 runs the masked kernel, and records nothing.  On any
+                 other impl the pool is gathered dense before the
+                 masked path runs — recorded as the honest
+                 paged->masked-dense downgrade (the dispatch stays
+                 ``paged`` so the call site still passes its tables;
+                 ``kernels.ops`` does the gather).
     """
     path = plan.kernel_path
     if path == DECODE_MEGAKERNEL and entry != "decode_block":
@@ -163,15 +164,16 @@ def dispatch(plan: ExecutionPlan, *, backend: str = "cpu",
                   "masked Pallas kernels (KV blocks past each row's "
                   "valid prefix skipped)")
     if paged:
-        if impl == "pallas":
-            plan.note("paged KV: block-table-indirect Pallas kernels "
-                      "(scalar-prefetched page table drives the KV "
-                      "DMAs; skipped pages issue none)")
-        else:
+        if impl != "pallas":
             plan.record_downgrade(
                 f"paged KV block tables unsupported on impl "
                 f"'{impl}': pool gathered to masked-dense",
                 path, path)
+        elif path == DECODE_MEGAKERNEL:
+            plan.note("paged KV: the decode megakernel reads the pool "
+                      "through its block table (scalar-prefetched page "
+                      "table drives the KV copies; skipped pages issue "
+                      "none)")
     t = plan.tiling
     return PlanDispatch(plan=plan, path=path, impl=impl,
                         block_q=t.block_q, block_k=t.block_kv,
@@ -398,8 +400,9 @@ def serving_plan(cfg, max_len: int, *, backend: str = "cpu",
     its config-driven dispatch.  MLA is planned as its absorbed head
     (:func:`absorbed_head`), its M=1 decode on ``MLA_DECODE``.  ``paged``/``page_size``: the
     engine stores KV as a free-list page pool + block tables; every
-    dispatch is then legalised on the ``paged`` axis (Pallas impls take
-    the block-table-indirect kernels, others record the honest
+    dispatch is then legalised on the ``paged`` axis (on Pallas the
+    decode megakernel reads the pool through its table and every other
+    path gathers it dense; other impls record the honest
     paged->masked-dense downgrade)."""
     attn_cfg = cfg
     if getattr(cfg, "attention", "gqa") == "mla":

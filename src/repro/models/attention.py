@@ -142,7 +142,8 @@ def gqa_forward(params, cfg: ModelConfig, x, positions, *,
     b's new token lands in pool page
     ``block_tables[b, cache_len[b] // page]`` at offset
     ``cache_len[b] % page``, and attention reads KV back through the
-    same table (the paged kernels / gather fallback in kernels.ops).
+    same table: the decode megakernel on Pallas reads the pool through
+    it, every other path has kernels.ops gather the pool dense first.
     Dead rows (zeroed table, cache_len 0) write into the allocator's
     reserved null page 0, whose content no live row ever reads.
     Single-token per-row decode only — prefill stays dense-side and is

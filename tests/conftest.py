@@ -19,15 +19,15 @@ def _clear_jax_caches_between_modules():
 
 
 @pytest.fixture(autouse=True)
-def _reset_lengths_downgrade_warning():
-    """Re-arm kernels.ops's warn-once masked-lengths downgrade flag
-    around every test, so one test tripping (or asserting on) the
-    warning cannot hide it from — or fail — another."""
+def _reset_downgrade_warnings():
+    """Re-arm kernels.ops's warn-once downgrade registry around every
+    test, so one test tripping (or asserting on) a warning cannot hide
+    it from — or fail — another."""
     try:
         from repro.kernels import ops
     except ImportError:          # pure-DSE tier without jax installed
         yield
         return
-    ops.reset_lengths_downgrade_warning()
+    ops.reset_downgrade_warnings()
     yield
-    ops.reset_lengths_downgrade_warning()
+    ops.reset_downgrade_warnings()

@@ -200,7 +200,7 @@ def test_ops_inconsistent_q_offset_downgrades_not_silently_diverges():
     — never return a silently different answer."""
     q, k, v = _qkv(1, 2, 2, 4, 256, 32)
     lens = jnp.array([8], jnp.int32)
-    ops.reset_lengths_downgrade_warning()
+    ops.reset_downgrade_warnings()
     import warnings as _w
     with _w.catch_warnings(record=True) as w:
         _w.simplefilter("always")
@@ -212,7 +212,7 @@ def test_ops_inconsistent_q_offset_downgrades_not_silently_diverges():
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_xla),
                                rtol=2e-5, atol=2e-5)
     # the consistent q_offset (= lengths - Sq) stays on the Pallas path
-    ops.reset_lengths_downgrade_warning()
+    ops.reset_downgrade_warnings()
     with _w.catch_warnings(record=True) as w2:
         _w.simplefilter("always")
         ops.attention(q, k, v, causal=True, lengths=lens,
@@ -228,7 +228,7 @@ def test_ops_causal_multirow_lengths_without_q_offset_downgrades():
     never return backend-dependent numerics."""
     q, k, v = _qkv(1, 2, 2, 4, 64, 32)
     lens = jnp.array([8], jnp.int32)
-    ops.reset_lengths_downgrade_warning()
+    ops.reset_downgrade_warnings()
     import warnings as _w
     with _w.catch_warnings(record=True) as w:
         _w.simplefilter("always")
@@ -272,7 +272,7 @@ def test_ops_unsupported_lengths_dtype_downgrades_with_reason():
                           n_kv_heads=2)
     d = lower.dispatch(p, backend="cpu", interpret=True,
                        lengths_masked=True)
-    ops.reset_lengths_downgrade_warning()
+    ops.reset_downgrade_warnings()
     import warnings as _w
     with _w.catch_warnings(record=True) as w_rec:
         _w.simplefilter("always")

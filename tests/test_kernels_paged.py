@@ -1,10 +1,12 @@
-"""Paged-KV kernels and serving: block-table-indirect Pallas kernels
-vs their gather-dense oracles (random non-contiguous tables, length-0
-rows, GQA, identity-table equivalence with the masked kernels),
-zero-downgrade dispatch through kernels.ops, the PageAllocator's
-free-list accounting, and preempt -> resume bit-identity on the
-continuous-batching engine."""
+"""Paged-KV kernels and serving: the paged decode megakernel vs its
+gather-dense oracle, the paged ``ops.attention`` / ``ops.qproj_attention``
+calls (a gather, then the masked kernels) vs theirs (random
+non-contiguous tables, length-0 rows, GQA, identity-table equivalence
+with the masked kernel), zero-downgrade dispatch through kernels.ops, a
+rung-down from the megakernel, the PageAllocator's free-list accounting,
+and preempt -> resume bit-identity on the continuous-batching engine."""
 
+import dataclasses
 import warnings
 
 import pytest
@@ -18,11 +20,8 @@ import numpy as np
 
 from repro import configs
 from repro.kernels import ops, ref
-from repro.kernels.fused_attention import (fused_attention_masked,
-                                           fused_attention_paged)
+from repro.kernels.fused_attention import fused_attention_masked
 from repro.kernels.fused_decode_block import fused_decode_block_paged
-from repro.kernels.fused_qproj_attention import (
-    fused_qproj_attention_paged)
 from repro.models import init_params_and_axes
 from repro.serve import (ContinuousBatchingEngine, OutOfPages,
                          PageAllocator, PagedContinuousBatchingEngine,
@@ -62,8 +61,10 @@ PAGED_SWEEP = [
 def test_paged_attention_matches_gather_oracle(b, hq, hkv, sq, page,
                                                max_pages, d, causal,
                                                lengths):
-    """fused_attention_paged == gather-dense unfused oracle over
-    shuffled non-contiguous tables (lengths not page multiples)."""
+    """Paged ops.attention on the Pallas impl (the pools gathered
+    dense, then the masked kernel) == the unfused oracle over the
+    gathered pools, on shuffled non-contiguous tables (lengths not page
+    multiples)."""
     n_pages = b * max_pages + 1
     kp, vp, tbl = _pools(b, hkv, n_pages, page, d, max_pages)
     q = jax.random.normal(KEYS[0], (b, hq, sq, d), jnp.float32)
@@ -72,18 +73,21 @@ def test_paged_attention_matches_gather_oracle(b, hq, hkv, sq, page,
     if causal and sq > 1:
         kw = {"q_offset": int(lengths[0]) - sq}   # multi-row contract
         lens = jnp.full((b,), lengths[0], jnp.int32)
-    o = fused_attention_paged(q, kp, vp, lens, tbl, causal=causal,
-                              interpret=True)
-    o_ref = ref.paged_attention_reference(q, kp, vp, lens, tbl,
-                                          causal=causal, **kw)
+    o = ops.attention(q, kp, vp, causal=causal, lengths=lens,
+                      block_tables=tbl, impl="pallas", interpret=True,
+                      **kw)
+    o_ref = ref.attention_reference(
+        q, ref.gather_pages(kp, tbl), ref.gather_pages(vp, tbl),
+        causal=causal, lengths=lens, **kw)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                rtol=2e-5, atol=2e-5)
 
 
 def test_paged_identity_table_equals_masked_dense():
     """With the identity table (row b's pages laid out contiguously),
-    the paged kernel reproduces the dense masked kernel bit-for-bit on
-    the same logical KV — the table only changes *where* blocks live."""
+    the paged ops.attention call reproduces the dense masked kernel
+    bit-for-bit on the same logical KV — the table only changes *where*
+    blocks live."""
     b, hq, hkv, page, max_pages, d = 2, 4, 2, 16, 4, 32
     skv = max_pages * page
     q = jax.random.normal(KEYS[1], (b, hq, 1, d), jnp.float32)
@@ -96,17 +100,21 @@ def test_paged_identity_table_equals_masked_dense():
         x.reshape(b, hkv, max_pages, page, d), 2, 1).reshape(
             b * max_pages, hkv, page, d)
     tbl = jnp.arange(b * max_pages, dtype=jnp.int32).reshape(b, max_pages)
-    o_paged = fused_attention_paged(q, pool_of(k), pool_of(v), lens,
-                                    tbl, causal=True, interpret=True)
+    o_paged = ops.attention(q, pool_of(k), pool_of(v), causal=True,
+                            lengths=lens, block_tables=tbl,
+                            impl="pallas", block_q=128, block_k=page,
+                            interpret=True)
     o_dense = fused_attention_masked(q, k, v, lens, causal=True,
-                                     block_k=page, interpret=True)
+                                     block_q=128, block_k=page,
+                                     interpret=True)
     np.testing.assert_array_equal(np.asarray(o_paged),
                                   np.asarray(o_dense))
 
 
 def test_paged_qproj_and_decode_block_match_oracles():
-    """The fused-Q and megakernel paged variants (in-kernel RoPE at
-    each row's end anchor) == their gather-dense oracles."""
+    """Paged ops.qproj_attention (gathered, then the masked fused-Q
+    kernel) and the paged megakernel (in-kernel RoPE at each row's end
+    anchor) == their gather-dense oracles."""
     b, hq, hkv, page, max_pages, d, e = 3, 4, 2, 16, 6, 32, 64
     n_pages = b * max_pages + 1
     kp, vp, tbl = _pools(b, hkv, n_pages, page, d, max_pages, seed=5)
@@ -115,11 +123,12 @@ def test_paged_qproj_and_decode_block_match_oracles():
     wq = jax.random.normal(KEYS[5], (e, hq, d), jnp.float32) * 0.1
     wo = jax.random.normal(KEYS[6], (hq, d, e), jnp.float32) * 0.1
     res = jax.random.normal(KEYS[7], (b, 1, e), jnp.float32)
-    o = fused_qproj_attention_paged(x, wq, kp, vp, lens, tbl,
-                                    causal=True, rope_theta=1e4,
-                                    interpret=True)
-    o_ref = ref.paged_qproj_attention_reference(
-        x, wq, kp, vp, lens, tbl, causal=False, rope_theta=1e4)
+    o = ops.qproj_attention(x, wq, kp, vp, causal=True, lengths=lens,
+                            block_tables=tbl, rope_theta=1e4,
+                            impl="pallas", interpret=True)
+    o_ref = ref.qproj_attention_reference(
+        x, wq, ref.gather_pages(kp, tbl), ref.gather_pages(vp, tbl),
+        causal=False, lengths=lens, rope_theta=1e4)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                rtol=2e-5, atol=2e-5)
     y = fused_decode_block_paged(x, wq, kp, vp, wo, res, lens, tbl,
@@ -202,23 +211,29 @@ def test_paged_decode_block_retiled_matches_oracle(dtype, hq, hkv, page,
 
 
 def test_paged_dispatch_zero_downgrades_and_per_reason_warn_once():
-    """ops.attention with block_tables stays on the Pallas path (no
-    downgrade warning); an *unsupported* paged call warns exactly once
+    """ops.decode_block with block_tables stays on the paged megakernel
+    (no downgrade warning); a call it *cannot* serve warns exactly once
     per distinct reason — the per-reason warn-once contract."""
-    b, hq, hkv, page, max_pages, d = 2, 4, 2, 16, 4, 32
+    b, hq, hkv, page, max_pages, d, e = 2, 4, 2, 16, 4, 32, 64
     n_pages = b * max_pages + 1
     kp, vp, tbl = _pools(b, hkv, n_pages, page, d, max_pages, seed=9)
-    q = jax.random.normal(KEYS[0], (b, hq, 1, d), jnp.float32)
+    x = jax.random.normal(KEYS[0], (b, 1, e), jnp.float32)
+    wq = jax.random.normal(KEYS[1], (e, hq, d), jnp.float32) * 0.1
+    wo = jax.random.normal(KEYS[2], (hq, d, e), jnp.float32) * 0.1
     lens = jnp.asarray([10, 50], jnp.int32)
+
+    def call(kp, vp, lens, tbl):
+        return ops.decode_block(x, wq, kp, vp, wo, x, lens,
+                                block_tables=tbl, rope_theta=1e4,
+                                impl="pallas", interpret=True)
+
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        o = ops.attention(q, kp, vp, causal=True, lengths=lens,
-                          block_tables=tbl, impl="pallas",
-                          interpret=True)
+        y = call(kp, vp, lens, tbl)
     np.testing.assert_allclose(
-        np.asarray(o),
-        np.asarray(ref.paged_attention_reference(q, kp, vp, lens, tbl,
-                                                 causal=True)),
+        np.asarray(y),
+        np.asarray(ref.paged_decode_block_reference(
+            x, wq, kp, vp, wo, x, lens, tbl, rope_theta=1e4)),
         rtol=2e-5, atol=2e-5)
     # a float table is refused -> one warning; repeating it is silent;
     # a *different* reason (misaligned page size) warns again
@@ -229,20 +244,61 @@ def test_paged_dispatch_zero_downgrades_and_per_reason_warn_once():
     vp12 = vp12[:, :, :12]
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
-        ops.attention(q, kp, vp, causal=True, lengths=lens,
-                      block_tables=bad_dtype, impl="pallas",
-                      interpret=True)
-        ops.attention(q, kp, vp, causal=True, lengths=lens,
-                      block_tables=bad_dtype, impl="pallas",
-                      interpret=True)
-        ops.attention(q, kp12, vp12, causal=True,
-                      lengths=jnp.minimum(lens, 12 * max_pages),
-                      block_tables=tbl12, impl="pallas",
-                      interpret=True)
+        call(kp, vp, lens, bad_dtype)
+        call(kp, vp, lens, bad_dtype)
+        call(kp12, vp12, jnp.minimum(lens, 12 * max_pages), tbl12)
     msgs = [str(x.message) for x in w]
     assert len(msgs) == 2, msgs
     assert all("paged-KV" in m for m in msgs)
     assert "masked-lengths" not in "".join(msgs)
+
+
+def test_rung_down_from_paged_megakernel_matches_gather_oracle():
+    """The supervisor's first rung-down from a paged megakernel
+    dispatch lands on qproj_attention: through gqa_forward the pools
+    are gathered dense and the masked fused-Q kernel runs.  Its output
+    and cache equal the reference dispatch's, which gathers with the
+    oracle, and the only ledger entry is the rung-down's own."""
+    from repro import lower
+    from repro.models.attention import gqa_forward, init_gqa
+    from repro.models.common import ModelConfig, split_params
+    cfg = ModelConfig(name="paged-rung-down", n_layers=1, d_model=128,
+                      n_heads=4, n_kv_heads=2, d_ff=256, vocab_size=64,
+                      param_dtype="float32", compute_dtype="float32")
+    params = split_params(init_gqa(KEYS[3], cfg))[0]
+    b, page, max_pages = 3, 16, 16            # 256 columns: megakernel
+    kp, vp, tbl = _pools(b, cfg.kv_heads, b * max_pages + 1, page,
+                         cfg.head_dim, max_pages, seed=3)
+    cache_len = jnp.asarray([37, 0, 200], jnp.int32)
+    x = jax.random.normal(KEYS[4], (b, 1, cfg.d_model), jnp.float32)
+    plan = lower.lower(cfg, "decode", max_pages * page)
+    d = lower.dispatch(plan, backend="cpu", interpret=True,
+                       entry="decode_block", rope=True,
+                       lengths_masked=True, paged=True)
+    assert (d.path, d.impl) == (lower.DECODE_MEGAKERNEL, "pallas")
+    ledger = len(plan.downgrades)
+    down = lower.rung_down(d, "test")
+    assert (down.path, down.impl) == (lower.QPROJ_ATTENTION, "pallas")
+
+    def run(disp):
+        return gqa_forward(params, cfg, x, cache_len[:, None],
+                           cache={"k": kp, "v": vp}, cache_len=cache_len,
+                           block_tables=tbl, plan=disp, residual=x)
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        out, cache = run(down)
+    oracle = dataclasses.replace(down, path=lower.UNFUSED,
+                                 impl="reference")
+    out_ref, cache_ref = run(oracle)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
+                               rtol=2e-5, atol=2e-5)
+    for got, want in zip(jax.tree.leaves(cache),
+                         jax.tree.leaves(cache_ref)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert [g.reason for g in plan.downgrades[ledger:]] == [
+        "test: rung-down decode_megakernel/pallas -> "
+        "qproj_attention/pallas"]
 
 
 # ---------------------------------------------------------------------------

@@ -207,6 +207,27 @@ def test_dispatch_masked_lengths_stays_pallas():
     assert any("masked-lengths" in n for n in plan.notes)
 
 
+def test_dispatch_paged_note_only_on_the_megakernel():
+    """Of the Pallas paths only the decode megakernel reads a page pool
+    through its table: a paged ``decode_megakernel`` dispatch carries
+    the block-table note, a paged ``qproj_attention`` one (a prefill
+    chunk's, whose pool ``kernels.ops`` gathers dense) does not, and
+    neither records a downgrade."""
+    mega = lower.lower(toy_cfg(), "decode", 256)
+    d = lower.dispatch(mega, backend="tpu", entry="decode_block",
+                       rope=True, lengths_masked=True, paged=True)
+    assert (d.path, d.impl, d.paged) == (lower.DECODE_MEGAKERNEL,
+                                         "pallas", True)
+    qproj = lower.lower(toy_cfg(), "decode", 256, decode_tokens=8)
+    d2 = lower.dispatch(qproj, backend="tpu", entry="qproj_attention",
+                        rope=True, lengths_masked=True, paged=True)
+    assert (d2.path, d2.impl, d2.paged) == (lower.QPROJ_ATTENTION,
+                                            "pallas", True)
+    assert any("block table" in n for n in mega.notes)
+    assert not any("block table" in n for n in qproj.notes)
+    assert not mega.downgrades and not qproj.downgrades
+
+
 def test_impl_for_backend_matrix():
     assert lower.impl_for(lower.UNFUSED, "tpu") == "reference"
     assert lower.impl_for(lower.FUSED_ATTENTION, "tpu") == "pallas"
@@ -308,7 +329,7 @@ def test_ops_lengths_pallas_runs_masked_kernel_without_warning():
     k = jax.random.normal(jax.random.PRNGKey(1), (2, 2, 16, 32))
     v = jax.random.normal(jax.random.PRNGKey(2), (2, 2, 16, 32))
     lengths = jnp.array([8, 16], jnp.int32)
-    ops.reset_lengths_downgrade_warning()
+    ops.reset_downgrade_warnings()
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
         o = ops.attention(q, k, v, causal=False, lengths=lengths,
@@ -332,7 +353,7 @@ def test_ops_lengths_downgrade_warns_once_with_reason():
     k = jax.random.normal(jax.random.PRNGKey(1), (2, 2, 16, 32))
     v = jax.random.normal(jax.random.PRNGKey(2), (2, 2, 16, 32))
     bad = jnp.array([8.0, 16.0], jnp.float32)
-    ops.reset_lengths_downgrade_warning()
+    ops.reset_downgrade_warnings()
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
         o = ops.attention(q, k, v, causal=False, lengths=bad,

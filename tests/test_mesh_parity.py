@@ -53,7 +53,7 @@ prompts = [np.arange(5) % 60, (np.arange(9) * 7) % 60]
 
 def run(hp):
     c = dataclasses.replace(cfg, head_parallel_decode=hp)
-    ops.reset_lengths_downgrade_warning()
+    ops.reset_downgrade_warnings()
     eng = ContinuousBatchingEngine(params, c, batch_size=2, max_len=32)
     eng.begin_prefill(0, prompts[0])
     eng.begin_prefill(1, prompts[1])

@@ -1,5 +1,6 @@
 """Compile-only checks of every attention Pallas kernel ``kernels/ops.py``
-dispatches to, for a described (not attached) TPU v5e, at the published
+dispatches to, and of the paged ``ops`` calls that land on a gather and
+a masked kernel, for a described (not attached) TPU v5e, at the published
 starcoder2-7b widths in bf16 (36 query heads, 4 KV heads, d_head 128,
 d_model 4608) and at the serving smoke's shapes (batch 8, page 16,
 max_len 1024, prefill chunk 256); the qk-norm paged megakernel at
@@ -28,6 +29,7 @@ from repro.kernels import fused_attention as fa
 from repro.kernels import fused_decode_block as fdb
 from repro.kernels import fused_mla_decode as fmd
 from repro.kernels import fused_qproj_attention as fqa
+from repro.kernels import ops
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 HQ, HKV, D, E = 36, 4, 128, 4608          # starcoder2-7b widths
@@ -118,16 +120,20 @@ def _tables(s):
     return s((B, MAX_LEN // PAGE), I32)
 
 
-def _paged(s):
+def _ops_attention_paged(s):
+    """A paged decode call below the megakernel: the pools gathered
+    dense, then the masked kernel."""
     q, pool = s((B, HQ, 1, D)), _pool(s)
-    return (lambda q, k, v, n, t: fa.fused_attention_paged(
-        q, k, v, n, t)), (q, pool, pool, s((B,), I32), _tables(s))
+    return (lambda q, k, v, n, t: ops.attention(
+        q, k, v, lengths=n, block_tables=t, impl="pallas")), \
+        (q, pool, pool, s((B,), I32), _tables(s))
 
 
-def _qproj_paged(s):
+def _ops_qproj_attention_paged(s):
     x, wq, pool = s((B, 1, E)), s((E, HQ, D)), _pool(s)
-    return (lambda x, wq, k, v, n, t: fqa.fused_qproj_attention_paged(
-        x, wq, k, v, n, t, rope_theta=THETA)), \
+    return (lambda x, wq, k, v, n, t: ops.qproj_attention(
+        x, wq, k, v, lengths=n, block_tables=t, rope_theta=THETA,
+        impl="pallas")), \
         (x, wq, pool, pool, s((B,), I32), _tables(s))
 
 
@@ -223,9 +229,10 @@ CASES = {
     "fused_attention_masked_decode": _masked(1, 128, 512),
     "fused_qproj_attention_masked_512": _qproj_masked(512),
     "fused_qproj_attention_masked_1024": _qproj_masked(1024),
-    # paged decode: every rung the ladder can land on
-    "fused_attention_paged": _paged,
-    "fused_qproj_attention_paged": _qproj_paged,
+    # paged decode: every rung the ladder can land on (below the
+    # megakernel, a gather and then the masked kernel)
+    "ops_attention_paged": _ops_attention_paged,
+    "ops_qproj_attention_paged": _ops_qproj_attention_paged,
     "fused_decode_block_512": _decode_block(512),
     "fused_decode_block_1024": _decode_block(1024),
     "fused_decode_block_paged": _decode_block_paged,
@@ -254,11 +261,13 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-#: the paged decode kernels (starcoder2-7b's, DeepSeek-V3's MLA), by the
-#: name their pallas_call gives them
+#: the kernels the benchmark's cells run, by the name their pallas_call
+#: gives them: the paged decode kernels (starcoder2-7b's and qwen3-8b's
+#: megakernel, DeepSeek-V3's MLA) and the masked prefill-chunk kernels
 NAMED = {"fused_decode_block_paged": _decode_block_paged,
-         "fused_attention_paged": _paged,
-         "fused_mla_decode_paged": _mla_decode_paged_at(B, MAX_LEN, 512)}
+         "fused_mla_decode_paged": _mla_decode_paged_at(B, MAX_LEN, 512),
+         "fused_attention_masked": _masked(CHUNK, 256, 256),
+         "fused_qproj_attention_masked": _qproj_masked(512)}
 
 
 @pytest.mark.parametrize("name", sorted(NAMED))
